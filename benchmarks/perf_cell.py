@@ -37,7 +37,6 @@ def main():
     ap.add_argument("--top-collectives", type=int, default=8)
     args = ap.parse_args()
 
-    from repro.compat import use_mesh
     from repro.configs import SHAPES, get_config
     from repro.configs.base import TrainConfig
     from repro.launch.dryrun import run_cell
@@ -48,7 +47,7 @@ def main():
     cfg = get_config(args.arch)
     shape = SHAPES[args.shape]
     mesh = make_production_mesh(multi_pod=(args.mesh == "multi"))
-    with use_mesh(mesh):
+    with jax.set_mesh(mesh):
         if shape.kind == "train":
             tcfg = TrainConfig(global_batch=shape.global_batch,
                                seq_len=shape.seq_len, remat="full")

@@ -110,6 +110,7 @@ import numpy as np
 
 from repro.configs import get_smoke_config
 from repro.configs.base import ServeConfig
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import build_model
 from repro.serve import (FleetConfig, FleetRouter, dense_kv_bytes,
                          paged_kv_bytes, pages_needed)
@@ -1207,6 +1208,7 @@ def main(argv=None):
     ap.add_argument("--quick", action="store_true",
                     help="CI-sized run (max_seq=512, lens 64/128/448)")
     args = ap.parse_args(argv)
+    enable_compile_cache()
     if args.quick:
         args.max_seq, args.lens = 512, [64, 128, 448]
         args.max_new, args.page_size = 16, 16

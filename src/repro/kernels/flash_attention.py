@@ -24,16 +24,11 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .pallas_compat import CompilerParams
-
 from ..core.tpu_mapping import choose_block_config
+from . import backend
 
 LOG2E = 1.4426950408889634
 NEG_INF = -1e30
-
-
-def _interpret() -> bool:
-    return jax.default_backend() != "tpu"
 
 
 def _fa_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref, m_ref, l_ref, *,
@@ -104,7 +99,7 @@ def _fa_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref, m_ref, l_ref, *,
     def _finalize():
         l = jnp.maximum(l_ref[...], 1e-20)
         o_ref[0, 0] = (acc_ref[...] / l).astype(o_ref.dtype)
-        lse_ref[0, 0] = (m_ref[...] + jnp.log(l))[:, 0]
+        lse_ref[0, 0] = m_ref[...] + jnp.log(l)
 
 
 @functools.partial(jax.jit, static_argnames=("causal", "window",
@@ -158,23 +153,26 @@ def flash_attention_fwd(q, k, v, *, causal: bool = True, window: int = 0,
         ],
         out_specs=[
             pl.BlockSpec((1, 1, block_q, D), lambda b, h, i, j: (b, h, i, 0)),
-            pl.BlockSpec((1, 1, block_q), lambda b, h, i, j: (b, h, i)),
+            # (block_q, 1): a trailing unit axis keeps the LSE block
+            # tiling-legal (a (1, block_q) block over (H, S) is not)
+            pl.BlockSpec((1, 1, block_q, 1),
+                         lambda b, h, i, j: (b, h, i, 0)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((B, Hq, Sqp, D), q.dtype),
-            jax.ShapeDtypeStruct((B, Hq, Sqp), jnp.float32),
+            jax.ShapeDtypeStruct((B, Hq, Sqp, 1), jnp.float32),
         ],
         scratch_shapes=[
             pltpu.VMEM((block_q, D), jnp.float32),
             pltpu.VMEM((block_q, 1), jnp.float32),
             pltpu.VMEM((block_q, 1), jnp.float32),
         ],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
-        interpret=_interpret(),
+        interpret=backend.interpret_mode(),
     )(qt, kt, vt)
 
     o = jnp.moveaxis(o[:, :, :Sq], 1, 2)          # back to (B,Sq,Hq,D)
-    lse = jnp.moveaxis(lse[:, :, :Sq], 1, 2)      # (B,Sq,Hq)
+    lse = jnp.moveaxis(lse[:, :, :Sq, 0], 1, 2)   # (B,Sq,Hq)
     return o, lse

@@ -24,14 +24,10 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .pallas_compat import CompilerParams
+from . import backend
 
 LOG2E = 1.4426950408889634
 NEG_INF = -1e30
-
-
-def _interpret() -> bool:
-    return jax.default_backend() != "tpu"
 
 
 def _masks(q_first, k_first, bq, bk, seq_kv, causal, window):
@@ -221,10 +217,10 @@ def flash_attention_bwd(q, k, v, o, lse, do, *, causal: bool = True,
                                lambda b, h, i, j: (b, h, i, 0)),
         out_shape=jax.ShapeDtypeStruct((B, Hq, Sqp, D), q.dtype),
         scratch_shapes=[pltpu.VMEM((block_q, D), jnp.float32)],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
-        interpret=_interpret(),
+        interpret=backend.interpret_mode(),
     )(qt, kt, vt, dot, lset, deltat)
 
     # ---- dk/dv --------------------------------------------------------------
@@ -263,10 +259,10 @@ def flash_attention_bwd(q, k, v, o, lse, do, *, causal: bool = True,
         ],
         scratch_shapes=[pltpu.VMEM((block_kv, D), jnp.float32),
                         pltpu.VMEM((block_kv, D), jnp.float32)],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
-        interpret=_interpret(),
+        interpret=backend.interpret_mode(),
     )(qg, kt, vt, dog, lseg, deltag)
 
     dq = jnp.moveaxis(dq[:, :, :Sq], 1, 2)

@@ -9,12 +9,17 @@ is branch-free.
 Two cache layouts share the same online-softmax inner step:
 
   flash_decode        dense (B, S_max, Hkv, D) caches - one contiguous
-                      KV strip per sequence.
-  paged_flash_decode  a global (P, page, Hkv, D) page pool shared by all
-                      sequences; each grid step gathers its page through a
-                      scalar-prefetched block table (SMEM), so the BlockSpec
-                      index map IS the page-table walk and the (m, l, acc)
-                      merge never leaves VMEM scratch.
+                      KV strip per sequence, moved head-major (B, Hkv, S, D)
+                      before the launch.
+  paged_flash_decode  a global head-major (Hkv, P, page, D) page pool shared
+                      by all sequences; each grid step gathers one head's
+                      page through a scalar-prefetched block table (SMEM), so
+                      the BlockSpec index map IS the page-table walk and the
+                      (m, l, acc) merge never leaves VMEM scratch.
+
+Both KV BlockSpecs are (1, 1, rows, D) over a head-major array: the TPU
+tiles the last two block dimensions by (8, 128) unless they span the whole
+array dimension, so a head must not sit on the second-minor axis of a block.
 """
 from __future__ import annotations
 
@@ -27,14 +32,10 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .pallas_compat import CompilerParams
+from . import backend
 
 LOG2E = 1.4426950408889634
 NEG_INF = -1e30
-
-
-def _interpret() -> bool:
-    return jax.default_backend() != "tpu"
 
 
 def _online_merge(s, mask, v, acc_ref, m_ref, l_ref):
@@ -67,7 +68,7 @@ def _online_softmax_step(q_ref, k_ref, v_ref, acc_ref, m_ref, l_ref, *,
     got into VMEM differs (contiguous BlockSpec walk vs block-table gather).
     """
     q = q_ref[0, 0].astype(jnp.float32) * scale              # (G, D)
-    k = k_ref[0].astype(jnp.float32)[:, 0]                   # (bk, D)
+    k = k_ref[0, 0].astype(jnp.float32)                      # (bk, D)
     s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                             preferred_element_type=jnp.float32)  # (G, bk)
     if softcap > 0.0:
@@ -76,7 +77,7 @@ def _online_softmax_step(q_ref, k_ref, v_ref, acc_ref, m_ref, l_ref, *,
     mask = pos < valid
     if window > 0:
         mask = mask & (pos >= valid - window)
-    v = v_ref[0].astype(jnp.float32)[:, 0]                   # (bk, D)
+    v = v_ref[0, 0].astype(jnp.float32)                      # (bk, D)
     _online_merge(s, mask, v, acc_ref, m_ref, l_ref)
 
 
@@ -127,8 +128,12 @@ def flash_decode(q, k_cache, v_cache, cache_len, *, window: int = 0,
 
     block_kv = min(block_kv, max(S, 128))
     pk = (-S) % block_kv
-    kc = jnp.pad(k_cache, ((0, 0), (0, pk), (0, 0), (0, 0))) if pk else k_cache
-    vc = jnp.pad(v_cache, ((0, 0), (0, pk), (0, 0), (0, 0))) if pk else v_cache
+    # head-major (B, Hkv, S, D), so a KV block is (block_kv, D) of one head
+    kc = jnp.moveaxis(k_cache, 2, 1)
+    vc = jnp.moveaxis(v_cache, 2, 1)
+    if pk:
+        kc = jnp.pad(kc, ((0, 0), (0, 0), (0, pk), (0, 0)))
+        vc = jnp.pad(vc, ((0, 0), (0, 0), (0, pk), (0, 0)))
     nk = (S + pk) // block_kv
 
     qg = q.reshape(B, Hkv, G, D)
@@ -143,8 +148,8 @@ def flash_decode(q, k_cache, v_cache, cache_len, *, window: int = 0,
         in_specs=[
             pl.BlockSpec(memory_space=pltpu.SMEM),   # cache_len, prefetched
             pl.BlockSpec((1, 1, G, D), lambda b, h, j: (b, h, 0, 0)),
-            pl.BlockSpec((1, block_kv, 1, D), lambda b, h, j: (b, j, h, 0)),
-            pl.BlockSpec((1, block_kv, 1, D), lambda b, h, j: (b, j, h, 0)),
+            pl.BlockSpec((1, 1, block_kv, D), lambda b, h, j: (b, h, j, 0)),
+            pl.BlockSpec((1, 1, block_kv, D), lambda b, h, j: (b, h, j, 0)),
         ],
         out_specs=pl.BlockSpec((1, 1, G, D), lambda b, h, j: (b, h, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((B, Hkv, G, D), q.dtype),
@@ -153,9 +158,9 @@ def flash_decode(q, k_cache, v_cache, cache_len, *, window: int = 0,
             pltpu.VMEM((G, 1), jnp.float32),
             pltpu.VMEM((G, 1), jnp.float32),
         ],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
-        interpret=_interpret(),
+        interpret=backend.interpret_mode(),
     )(cache_len, qg, kc, vc)
     return o.reshape(B, 1, Hq, D)
 
@@ -207,7 +212,8 @@ def paged_flash_decode(q, k_pages, v_pages, block_table, cache_len, *,
     """Decode against a paged KV cache.
 
     q:           (B, 1, Hq, D)
-    k/v_pages:   (P, page_size, Hkv, D) global page pool (all sequences)
+    k/v_pages:   (Hkv, P, page_size, D) head-major global page pool (all
+                 sequences)
     block_table: (B, n_max) int32 - page ids per sequence, position-major;
                  unused entries must point at a valid page (the engine keeps
                  page 0 as a never-allocated null page)
@@ -215,7 +221,7 @@ def paged_flash_decode(q, k_pages, v_pages, block_table, cache_len, *,
     Returns (B, 1, Hq, D).
     """
     B, _, Hq, D = q.shape
-    ps, Hkv = k_pages.shape[1], k_pages.shape[2]
+    Hkv, _, ps, _ = k_pages.shape
     G = Hq // Hkv
     n_max = block_table.shape[1]
     scale = scale if scale is not None else 1.0 / math.sqrt(D)
@@ -233,11 +239,12 @@ def paged_flash_decode(q, k_pages, v_pages, block_table, cache_len, *,
         grid=(B, Hkv, n_max),
         in_specs=[
             pl.BlockSpec((1, 1, G, D), lambda b, h, j, bt, cl: (b, h, 0, 0)),
-            # the index map IS the page-table walk: page j of sequence b
-            pl.BlockSpec((1, ps, 1, D),
-                         lambda b, h, j, bt, cl: (bt[b, j], 0, h, 0)),
-            pl.BlockSpec((1, ps, 1, D),
-                         lambda b, h, j, bt, cl: (bt[b, j], 0, h, 0)),
+            # the index map IS the page-table walk: head h of page j of
+            # sequence b
+            pl.BlockSpec((1, 1, ps, D),
+                         lambda b, h, j, bt, cl: (h, bt[b, j], 0, 0)),
+            pl.BlockSpec((1, 1, ps, D),
+                         lambda b, h, j, bt, cl: (h, bt[b, j], 0, 0)),
         ],
         out_specs=pl.BlockSpec((1, 1, G, D),
                                lambda b, h, j, bt, cl: (b, h, 0, 0)),
@@ -251,8 +258,8 @@ def paged_flash_decode(q, k_pages, v_pages, block_table, cache_len, *,
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, Hkv, G, D), q.dtype),
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
-        interpret=_interpret(),
+        interpret=backend.interpret_mode(),
     )(block_table, cache_len, qg, k_pages, v_pages)
     return o.reshape(B, 1, Hq, D)

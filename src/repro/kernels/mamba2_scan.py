@@ -17,11 +17,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .pallas_compat import CompilerParams
-
-
-def _interpret() -> bool:
-    return jax.default_backend() != "tpu"
+from . import backend
 
 
 def _ssd_kernel(a_ref, x_ref, dt_ref, b_ref, c_ref, y_ref, h_ref, *,
@@ -97,8 +93,8 @@ def mamba2_scan(x, dt, A, Bm, Cm, *, chunk: int = 128):
         out_specs=pl.BlockSpec((1, chunk, 1, P), lambda b, h, c: (b, c, h, 0)),
         out_shape=jax.ShapeDtypeStruct((B, Sp, H, P), x.dtype),
         scratch_shapes=[pltpu.VMEM((P, N), jnp.float32)],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
-        interpret=_interpret(),
+        interpret=backend.interpret_mode(),
     )(A.astype(jnp.float32), x, dt, Bm, Cm)
     return y[:, :S]

@@ -197,7 +197,8 @@ def _tp_head_sharded(fn, tp_mesh, n_pools: int, n_scalars: int):
     """shard_map a paged attention kernel on the HEAD axis of a serve mesh.
 
     The wrapped kernel sees q and n_pools page pools with their head axis
-    (axis 2 of (B,S,H,D) / (P,ps,Hkv,D)) split across "model" plus
+    (axis 2 of q's (B,S,H,D), axis 0 of a head-major (Hkv,P,ps,D) pool)
+    split across "model" plus
     n_scalars replicated block-table/length operands, computes its local
     head slice — per-head attention math never mixes heads, so the slice
     is the exact per-head result — and all-gathers outputs back to the
@@ -209,18 +210,17 @@ def _tp_head_sharded(fn, tp_mesh, n_pools: int, n_scalars: int):
     """
     from jax.sharding import PartitionSpec as P
 
-    from .. import compat
-
-    hs = P(None, None, "model", None)
+    q_heads = P(None, None, "model", None)
+    pool_heads = P("model", None, None, None)
 
     def local(*args):
         o = fn(*args)
         return jax.lax.all_gather(o, "model", axis=2, tiled=True)
 
-    return compat.shard_map(
-        local, tp_mesh,
-        in_specs=(hs,) * (1 + n_pools) + (P(),) * n_scalars,
-        out_specs=P())
+    return jax.shard_map(
+        local, mesh=tp_mesh,
+        in_specs=(q_heads,) + (pool_heads,) * n_pools + (P(),) * n_scalars,
+        out_specs=P(), check_vma=False)
 
 
 def paged_flash_decode(q, k_pages, v_pages, block_table, cache_len, *,
@@ -230,7 +230,8 @@ def paged_flash_decode(q, k_pages, v_pages, block_table, cache_len, *,
                        tp_mesh=None) -> jax.Array:
     """Decode against a paged KV cache (vLLM-style block table).
 
-    q: (B,1,Hq,D); k_pages/v_pages: (P, page_size, Hkv, D) global page pool;
+    q: (B,1,Hq,D); k_pages/v_pages: (Hkv, P, page_size, D) head-major
+    global page pool;
     block_table: (B, n_max) int32 page ids; cache_len: (B,) valid lengths.
     The Pallas path walks the block table from SMEM inside the BlockSpec
     index maps, keeping the (m, l, acc) merge VMEM-resident; the ref path

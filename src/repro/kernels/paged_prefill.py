@@ -67,13 +67,9 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from .flash_decode import _online_merge
-from .pallas_compat import CompilerParams
+from . import backend
 
 NEG_INF = -1e30
-
-
-def _interpret() -> bool:
-    return jax.default_backend() != "tpu"
 
 
 def _chunk_kernel(tbl_ref, off_ref, tl_ref, ql_ref, q_ref, k_ref, v_ref,
@@ -109,8 +105,8 @@ def _chunk_kernel(tbl_ref, off_ref, tl_ref, ql_ref, q_ref, k_ref, v_ref,
 
     @pl.when(run)
     def _compute():
-        q = q_ref[0, 0].astype(jnp.float32).reshape(s_suf * gq, -1) * scale
-        k = k_ref[0].astype(jnp.float32)[:, 0]               # (ps, D)
+        q = q_ref[0, 0].astype(jnp.float32) * scale          # (S*G, D)
+        k = k_ref[0, 0].astype(jnp.float32)                  # (ps, D)
         s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32)
         if softcap > 0.0:
@@ -122,7 +118,7 @@ def _chunk_kernel(tbl_ref, off_ref, tl_ref, ql_ref, q_ref, k_ref, v_ref,
         mask = col <= row
         if window > 0:
             mask = mask & (col > row - window)
-        v = v_ref[0].astype(jnp.float32)[:, 0]               # (ps, D)
+        v = v_ref[0, 0].astype(jnp.float32)                  # (ps, D)
         _online_merge(s, mask, v, acc_ref, m_ref, l_ref)
 
     @pl.when(j == nk - 1)
@@ -132,8 +128,7 @@ def _chunk_kernel(tbl_ref, off_ref, tl_ref, ql_ref, q_ref, k_ref, v_ref,
         # zero pad query lanes (flattened row r is query index r // gq)
         ql = ql_ref[b]
         qidx = jax.lax.broadcasted_iota(jnp.int32, (s_suf * gq, 1), 0) // gq
-        o = jnp.where(qidx < ql, o, 0.0).reshape(s_suf, gq, -1)
-        o_ref[0, 0] = o.astype(o_ref.dtype)
+        o_ref[0, 0] = jnp.where(qidx < ql, o, 0.0).astype(o_ref.dtype)
 
 
 @functools.partial(jax.jit, static_argnames=("window", "scale",
@@ -155,7 +150,7 @@ def batched_paged_prefill_attention(q, k_pages, v_pages, page_tables,
                  launch: the per-layer scatter lands before this kernel
                  reads the pool, so packing two chunks of one sequence is
                  exact as long as their offsets are ordered).
-    k/v_pages:   (P, page_size, Hkv, D) global page pool
+    k/v_pages:   (Hkv, P, page_size, D) head-major global page pool
     page_tables: (K, n_max) int32 - per-row block-table rows,
                  position-major; entries past the reservation point at the
                  null page 0 and are never touched by the causal mask
@@ -175,7 +170,7 @@ def batched_paged_prefill_attention(q, k_pages, v_pages, page_tables,
     Returns (K, S, Hq, D).
     """
     K, S, Hq, D = q.shape
-    ps, Hkv = k_pages.shape[1], k_pages.shape[2]
+    Hkv, _, ps, _ = k_pages.shape
     G = Hq // Hkv
     n_max = page_tables.shape[1]
     scale = scale if scale is not None else 1.0 / math.sqrt(D)
@@ -185,8 +180,11 @@ def batched_paged_prefill_attention(q, k_pages, v_pages, page_tables,
     ql = jnp.clip(tl - off, 0, S) if q_lens is None \
         else jnp.asarray(q_lens, jnp.int32).reshape(K)
 
-    # head-major GQA grouping, one grid row per (sequence row, KV head)
-    qg = q.reshape(K, S, Hkv, G, D).transpose(0, 2, 1, 3, 4)  # (K,Hkv,S,G,D)
+    # head-major GQA grouping, one grid row per (sequence row, KV head);
+    # the S query positions x G group heads of one KV head are flattened
+    # into the rows of one (S*G, D) block (row r is query r // G)
+    qg = q.reshape(K, S, Hkv, G, D).transpose(0, 2, 1, 3, 4).reshape(
+        K, Hkv, S * G, D)
     kernel = functools.partial(_chunk_kernel, page_size=ps, window=window,
                                scale=scale, softcap=logit_softcap, gq=G,
                                s_suf=S)
@@ -195,19 +193,19 @@ def batched_paged_prefill_attention(q, k_pages, v_pages, page_tables,
         num_scalar_prefetch=4,
         grid=(K, Hkv, n_max),
         in_specs=[
-            pl.BlockSpec((1, 1, S, G, D),
-                         lambda b, h, j, tbl, off, tl, ql: (b, h, 0, 0, 0)),
-            # the index map IS the page-table walk: page j of row b
-            pl.BlockSpec((1, ps, 1, D),
+            pl.BlockSpec((1, 1, S * G, D),
+                         lambda b, h, j, tbl, off, tl, ql: (b, h, 0, 0)),
+            # the index map IS the page-table walk: head h of page j of row b
+            pl.BlockSpec((1, 1, ps, D),
                          lambda b, h, j, tbl, off, tl, ql:
-                         (tbl[b, j], 0, h, 0)),
-            pl.BlockSpec((1, ps, 1, D),
+                         (h, tbl[b, j], 0, 0)),
+            pl.BlockSpec((1, 1, ps, D),
                          lambda b, h, j, tbl, off, tl, ql:
-                         (tbl[b, j], 0, h, 0)),
+                         (h, tbl[b, j], 0, 0)),
         ],
-        out_specs=pl.BlockSpec((1, 1, S, G, D),
+        out_specs=pl.BlockSpec((1, 1, S * G, D),
                                lambda b, h, j, tbl, off, tl, ql:
-                               (b, h, 0, 0, 0)),
+                               (b, h, 0, 0)),
         scratch_shapes=[
             pltpu.VMEM((S * G, D), jnp.float32),
             pltpu.VMEM((S * G, 1), jnp.float32),
@@ -217,12 +215,13 @@ def batched_paged_prefill_attention(q, k_pages, v_pages, page_tables,
     o = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((K, Hkv, S, G, D), q.dtype),
-        compiler_params=CompilerParams(
+        out_shape=jax.ShapeDtypeStruct((K, Hkv, S * G, D), q.dtype),
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
-        interpret=_interpret(),
+        interpret=backend.interpret_mode(),
     )(page_tables, off, tl, ql, qg, k_pages, v_pages)
-    return o.transpose(0, 2, 1, 3, 4).reshape(K, S, Hq, D)
+    return o.reshape(K, Hkv, S, G, D).transpose(0, 2, 1, 3, 4).reshape(
+        K, S, Hq, D)
 
 
 def paged_prefill_attention(q, k_pages, v_pages, page_row, q_offset, *,

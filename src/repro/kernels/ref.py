@@ -47,22 +47,6 @@ def _gqa_expand(h_q: int, h_kv: int):
 # FlashAttention-2 forward (chunked, numerically stable)
 # ===========================================================================
 
-def _mesh_aligned_block(Skv: int, block_kv: int) -> int:
-    """Align the KV block count to the mesh's model-axis size so the scan's
-    stacked KV blocks stay sequence-sharded (one block per shard step)
-    instead of being gathered wholesale."""
-    try:
-        mesh = jax.sharding.get_abstract_mesh()
-        if mesh is not None and mesh.axis_names and "model" in mesh.axis_names:
-            tp = dict(zip(mesh.axis_names, mesh.shape.values()))["model"] \
-                if not hasattr(mesh.shape, "get") else mesh.shape.get("model", 1)
-            if tp > 1 and Skv % tp == 0 and Skv // tp >= 128:
-                return Skv // tp
-    except Exception:
-        pass
-    return block_kv
-
-
 def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
                     causal: bool = True,
                     window: int = 0,
@@ -145,9 +129,20 @@ def flash_decode(q: jax.Array, k_cache: jax.Array, v_cache: jax.Array,
                  block_kv: int = 1024) -> jax.Array:
     """q: (B, 1, Hq, D); k_cache/v_cache: (B, S_max, Hkv, D); cache_len: (B,)
     valid prefix length per sequence.  Returns (B, 1, Hq, D)."""
+    return _flash_decode_head_major(
+        q, jnp.moveaxis(k_cache, 2, 0), jnp.moveaxis(v_cache, 2, 0),
+        cache_len, scale=scale, window=window, logit_softcap=logit_softcap,
+        block_kv=block_kv)
+
+
+def _flash_decode_head_major(q, k, v, cache_len, *, scale, window,
+                             logit_softcap, block_kv: int = 1024):
+    """flash_decode over head-major (Hkv, B, S_max, D) caches.  Every
+    intermediate keeps the KV head leading, so a head-sharded caller's
+    slice computes exactly what the same heads compute unsharded."""
     B, Sq, Hq, D = q.shape
     assert Sq == 1
-    _, S, Hkv, _ = k_cache.shape
+    Hkv, _, S, _ = k.shape
     G = _gqa_expand(Hq, Hkv)
     scale = scale if scale is not None else 1.0 / math.sqrt(D)
     LOG2E = 1.4426950408889634
@@ -157,33 +152,40 @@ def flash_decode(q: jax.Array, k_cache: jax.Array, v_cache: jax.Array,
 
     nblk = -(-S // block_kv)
     pad = nblk * block_kv - S
-    kc = jnp.pad(k_cache, ((0, 0), (0, pad), (0, 0), (0, 0))) if pad else k_cache
-    vc = jnp.pad(v_cache, ((0, 0), (0, pad), (0, 0), (0, 0))) if pad else v_cache
-    kb = jnp.moveaxis(kc.reshape(B, nblk, block_kv, Hkv, D), 1, 0)
-    vb = jnp.moveaxis(vc.reshape(B, nblk, block_kv, Hkv, D), 1, 0)
+    kc = jnp.pad(k, ((0, 0), (0, 0), (0, pad), (0, 0))) if pad else k
+    vc = jnp.pad(v, ((0, 0), (0, 0), (0, pad), (0, 0))) if pad else v
+    kb = jnp.moveaxis(kc.reshape(Hkv, B, nblk, block_kv, D), 2, 0)
+    vb = jnp.moveaxis(vc.reshape(Hkv, B, nblk, block_kv, D), 2, 0)
 
-    qf = (q.astype(jnp.float32) * scale).reshape(B, Hkv, G, D)
+    qf = jnp.moveaxis((q.astype(jnp.float32) * scale).reshape(B, Hkv, G, D),
+                      1, 0)                                # (Hkv, B, G, D)
 
     def body(carry, blk):
         m, l, o = carry
         kblk, vblk, j = blk
         pos = j * block_kv + jnp.arange(block_kv)          # (bk,)
-        s = jnp.einsum("bhgd,bkhd->bhgk", qf, kblk.astype(jnp.float32))
+        # multiply-and-reduce rather than a dot: XLA picks a dot's
+        # summation order from the batch shape, so a head-sharded slice
+        # would round differently from the same heads unsharded
+        s = jnp.sum(qf[:, :, :, None, :]
+                    * kblk.astype(jnp.float32)[:, :, None], -1)
         if logit_softcap > 0.0:
             s = logit_softcap * jnp.tanh(s / logit_softcap)
         mask = pos[None, :] < cache_len[:, None]           # (B, bk)
         if window > 0:
             mask = mask & (pos[None, :] >= cache_len[:, None] - window)
-        s = jnp.where(mask[:, None, None, :], s, NEG_INF)
+        mask = mask[None, :, None, :]
+        s = jnp.where(mask, s, NEG_INF)
         m_blk = jnp.max(s, axis=-1)
         m_new = jnp.maximum(m, m_blk)
         m_safe = jnp.where(m_new <= NEG_INF / 2, 0.0, m_new)
         p = jnp.exp2((s - m_safe[..., None]) * LOG2E)
-        p = jnp.where(mask[:, None, None, :], p, 0.0)
+        p = jnp.where(mask, p, 0.0)
         alpha = jnp.exp2((m - m_new) * LOG2E)
         alpha = jnp.where(m <= NEG_INF / 2, 0.0, alpha)
         l_new = l * alpha + jnp.sum(p, axis=-1)
-        pv = jnp.einsum("bhgk,bkhd->bhgd", p, vblk.astype(jnp.float32))
+        pv = jnp.sum(p[..., None] * vblk.astype(jnp.float32)[:, :, None],
+                     -2)
         o_new = o * alpha[..., None] + pv
         return (m_new, l_new, o_new), None
 
@@ -191,14 +193,23 @@ def flash_decode(q: jax.Array, k_cache: jax.Array, v_cache: jax.Array,
     # wraps this kernel with the head axis sharded) the scan carry must
     # carry the same "varying manual axes" type as the body outputs, which
     # depend on the sharded cache; outside shard_map this is exactly +0.0
-    vzero = jnp.sum(k_cache[:, :0].astype(jnp.float32))
-    m0 = jnp.full((B, Hkv, G), NEG_INF, jnp.float32) + vzero
-    l0 = jnp.zeros((B, Hkv, G), jnp.float32) + vzero
-    o0 = jnp.zeros((B, Hkv, G, D), jnp.float32) + vzero
+    vzero = jnp.sum(k[:, :, :0].astype(jnp.float32))
+    m0 = jnp.full((Hkv, B, G), NEG_INF, jnp.float32) + vzero
+    l0 = jnp.zeros((Hkv, B, G), jnp.float32) + vzero
+    o0 = jnp.zeros((Hkv, B, G, D), jnp.float32) + vzero
     (m, l, o), _ = jax.lax.scan(body, (m0, l0, o0),
                                 (kb, vb, jnp.arange(nblk)))
-    o = o / jnp.maximum(l, 1e-20)[..., None]
+    o = jnp.moveaxis(o / jnp.maximum(l, 1e-20)[..., None], 0, 1)
     return o.reshape(B, 1, Hq, D).astype(q.dtype)
+
+
+def _gather_pages(pages, tables):
+    """Contiguous (R, n_max * page_size, Hkv, D) strips of the pages each
+    row of `tables` (R, n_max) names, from a head-major (Hkv, P, page_size,
+    D) pool."""
+    Hkv, _, _, D = pages.shape
+    R = tables.shape[0]
+    return jnp.moveaxis(pages[:, tables], 0, 3).reshape(R, -1, Hkv, D)
 
 
 def paged_flash_decode(q, k_pages, v_pages, block_table, cache_len, *,
@@ -207,21 +218,24 @@ def paged_flash_decode(q, k_pages, v_pages, block_table, cache_len, *,
                        logit_softcap: float = 0.0) -> jax.Array:
     """Decode against a paged KV cache (reference oracle).
 
-    q: (B, 1, Hq, D); k_pages/v_pages: (P, page_size, Hkv, D) global page
-    pool; block_table: (B, n_max) int32 page ids (position-major, unused
-    entries pointing at any valid page); cache_len: (B,) or scalar.
+    q: (B, 1, Hq, D); k_pages/v_pages: (Hkv, P, page_size, D) head-major
+    global page pool; block_table: (B, n_max) int32 page ids
+    (position-major, unused entries pointing at any valid page);
+    cache_len: (B,) or scalar.
 
-    Gathers each sequence's pages into a contiguous strip and runs the
-    chunked dense decode - the ground truth the Pallas block-table kernel is
-    validated against, and the portable paged-serving path off-TPU.
+    Gathers each sequence's pages into a contiguous head-major strip and
+    runs the chunked dense decode - the ground truth the Pallas
+    block-table kernel is validated against, and the portable
+    paged-serving path off-TPU.
     """
-    B = q.shape[0]
-    _, page_size, Hkv, D = k_pages.shape
+    Hkv, _, _, D = k_pages.shape
     block_table = jnp.asarray(block_table, jnp.int32)
-    k = k_pages[block_table].reshape(B, -1, Hkv, D)
-    v = v_pages[block_table].reshape(B, -1, Hkv, D)
-    return flash_decode(q, k, v, cache_len, scale=scale, window=window,
-                        logit_softcap=logit_softcap)
+    B = block_table.shape[0]
+    k = k_pages[:, block_table].reshape(Hkv, B, -1, D)
+    v = v_pages[:, block_table].reshape(Hkv, B, -1, D)
+    return _flash_decode_head_major(q, k, v, cache_len, scale=scale,
+                                    window=window,
+                                    logit_softcap=logit_softcap)
 
 
 def batched_paged_prefill_attention(q, k_pages, v_pages, page_tables,
@@ -235,8 +249,8 @@ def batched_paged_prefill_attention(q, k_pages, v_pages, page_tables,
 
     q: (K, S, Hq, D) chunk queries; row k sits at absolute positions
     q_offsets[k] + arange(S), zero-padded past its real length (ragged
-    rows share one static S); k/v_pages: (P, page_size, Hkv, D) global
-    pool; page_tables: (K, n_max) per-row block-table rows (each row's
+    rows share one static S); k/v_pages: (Hkv, P, page_size, D)
+    head-major global pool; page_tables: (K, n_max) per-row block-table rows (each row's
     chunk K/V already written into its pages, as is all K/V for positions
     < q_offsets[k]); true_lens: (K,) each row's prefill cursor after its
     last REAL token - columns at or past it are masked, the gather-level
@@ -252,13 +266,13 @@ def batched_paged_prefill_attention(q, k_pages, v_pages, page_tables,
     chunked / prefix-cached serving path off-TPU.
     """
     K, S, Hq, D = q.shape
-    _, ps, Hkv, _ = k_pages.shape
+    Hkv = k_pages.shape[0]
     G = _gqa_expand(Hq, Hkv)
     sc = scale if scale is not None else 1.0 / math.sqrt(D)
     LOG2E = 1.4426950408889634
     page_tables = jnp.asarray(page_tables, jnp.int32)
-    k = k_pages[page_tables].reshape(K, -1, Hkv, D)      # (K, n_max*ps, ...)
-    v = v_pages[page_tables].reshape(K, -1, Hkv, D)
+    k = _gather_pages(k_pages, page_tables)              # (K, n_max*ps, ...)
+    v = _gather_pages(v_pages, page_tables)
     Skv = k.shape[1]
     qf = (q.astype(jnp.float32) * sc).reshape(K, S, Hkv, G, D)
     s = jnp.einsum("bshgd,bkhd->bshgk", qf, k.astype(jnp.float32))

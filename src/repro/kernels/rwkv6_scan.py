@@ -23,11 +23,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .pallas_compat import CompilerParams
-
-
-def _interpret() -> bool:
-    return jax.default_backend() != "tpu"
+from . import backend
 
 
 def _wkv_kernel(u_ref, r_ref, k_ref, v_ref, w_ref, y_ref, s_ref, *,
@@ -114,8 +110,8 @@ def rwkv6_scan(r, k, v, w, u, *, chunk: int = 32):
         out_specs=pl.BlockSpec((1, chunk, 1, V), lambda b, h, c: (b, c, h, 0)),
         out_shape=jax.ShapeDtypeStruct((B, Sp, H, V), r.dtype),
         scratch_shapes=[pltpu.VMEM((K, V), jnp.float32)],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
-        interpret=_interpret(),
+        interpret=backend.interpret_mode(),
     )(u, r, k, v, w)
     return y[:, :S]

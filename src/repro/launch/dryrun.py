@@ -28,7 +28,6 @@ from pathlib import Path
 
 import jax
 
-from ..compat import use_mesh
 from ..configs import ASSIGNED_ARCHS, SHAPES, get_config
 from ..configs.base import TrainConfig
 from .hlo_cost import analyze as hlo_analyze
@@ -97,7 +96,7 @@ def run_cell(arch: str, shape_name: str, multi_pod: bool,
            "n_devices": int(mesh.devices.size)}
 
     t0 = time.time()
-    with use_mesh(mesh):
+    with jax.set_mesh(mesh):
         if shape.kind == "train":
             tcfg = TrainConfig(global_batch=shape.global_batch,
                                seq_len=shape.seq_len, remat="full")

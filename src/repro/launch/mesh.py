@@ -7,7 +7,14 @@ to obtain placeholder devices for the production meshes.
 """
 from __future__ import annotations
 
-from ..compat import make_mesh
+import jax
+
+
+def make_mesh(shape, axes):
+    """jax.make_mesh with Auto axes: the sharding rules place arrays
+    through constraints, not explicit-sharding types."""
+    return jax.make_mesh(tuple(shape), tuple(axes),
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -33,7 +40,6 @@ def make_serve_mesh(tp_degree: int):
     Axis names match sharding/rules.py: serving shards only "model" (the
     head axis); "data" stays 1 per replica (the router is the data axis).
     """
-    import jax
     import numpy as np
 
     devs = jax.devices()

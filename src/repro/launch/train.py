@@ -11,7 +11,6 @@ import argparse
 
 import jax
 
-from ..compat import use_mesh
 from ..configs import get_config, get_smoke_config
 from ..configs.base import TrainConfig
 from .mesh import make_debug_mesh, make_production_mesh
@@ -50,7 +49,7 @@ def main(argv=None):
         from ..launch.specs import train_cell
         from ..configs.base import ShapeSpec
         shape = ShapeSpec("train", tcfg.seq_len, tcfg.global_batch, "train")
-        with use_mesh(mesh):
+        with jax.set_mesh(mesh):
             _, _, shardings = train_cell(cfg, shape, mesh, tcfg)
             tr = Trainer(cfg, tcfg, mesh=mesh, state_shardings=shardings[0])
     out = tr.run()

@@ -95,12 +95,12 @@ def attn_prefill_paged(params, x, cfg: ModelConfig, k_pages, v_pages,
 
     x: (1, S, D) with S a multiple of the page size (pad the prompt
     upstream; trailing pad K/V is masked by `lens` at decode time and gets
-    overwritten as decode advances).  k/v_pages: (P, page_size, Hkv, D)
-    global pool; page_ids: (S // page_size,) pages owned by this sequence,
+    overwritten as decode advances).  k/v_pages: (Hkv, P, page_size, D)
+    head-major global pool; page_ids: (S // page_size,) pages owned by this sequence,
     position-major.  Returns (y, k_pages, v_pages)."""
     q, k, v = _qkv(params, x, cfg)
     S = x.shape[1]
-    page_size = k_pages.shape[1]
+    page_size = k_pages.shape[2]
     n = S // page_size
     if cfg.use_rope:
         pos = jnp.arange(S)
@@ -108,8 +108,10 @@ def attn_prefill_paged(params, x, cfg: ModelConfig, k_pages, v_pages,
         k = rope(k, pos, cfg.rope_theta, cfg.rope_scaling)
     kp = k[0].reshape(n, page_size, cfg.n_kv_heads, cfg.head_dim)
     vp = v[0].reshape(n, page_size, cfg.n_kv_heads, cfg.head_dim)
-    k_pages = k_pages.at[page_ids].set(kp.astype(k_pages.dtype))
-    v_pages = v_pages.at[page_ids].set(vp.astype(v_pages.dtype))
+    k_pages = k_pages.at[:, page_ids].set(
+        jnp.moveaxis(kp, 2, 0).astype(k_pages.dtype))
+    v_pages = v_pages.at[:, page_ids].set(
+        jnp.moveaxis(vp, 2, 0).astype(v_pages.dtype))
     o = ops.flash_attention(q, k, v, causal=True, window=window,
                             logit_softcap=cfg.attn_logit_softcap, impl=impl)
     y = dense(params["wo"], o.reshape(1, S, cfg.n_heads * cfg.head_dim))
@@ -130,7 +132,7 @@ def _tp_pool_constrain(pages, tp_mesh):
         return pages
     from jax.sharding import NamedSharding, PartitionSpec as P
     return jax.lax.with_sharding_constraint(
-        pages, NamedSharding(tp_mesh, P(None, None, "model", None)))
+        pages, NamedSharding(tp_mesh, P("model", None, None, None)))
 
 
 def attn_decode_paged(params, x, cfg: ModelConfig, k_pages, v_pages,
@@ -138,7 +140,7 @@ def attn_decode_paged(params, x, cfg: ModelConfig, k_pages, v_pages,
                       impl: Optional[str] = None, tp_mesh=None):
     """Single-token decode through the block table.
 
-    x: (B, 1, D); k/v_pages: (P, page_size, Hkv, D) global pool;
+    x: (B, 1, D); k/v_pages: (Hkv, P, page_size, D) head-major global pool;
     block_table: (B, n_max) page ids; lens: (B,) current lengths (the new
     token's K/V is scattered into page lens // page_size at offset
     lens % page_size).  Idle slots (lens == 0, block-table row zeroed) write
@@ -152,12 +154,15 @@ def attn_decode_paged(params, x, cfg: ModelConfig, k_pages, v_pages,
     if cfg.use_rope:
         q = rope(q, lens[:, None], cfg.rope_theta, cfg.rope_scaling)
         k = rope(k, lens[:, None], cfg.rope_theta, cfg.rope_scaling)
-    page_size = k_pages.shape[1]
+    page_size = k_pages.shape[2]
     bidx = jnp.arange(B)
     page = block_table[bidx, lens // page_size]
     off = lens % page_size
-    k_pages = k_pages.at[page, off].set(k[:, 0].astype(k_pages.dtype))
-    v_pages = v_pages.at[page, off].set(v[:, 0].astype(v_pages.dtype))
+    # k[:, 0] is (B, Hkv, D); the pool is indexed (Hkv, page, off)
+    k_pages = k_pages.at[:, page, off].set(
+        jnp.moveaxis(k[:, 0], 1, 0).astype(k_pages.dtype))
+    v_pages = v_pages.at[:, page, off].set(
+        jnp.moveaxis(v[:, 0], 1, 0).astype(v_pages.dtype))
     k_pages = _tp_pool_constrain(k_pages, tp_mesh)
     v_pages = _tp_pool_constrain(v_pages, tp_mesh)
     o = ops.paged_flash_decode(q, k_pages, v_pages, block_table, lens + 1,
@@ -194,7 +199,7 @@ def attn_prefill_chunks_paged(params, x, cfg: ModelConfig, k_pages, v_pages,
     Returns (y, k_pages, v_pages)."""
     q, k, v = _qkv(params, x, cfg)
     K, S = x.shape[:2]
-    page_size = k_pages.shape[1]
+    page_size = k_pages.shape[2]
     n_max = page_tables.shape[1]
     pos = jnp.asarray(offsets, jnp.int32)[:, None] + jnp.arange(S)[None, :]
     if cfg.use_rope:
@@ -205,8 +210,11 @@ def attn_prefill_chunks_paged(params, x, cfg: ModelConfig, k_pages, v_pages,
     pages = jnp.where(valid, jnp.take_along_axis(page_tables, pidx, axis=1),
                       0)
     offs = jnp.where(valid, pos % page_size, 0)
-    k_pages = k_pages.at[pages, offs].set(k.astype(k_pages.dtype))
-    v_pages = v_pages.at[pages, offs].set(v.astype(v_pages.dtype))
+    # k is (K, S, Hkv, D); the pool is indexed (Hkv, page, off)
+    k_pages = k_pages.at[:, pages, offs].set(
+        jnp.moveaxis(k, 2, 0).astype(k_pages.dtype))
+    v_pages = v_pages.at[:, pages, offs].set(
+        jnp.moveaxis(v, 2, 0).astype(v_pages.dtype))
     k_pages = _tp_pool_constrain(k_pages, tp_mesh)
     v_pages = _tp_pool_constrain(v_pages, tp_mesh)
     o = ops.batched_paged_prefill_attention(

@@ -146,8 +146,8 @@ def build_model(cfg: ModelConfig) -> Model:
     def init_cache(batch_size: int, max_len: int, enc_len: int = 0, *,
                    page_size: int = 0, num_pages: int = 0):
         """Dense layout by default; page_size > 0 selects the paged layout:
-        a global (L, num_pages, page_size, Hkv, D) page pool shared by all
-        sequences plus a (batch, ceil(max_len/page_size)) block table.  Page
+        a global head-major (L, Hkv, num_pages, page_size, D) page pool
+        shared by all sequences plus a (batch, ceil(max_len/page_size)) block table.  Page
         0 is reserved as the null page (see serve/paged_cache.py)."""
         dt = pdtype(cfg)
         if page_size > 0:
@@ -160,7 +160,7 @@ def build_model(cfg: ModelConfig) -> Model:
             if num_pages <= 0:
                 num_pages = dense_equivalent_pages(batch_size, max_len,
                                                    page_size)
-            shp = (L, num_pages, page_size, cfg.n_kv_heads, cfg.head_dim)
+            shp = (L, cfg.n_kv_heads, num_pages, page_size, cfg.head_dim)
             return {"k_pages": jnp.zeros(shp, dt),
                     "v_pages": jnp.zeros(shp, dt),
                     "block_table": jnp.zeros((batch_size, n_max), jnp.int32)}

@@ -54,22 +54,20 @@ def moe_ffn(params, x, cfg: ModelConfig):
     # the K-expanded rows per layer (EXPERIMENTS.md S.Perf Phase C/F).
     import os
     if os.environ.get("REPRO_MOE_A2A"):
-        from ..sharding import active_mesh, mesh_axis_size
-        mesh = active_mesh()
-        names = tuple(mesh.axis_names) if mesh is not None else ()
-        if "model" in names:
-            tp = mesh_axis_size(mesh, "model")
+        mesh = jax.sharding.get_abstract_mesh()
+        if "model" in mesh.axis_names:
+            tp = mesh.shape["model"]
             if tp > 1 and E % tp == 0 and S % tp == 0:
                 from .moe_a2a import moe_ffn_a2a_local
                 from jax.sharding import PartitionSpec as P
                 pspec = {k: (P("model", None, None) if k.startswith("experts")
                              else P(None, None)) for k in params}
-                from ..compat import shard_map
-                fn = shard_map(
+                fn = jax.shard_map(
                     lambda p, xx: moe_ffn_a2a_local(p, xx, cfg),
                     mesh=mesh,
                     in_specs=(pspec, P(None, "model", None)),
-                    out_specs=(P(None, "model", None), P()))
+                    out_specs=(P(None, "model", None), P()),
+                    check_vma=False)
                 return fn(params, x)
 
     logits = jnp.einsum("bsd,de->bse", x.astype(jnp.float32),

@@ -50,8 +50,7 @@ def moe_ffn_a2a_local(params, x_local, cfg: ModelConfig, *,
     """
     B, S_l, D = x_local.shape
     E, K = cfg.moe_experts, cfg.moe_top_k
-    from ..compat import axis_size
-    tp = axis_size(axis)
+    tp = jax.lax.axis_size(axis)
     my = jax.lax.axis_index(axis)
     e_loc = E // tp
 
@@ -144,8 +143,7 @@ def make_sharded_moe(cfg: ModelConfig, mesh, *, axis: str = "model"):
     def fn(params, x):
         return moe_ffn_a2a_local(params, x, cfg, axis=axis)
 
-    from ..compat import shard_map
-    return shard_map(
+    return jax.shard_map(
         fn, mesh=mesh,
         in_specs=(pspec, P(None, axis, None)),
-        out_specs=(P(None, axis, None), P()))
+        out_specs=(P(None, axis, None), P()), check_vma=False)

@@ -21,7 +21,6 @@ from typing import Any, Dict, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
-from ..compat import optimization_barrier
 from ..configs.base import ModelConfig
 from ..sharding import constrain
 from .attention import (attn_decode, attn_decode_paged, attn_forward,
@@ -33,6 +32,25 @@ from .mamba2 import (mamba2_decode, mamba2_forward, mamba2_init,
 from .moe import moe_ffn, moe_init
 from .rwkv6 import (rwkv6_channel_mix, rwkv6_init, rwkv6_init_state,
                     rwkv6_time_mix)
+
+
+@jax.custom_vjp
+def optimization_barrier(x):
+    """jax.lax.optimization_barrier that also keeps the barrier on the
+    COTANGENT, so the backward pass gets the same hoisting protection as
+    the forward."""
+    return jax.lax.optimization_barrier(x)
+
+
+def _barrier_fwd(x):
+    return optimization_barrier(x), None
+
+
+def _barrier_bwd(_, g):
+    return (jax.lax.optimization_barrier(g),)
+
+
+optimization_barrier.defvjp(_barrier_fwd, _barrier_bwd)
 
 
 def _stack_trees(trees):
@@ -192,7 +210,7 @@ def stack_decode(params, x, cfg: ModelConfig, cache, lens, *, impl=None,
 def stack_prefill_paged(params, x, cfg: ModelConfig, cache, page_ids, *,
                         impl=None):
     """Paged prefill of ONE sequence (B=1), x: (1, S, D) with S a multiple
-    of the page size.  cache: {"k_pages"/"v_pages": (L, P, page, Hkv, D),
+    of the page size.  cache: {"k_pages"/"v_pages": (L, Hkv, P, page, D),
     "block_table": (B, n_max)}; page_ids: (S // page,) pages owned by the
     sequence.  The block table itself is host-managed (serve/paged_cache.py)
     and passes through untouched."""

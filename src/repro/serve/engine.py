@@ -335,13 +335,13 @@ class ServeEngine:
                 from jax.sharding import NamedSharding, PartitionSpec
                 from ..launch.mesh import make_serve_mesh
                 self.tp_mesh = make_serve_mesh(scfg.tp_degree)
-                # commit placement ONCE at construction: the (L, P, ps,
-                # Hkv, D) pools head-sharded, params fully replicated, so
+                # commit placement ONCE at construction: the (L, Hkv, P,
+                # ps, D) pools head-sharded, params fully replicated, so
                 # every jitted step compiles against stable shardings and
                 # per-tick uploads (block table, chunk packs, tokens) stay
                 # small uncommitted host arrays jit re-shards for free
                 hs = NamedSharding(self.tp_mesh,
-                                   PartitionSpec(None, None, None, "model",
+                                   PartitionSpec(None, "model", None, None,
                                                  None))
                 rep = NamedSharding(self.tp_mesh, PartitionSpec())
                 self.cache = {
@@ -1033,7 +1033,7 @@ class ServeEngine:
         bookkeeping)."""
         for key in ("k_pages", "v_pages"):
             slab = self.cache[key]
-            self.cache[key] = slab.at[:, dst].set(slab[:, src])
+            self.cache[key] = slab.at[:, :, dst].set(slab[:, :, src])
 
     def _admit_paged(self, slot: int, req: Request) -> bool:
         """Paged cache: reserve the request's worst case up front; prefill

@@ -1,7 +1,7 @@
 """Paged KV-cache bookkeeping: fixed-size pages, a free list, block tables.
 
-The device side is a global page pool per layer - (num_pages, page_size,
-Hkv, D) slabs shared by every sequence (see models/model.py init_cache) -
+The device side is a global page pool per layer - head-major (Hkv,
+num_pages, page_size, D) slabs shared by every sequence (see models/model.py init_cache) -
 plus one (max_batch, max_pages_per_seq) int32 block table.  This module owns
 the HOST side: which pages are free, which belong to which slot, and the
 numpy mirror of the block table.  All methods are O(pages moved); nothing
@@ -47,7 +47,7 @@ def dense_kv_bytes(cfg: ModelConfig, scfg: ServeConfig) -> int:
 
 def paged_kv_bytes(cfg: ModelConfig, scfg: ServeConfig,
                    num_pages: int = 0) -> int:
-    """Bytes of the paged (L, num_pages, page_size, Hkv, D) K+V pool."""
+    """Bytes of the paged (L, Hkv, num_pages, page_size, D) K+V pool."""
     if num_pages <= 0:
         num_pages = dense_equivalent_pages(scfg.max_batch, scfg.max_seq,
                                            scfg.page_size)

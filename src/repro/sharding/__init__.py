@@ -1,7 +1,5 @@
-from .rules import (active_mesh, cache_spec, constrain, dp_axes,
-                    mesh_axis_size, param_sharding_tree, param_spec, tp_axis,
-                    tree_paths)
+from .rules import (cache_spec, constrain, dp_axes, param_sharding_tree,
+                    param_spec, tp_axis, tree_paths)
 
-__all__ = ["active_mesh", "cache_spec", "constrain", "dp_axes",
-           "mesh_axis_size", "param_sharding_tree", "param_spec", "tp_axis",
-           "tree_paths"]
+__all__ = ["cache_spec", "constrain", "dp_axes", "param_sharding_tree",
+           "param_spec", "tp_axis", "tree_paths"]

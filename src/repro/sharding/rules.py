@@ -18,38 +18,9 @@ import jax
 from jax.sharding import PartitionSpec as P
 
 
-def _active_mesh():
-    """The mesh currently in scope, or None.
-
-    jax >= 0.5 exposes jax.sharding.get_abstract_mesh(); on older releases
-    fall back to the physical mesh bound by `with mesh:` (thread_resources).
-    """
-    getter = getattr(jax.sharding, "get_abstract_mesh", None)
-    if getter is not None:
-        return getter()
-    try:
-        from jax._src.mesh import thread_resources
-        m = thread_resources.env.physical_mesh
-        return m if m.axis_names else None
-    except Exception:
-        return None
-
-
-def _mesh_axis_size(mesh, name: str) -> int:
-    shape = mesh.shape
-    if hasattr(shape, "get"):
-        return shape.get(name, 1)
-    return dict(zip(mesh.axis_names, shape.values())).get(name, 1)
-
-
 def _axis_names() -> Tuple[str, ...]:
-    m = _active_mesh()
-    return tuple(m.axis_names) if m is not None and m.axis_names else ()
-
-
-# public aliases (model code reuses the version-compat mesh lookup)
-active_mesh = _active_mesh
-mesh_axis_size = _mesh_axis_size
+    """Axes of the mesh in scope (`jax.set_mesh`); empty when none is."""
+    return tuple(jax.sharding.get_abstract_mesh().axis_names)
 
 
 def dp_axes(names: Optional[Tuple[str, ...]] = None):
@@ -108,8 +79,7 @@ def constrain(x: jax.Array, logical: str) -> jax.Array:
     dp = dp_axes(names)
     tp = tp_axis(names)
     if logical == "bshd" and tp is not None:
-        mesh = _active_mesh()
-        tp_n = _mesh_axis_size(mesh, "model")
+        tp_n = jax.sharding.get_abstract_mesh().shape["model"]
         # PREFER sequence sharding (context parallelism): projections and
         # the attention output then stay sequence-local, eliminating the
         # per-layer residual all-gather + partial-sum all-reduce entirely;
@@ -123,10 +93,7 @@ def constrain(x: jax.Array, logical: str) -> jax.Array:
             spec = P(dp, None, None, None)
     else:
         spec = _ACT_SPECS[logical](dp, tp)
-    try:
-        return jax.lax.with_sharding_constraint(x, spec)
-    except Exception:
-        return x
+    return jax.lax.with_sharding_constraint(x, spec)
 
 
 # --------------------------------------------------------------------------

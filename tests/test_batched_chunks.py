@@ -57,8 +57,8 @@ def test_batched_kernel_matches_single_rows(impl, window, rng):
     tables, and a dead padding row returning exactly zero."""
     S, Hq, Hkv, D, ps, n_pages, n_max = 8, 4, 2, 16, 4, 24, 8
     ks = jax.random.split(rng, 3)
-    k_pages = jax.random.normal(ks[0], (n_pages, ps, Hkv, D))
-    v_pages = jax.random.normal(ks[1], (n_pages, ps, Hkv, D))
+    k_pages = jax.random.normal(ks[0], (Hkv, n_pages, ps, D))
+    v_pages = jax.random.normal(ks[1], (Hkv, n_pages, ps, D))
     q = jax.random.normal(ks[2], (4, S, Hq, D))
     perm = np.random.default_rng(0).permutation(
         np.arange(1, n_pages)).astype(np.int32)
@@ -100,6 +100,8 @@ def test_same_sequence_two_chunks_one_launch(impl, rng):
     for j in range(n_pages):
         k_pages = k_pages.at[j + 1].set(k[0, j * ps:(j + 1) * ps])
         v_pages = v_pages.at[j + 1].set(v[0, j * ps:(j + 1) * ps])
+    # the pool is head-major: (Hkv, P, ps, D)
+    k_pages, v_pages = jnp.moveaxis(k_pages, 2, 0), jnp.moveaxis(v_pages, 2, 0)
     row = np.arange(1, n_pages + 1, dtype=np.int32)
     tables = np.stack([row, row])
     half = S // 2

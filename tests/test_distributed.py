@@ -42,17 +42,17 @@ lens = jnp.array([S - 5, S // 2])
 
 o_ref = ref.flash_decode(q, kc, vc, lens)
 
-from repro.compat import make_mesh, shard_map
-mesh = make_mesh((4, 1), ("data", "model"))
+from repro.launch.mesh import make_debug_mesh
+mesh = make_debug_mesh(4, 1)
 # replication checking off: the psum/pmax-combined output is replicated by
 # construction; correctness is asserted numerically below.
-fn = shard_map(
+fn = jax.shard_map(
     lambda q, kc, vc, lens: ops.seq_parallel_decode(q, kc, vc, lens,
                                                     axis="data"),
     mesh=mesh,
     in_specs=(P(), P(None, "data", None, None),
               P(None, "data", None, None), P()),
-    out_specs=P())
+    out_specs=P(), check_vma=False)
 o_par = fn(q, kc, vc, lens)
 err = float(jnp.abs(o_par - o_ref).max())
 assert err < 2e-5, err
@@ -83,9 +83,9 @@ _, met_ref = jax.jit(make_train_step(m, tcfg))(state0, batch)
 loss_ref = float(met_ref["loss"])
 
 # sharded
-from repro.compat import make_mesh, use_mesh
-mesh = make_mesh((2, 2), ("data", "model"))
-with use_mesh(mesh):
+from repro.launch.mesh import make_debug_mesh
+mesh = make_debug_mesh(2, 2)
+with jax.set_mesh(mesh):
     shape = ShapeSpec("t", 32, 4, "train")
     step, args, shardings = train_cell(cfg, shape, mesh, tcfg)
     state1 = jax.device_put(init_train_state(m, key, tcfg), shardings[0])
@@ -119,9 +119,9 @@ tokens = jnp.ones((B, 1), jnp.int32)
 lens = jnp.full((B,), 7, jnp.int32)
 logits_ref, _ = m.decode_step(params, tokens, lens, cache)
 
-from repro.compat import make_mesh, use_mesh
-mesh = make_mesh((2, 2), ("data", "model"))
-with use_mesh(mesh):
+from repro.launch.mesh import make_debug_mesh
+mesh = make_debug_mesh(2, 2)
+with jax.set_mesh(mesh):
     shape = ShapeSpec("d", S, B, "decode")
     step, args, shardings = serve_cell(cfg, shape, mesh)
     logits_sh, _ = jax.jit(step, in_shardings=shardings)(

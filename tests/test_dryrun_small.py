@@ -3,7 +3,6 @@ import jax
 import jax.numpy as jnp
 import pytest
 
-from repro.compat import use_mesh
 from repro.configs import get_smoke_config
 from repro.configs.base import ShapeSpec, TrainConfig
 from repro.launch.dryrun import parse_collectives
@@ -31,7 +30,7 @@ def test_cells_lower_on_debug_mesh(kind):
     cfg = get_smoke_config("granite-3-2b")
     mesh = make_debug_mesh(1, 1)
     shape = ShapeSpec("t", 32, 2, kind)
-    with use_mesh(mesh):
+    with jax.set_mesh(mesh):
         if kind == "train":
             tcfg = TrainConfig(global_batch=2, seq_len=32, remat="full")
             step, args, shardings = train_cell(cfg, shape, mesh, tcfg)

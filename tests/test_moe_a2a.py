@@ -33,8 +33,8 @@ x = jax.random.normal(jax.random.PRNGKey(1), (B, S, cfg.d_model)
                       ).astype(jnp.bfloat16)
 y_ref, aux_ref = moe_ffn(params, x, cfg)
 
-from repro.compat import make_mesh
-mesh = make_mesh((1, 4), ("data", "model"))
+from repro.launch.mesh import make_debug_mesh
+mesh = make_debug_mesh(1, 4)
 fn = make_sharded_moe(cfg, mesh)
 y_a2a, aux_a2a = jax.jit(fn)(params, x)
 err = float(jnp.abs(y_a2a.astype(jnp.float32)

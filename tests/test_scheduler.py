@@ -550,6 +550,8 @@ def test_chunk_attention_matches_monolithic(impl, off, s_chunk, rng):
     for j in range(n_pages):
         k_pages = k_pages.at[perm[j]].set(k[0, j * ps:(j + 1) * ps])
         v_pages = v_pages.at[perm[j]].set(v[0, j * ps:(j + 1) * ps])
+    # the pool is head-major: (Hkv, P, ps, D)
+    k_pages, v_pages = jnp.moveaxis(k_pages, 2, 0), jnp.moveaxis(v_pages, 2, 0)
     got = ops.paged_prefill_attention(
         q[:, off:off + s_chunk], k_pages, v_pages,
         jnp.asarray(perm, jnp.int32), off, impl=impl)

@@ -15,8 +15,9 @@ from traffic import mixed_prompts, serve_all
 
 
 def _paged_from_dense(kc, vc, page_size, seed=0):
-    """Scatter a dense (B, S, Hkv, D) cache into a SHUFFLED page pool and
-    the matching block table (page 0 kept as the null page)."""
+    """Scatter a dense (B, S, Hkv, D) cache into a SHUFFLED head-major
+    (Hkv, P, page_size, D) page pool and the matching block table (page 0
+    kept as the null page)."""
     B, S, Hkv, D = kc.shape
     n_max = S // page_size
     n_pool = B * n_max + 1
@@ -29,7 +30,8 @@ def _paged_from_dense(kc, vc, page_size, seed=0):
         for j in range(n_max):
             k_pages[bt[b, j]] = np.asarray(kc[b, j*page_size:(j+1)*page_size])
             v_pages[bt[b, j]] = np.asarray(vc[b, j*page_size:(j+1)*page_size])
-    return jnp.asarray(k_pages), jnp.asarray(v_pages), jnp.asarray(bt)
+    return (jnp.asarray(np.moveaxis(k_pages, 2, 0)),
+            jnp.asarray(np.moveaxis(v_pages, 2, 0)), jnp.asarray(bt))
 
 
 # ===========================================================================

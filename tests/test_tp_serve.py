@@ -209,9 +209,9 @@ def _random_paged(rng, tp, hkv_mult, gqa, n_rows, d=8, page_size=4,
     (page 0 reserved null, as the engine lays it out)."""
     hkv = tp * hkv_mult
     hq = hkv * gqa
-    kp = jnp.asarray(rng.standard_normal((n_pages, page_size, hkv, d)),
+    kp = jnp.asarray(rng.standard_normal((hkv, n_pages, page_size, d)),
                      jnp.float32)
-    vp = jnp.asarray(rng.standard_normal((n_pages, page_size, hkv, d)),
+    vp = jnp.asarray(rng.standard_normal((hkv, n_pages, page_size, d)),
                      jnp.float32)
     tables = np.zeros((n_rows, n_max), np.int32)
     lens = np.zeros((n_rows,), np.int32)
