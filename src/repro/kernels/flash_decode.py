@@ -261,5 +261,6 @@ def paged_flash_decode(q, k_pages, v_pages, block_table, cache_len, *,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=backend.interpret_mode(),
+        name="paged_flash_decode",
     )(block_table, cache_len, qg, k_pages, v_pages)
     return o.reshape(B, 1, Hq, D)

@@ -219,6 +219,7 @@ def batched_paged_prefill_attention(q, k_pages, v_pages, page_tables,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=backend.interpret_mode(),
+        name="batched_paged_prefill_attention",
     )(page_tables, off, tl, ql, qg, k_pages, v_pages)
     return o.reshape(K, Hkv, S, G, D).transpose(0, 2, 1, 3, 4).reshape(
         K, S, Hq, D)

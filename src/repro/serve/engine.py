@@ -248,7 +248,8 @@ class ServeEngine:
         # tracer (ServeConfig.telemetry; host-side only - zero jitted
         # calls, zero device->host syncs, bit-identical outputs on or off)
         tracer = SpanTracer(scfg.telemetry_spans) if scfg.telemetry else None
-        self.tm = Telemetry(registry=MetricsRegistry(), tracer=tracer)
+        self.tm = Telemetry(registry=MetricsRegistry(), tracer=tracer,
+                            clock=self._clock)
         m = self.tm.registry
         m.counter("serve_jit_calls_total",
                   "Jitted model-step launches dispatched")
@@ -272,6 +273,11 @@ class ServeEngine:
                   "Requests accepted by submit()")
         m.counter("serve_requests_finished_total",
                   "Requests finished (length or stop token)")
+        m.histogram("serve_queue_wait_seconds",
+                    "Seconds from submit() to a request's first slot and "
+                    "page reservation (resumes not observed)",
+                    buckets=(0.005, 0.01, 0.025, 0.05, 0.1, 0.25, 0.5, 1,
+                             2.5, 5, 10))
         m.gauge("serve_peak_pages",
                 "High-water mark of pool pages in use (cached included)")
         m.gauge("serve_peak_live_pages",
@@ -380,8 +386,8 @@ class ServeEngine:
         # dispatch / throughput counters (jit_calls, host_syncs,
         # gen_tokens, decode_launches, kv_pages_read, ...) live in the
         # telemetry registry; the attribute names below the class body are
-        # registry-backed properties, and launch_log is a view over the
-        # typed per-tick records in self.tm.ticks
+        # registry-backed properties, and per-tick dispatch accounting is
+        # the typed TickRecords in self.tm.ticks
         # n_acc array of the tick's verify launch, fetched WITH tokens
         self._spec_nacc: Optional[jax.Array] = None
 
@@ -414,21 +420,14 @@ class ServeEngine:
     peak_pages = _registry_gauge("serve_peak_pages")
     peak_live_pages = _registry_gauge("serve_peak_live_pages")
 
-    @property
-    def launch_log(self) -> List[tuple]:
-        """Per-tick dispatch accounting as the legacy 5-tuple rows
-        (jit_calls, host_syncs, host_wall_s, n_chunk_tasks, n_decode) -
-        a compatibility view over the typed TickRecords in self.tm.ticks."""
-        return [t.as_tuple() for t in self.tm.ticks]
-
     # ------------------------------------------------------------------
     # telemetry surface
     # ------------------------------------------------------------------
     def export_trace(self, path, clock: str = "wall"):
         """Write the span tracer's records as Chrome trace-event JSON
         (open in Perfetto / chrome://tracing): request lifecycle spans on
-        per-slot tracks, engine phases and kernel launches on engine
-        tracks.  clock="wall" for the human view, "work" for the
+        per-slot tracks, nested engine phases on the engine track.
+        clock="wall" for the human view, "work" for the
         deterministic work-clock view.  Returns the trace dict."""
         if self.tm.tracer is None:
             raise ValueError(
@@ -486,17 +485,15 @@ class ServeEngine:
     def _note_launch(self, kind: str, rows: int, live_rows: int,
                      true_tokens: int, padded_tokens: int,
                      kv_pages_read: int, kv_pages_written: int,
-                     new_kv_tokens: int, wall0: float = 0.0,
-                     wall1: float = 0.0):
-        """Record one kernel launch's data-movement attribution (and, with
-        the tracer on, its span on the engine track)."""
-        self.tm.launch(LaunchRecord(
+                     new_kv_tokens: int):
+        """Record one kernel launch's data-movement attribution."""
+        self.tm.launches.append(LaunchRecord(
             tick=self.sched.ticks, kind=kind, rows=rows,
             live_rows=live_rows, true_tokens=true_tokens,
             padded_tokens=padded_tokens, kv_pages_read=kv_pages_read,
             kv_pages_written=kv_pages_written,
             new_kv_tokens=new_kv_tokens,
-            work_clock=self.sched.work_clock), wall0, wall1)
+            work_clock=self.sched.work_clock))
 
     def _note_kv_pages_read(self, n_pages: int):
         """Count pages a token-emitting launch read, in BOTH units: pool
@@ -531,10 +528,9 @@ class ServeEngine:
         ps = self.scfg.page_size
         return end // ps - start // ps + (1 if end % ps else 0)
 
-    def _wall(self) -> float:
-        """Tracer wall stamp; 0.0 (never read) with the tracer off."""
-        tr = self.tm.tracer
-        return tr.now() if tr is not None else 0.0
+    def _clock(self):
+        """(tick index, work clock): the stamps of the phase spans."""
+        return self.sched.ticks, self.sched.work_clock
 
     def _phase(self, req: Request, phase: str, track: int, **args):
         """Request-lifecycle phase transition onto the tracer (no-op off)."""
@@ -698,13 +694,15 @@ class ServeEngine:
                                     if self.decode_launches else 0.0)
         out["tokens_per_kv_page"] = (self.gen_tokens / self.kv_pages_read
                                      if self.kv_pages_read else 0.0)
-        if self.launch_log:
-            calls = [r[0] for r in self.launch_log]
-            syncs = [r[1] for r in self.launch_log]
-            walls = [r[2] for r in self.launch_log]
+        ticks = self.tm.ticks
+        if ticks:
+            calls = [t.jit_calls for t in ticks]
+            syncs = [t.host_syncs for t in ticks]
+            walls = [t.host_wall_s for t in ticks]
             # "busy" = the steady-state shape of the acceptance criterion:
             # prefill chunks AND decodes in the same tick
-            busy = [r[0] for r in self.launch_log if r[3] and r[4]]
+            busy = [t.jit_calls for t in ticks
+                    if t.n_chunk_tasks and t.n_decode]
             out["jit_calls_per_tick_max"] = max(calls)
             out["jit_calls_per_tick_mean"] = float(np.mean(calls))
             out["jit_calls_per_busy_tick_max"] = max(busy) if busy else 0
@@ -816,7 +814,7 @@ class ServeEngine:
         decode launch, but the stamp must match the sequential path)."""
         req.out_tokens.append(tok)
         self.gen_tokens += 1
-        self.sched.note_token(req, time.time(), work=work)
+        self.sched.note_token(req, time.perf_counter(), work=work)
         if tok in req.stop_tokens:
             req.finish_reason = "stop"
             return True
@@ -929,14 +927,16 @@ class ServeEngine:
         zero-copy on CPU, and the allocator mutates this table in place on
         every alloc/free/preempt - an aliased upload would let those host
         writes retarget the device table under an in-flight tick."""
-        tbl = self.allocator.table.copy()
-        masked = [i for i, r in enumerate(self.slots)
-                  if r is not None and r.state is not RequestState.DECODING]
-        if masked:
-            tbl[masked] = 0
-        self.cache["block_table"] = jnp.asarray(tbl)
-        self._note_table_upload(tbl.nbytes)
-        self._table_dirty = False
+        with self.tm.phase("engine.table_upload"):
+            tbl = self.allocator.table.copy()
+            masked = [i for i, r in enumerate(self.slots)
+                      if r is not None
+                      and r.state is not RequestState.DECODING]
+            if masked:
+                tbl[masked] = 0
+            self.cache["block_table"] = jnp.asarray(tbl)
+            self._note_table_upload(tbl.nbytes)
+            self._table_dirty = False
 
     # ------------------------------------------------------------------
     # admission (monolithic prefill)
@@ -989,20 +989,19 @@ class ServeEngine:
     def _admit_prefill(self, slot: int, req: Request):
         """Dense cache, attention family: one batched prefill into a
         sub-cache sized to the padded prompt, scattered into the slot row."""
-        self.sched.pop(req)
+        self._pop_admitted(req)
         self._phase(req, "PREFILLING", slot)
         toks, s_real = self._padded_prompt(req.prompt, PREFILL_BUCKET)
         s_pad = toks.shape[1]
         sub = self.model.init_cache(1, s_pad)
         batch = {"tokens": toks, "true_lens": jnp.asarray([s_real])}
         self.jit_calls += 1
-        w0 = self._wall()
-        logits, sub, _ = self._prefill(self.params, batch, sub)
+        with self.tm.phase("engine.prefill_launch", true_tokens=s_real):
+            logits, sub, _ = self._prefill(self.params, batch, sub)
         self._note_launch("prefill", rows=1, live_rows=1,
                           true_tokens=s_real, padded_tokens=s_pad,
                           kv_pages_read=0, kv_pages_written=0,
-                          new_kv_tokens=s_real, wall0=w0,
-                          wall1=self._wall())
+                          new_kv_tokens=s_real)
         self.cache["k"] = self.cache["k"].at[:, slot, :s_pad].set(
             sub["k"][:, 0])
         self.cache["v"] = self.cache["v"].at[:, slot, :s_pad].set(
@@ -1046,7 +1045,7 @@ class ServeEngine:
                             scfg.page_size)
         if not self.allocator.can_alloc(need):
             return False
-        self.sched.pop(req)
+        self._pop_admitted(req)
         self._phase(req, "PREFILLING", slot)
         pages = self.allocator.alloc(slot, need)
         self._note_alloc()
@@ -1057,16 +1056,15 @@ class ServeEngine:
         self._note_table_upload(self.allocator.table.nbytes)
         batch = {"tokens": toks, "true_lens": jnp.asarray([s_real])}
         self.jit_calls += 1
-        w0 = self._wall()
-        logits, self.cache, _ = self._prefill_paged(
-            self.params, batch, self.cache, page_ids)
+        with self.tm.phase("engine.prefill_launch", true_tokens=s_real):
+            logits, self.cache, _ = self._prefill_paged(
+                self.params, batch, self.cache, page_ids)
         self._note_launch("prefill_paged", rows=1, live_rows=1,
                           true_tokens=s_real,
                           padded_tokens=toks.shape[1],
                           kv_pages_read=self._row_pages(slot, s_real),
                           kv_pages_written=self._span_pages(0, s_real),
-                          new_kv_tokens=s_real, wall0=w0,
-                          wall1=self._wall())
+                          new_kv_tokens=s_real)
         self.prefill_tokens += s_real
         self.sched.note_work(s_real)
         self._place(slot, req, logits, s_real)
@@ -1114,6 +1112,16 @@ class ServeEngine:
         req.admit_seq = self._admit_seq
         self._admit_seq += 1
 
+    def _pop_admitted(self, req: Request):
+        """Take a request whose slot (and pages) were just reserved off the
+        queue.  Its first admission stamps t_admit and observes its queue
+        wait; a resume after preemption keeps both."""
+        self.sched.pop(req)
+        if req.t_admit is None:
+            req.t_admit = time.perf_counter()
+            self.tm.registry.get("serve_queue_wait_seconds").observe(
+                req.t_admit - req.t_submit)
+
     def _admit_prefix(self, slot: int, req: Request) -> bool:
         """Prefix-cached monolithic admission: the whole uncached suffix
         prefills in one pass - literally the request's FINAL chunk, so
@@ -1123,7 +1131,7 @@ class ServeEngine:
         start = self._reserve_prefix(slot, req)
         if start is None:
             return False
-        self.sched.pop(req)
+        self._pop_admitted(req)
         self.slots[slot] = req
         req.slot = slot
         req.prefill_pos = start
@@ -1140,26 +1148,26 @@ class ServeEngine:
     def _admit_stepwise(self, slot: int, req: Request):
         """Token-by-token prefill through decode_step (exact for every
         architecture family, including recurrent state caches)."""
-        self.sched.pop(req)
+        self._pop_admitted(req)
         self._phase(req, "PREFILLING", slot)
         lens = self.lens
         cache = self.cache
         last_logits = None
-        w0 = self._wall()
-        for t in req.prompt:
-            tok = self.tokens.at[slot, 0].set(t)
-            pos = lens
-            self.jit_calls += 1
-            logits, cache = self._decode(self.params, cache, tok, pos)
-            lens = lens.at[slot].add(1)
-            last_logits = logits
+        with self.tm.phase("engine.prefill_launch",
+                           true_tokens=len(req.prompt)):
+            for t in req.prompt:
+                tok = self.tokens.at[slot, 0].set(t)
+                pos = lens
+                self.jit_calls += 1
+                logits, cache = self._decode(self.params, cache, tok, pos)
+                lens = lens.at[slot].add(1)
+                last_logits = logits
         # one aggregated record for the whole token-by-token sweep
         self._note_launch("stepwise", rows=1, live_rows=1,
                           true_tokens=len(req.prompt),
                           padded_tokens=len(req.prompt),
                           kv_pages_read=0, kv_pages_written=0,
-                          new_kv_tokens=len(req.prompt), wall0=w0,
-                          wall1=self._wall())
+                          new_kv_tokens=len(req.prompt))
         self.cache, self.lens = cache, lens
         self._lens_np[slot] = len(req.prompt)
         self.prefill_tokens += len(req.prompt)
@@ -1216,19 +1224,21 @@ class ServeEngine:
         req, slot = task.req, task.slot
         ps = self.scfg.page_size
         start, n = task.start, task.length
-        s_pad = -(-n // ps) * ps
-        toks = np.zeros((1, s_pad), np.int32)
-        toks[0, :n] = req.target[start:start + n]
-        # copy: the row is a view into the live allocator table (see
-        # _sync_table for the zero-copy aliasing hazard)
-        page_row = jnp.asarray(self.allocator.table[slot].copy(), jnp.int32)
-        batch = {"tokens": jnp.asarray(toks),
-                 "offset": jnp.asarray([start], jnp.int32),
-                 "true_lens": jnp.asarray([start + n], jnp.int32)}
-        self.jit_calls += 1
-        w0 = self._wall()
-        logits, self.cache, _ = self._prefill_chunk(
-            self.params, batch, self.cache, page_row)
+        with self.tm.phase("engine.chunk_launch", rows=1, live_rows=1,
+                           true_tokens=n):
+            s_pad = -(-n // ps) * ps
+            toks = np.zeros((1, s_pad), np.int32)
+            toks[0, :n] = req.target[start:start + n]
+            # copy: the row is a view into the live allocator table (see
+            # _sync_table for the zero-copy aliasing hazard)
+            page_row = jnp.asarray(self.allocator.table[slot].copy(),
+                                   jnp.int32)
+            batch = {"tokens": jnp.asarray(toks),
+                     "offset": jnp.asarray([start], jnp.int32),
+                     "true_lens": jnp.asarray([start + n], jnp.int32)}
+            self.jit_calls += 1
+            logits, self.cache, _ = self._prefill_chunk(
+                self.params, batch, self.cache, page_row)
         req.prefill_pos = start + n
         self.prefill_tokens += n
         self.sched.note_work(n)
@@ -1238,7 +1248,7 @@ class ServeEngine:
                           kv_pages_read=self._row_pages(slot, start + n),
                           kv_pages_written=self._span_pages(start,
                                                             start + n),
-                          new_kv_tokens=n, wall0=w0, wall1=self._wall())
+                          new_kv_tokens=n)
         if req.prefill_pos >= len(req.target):
             self.lens = self.lens.at[slot].set(len(req.target))
             self._lens_np[slot] = len(req.target)
@@ -1264,46 +1274,48 @@ class ServeEngine:
 
         Host accounting walks the tasks in plan order so work-clock
         TTFT/TBT match the sequential per-chunk path bit for bit."""
-        pack = self.sched.pack_chunks(tasks)
-        finals = []
-        for t in tasks:
-            t.req.prefill_pos = t.start + t.length
-            self.prefill_tokens += t.length
-            self.sched.note_work(t.length)
-            self.sched.chunks_run += 1
-            if t.req.prefill_pos >= len(t.req.target):
-                t.req.state = RequestState.DECODING
-                self._phase(t.req, "DECODING", t.slot)
-                self._table_dirty = True     # unmask the slot's device row
-                self._lens_np[t.slot] = len(t.req.target)
-                finals.append((t.req, t.slot, self.sched.work_clock))
-        # per-row block-table rows from the host allocator (dead rows keep
-        # the all-null table so every page walk lands on the null page)
-        tables = np.zeros((pack.tokens.shape[0],
-                           self.allocator.table.shape[1]), np.int32)
-        live = pack.row_slots >= 0
-        tables[live] = self.allocator.table[pack.row_slots[live]]
-        batch = {"tokens": jnp.asarray(pack.tokens),
-                 "offset": jnp.asarray(pack.offsets),
-                 "true_lens": jnp.asarray(pack.true_lens),
-                 "final_slot": jnp.asarray(pack.final_slots)}
-        self.jit_calls += 1
-        self.sched.packs_run += 1
-        w0 = self._wall()
-        self.cache, self.tokens, self.lens = self._prefill_chunks(
-            self.params, batch, self.cache, jnp.asarray(tables),
-            self.tokens, self.lens, self._next_key())
-        n_true = sum(t.length for t in tasks)
-        self._note_launch(
-            "chunk_batch", rows=int(pack.tokens.shape[0]),
-            live_rows=len(tasks), true_tokens=n_true,
-            padded_tokens=int(pack.tokens.shape[0] * pack.tokens.shape[1]),
-            kv_pages_read=sum(self._row_pages(t.slot, t.start + t.length)
-                              for t in tasks),
-            kv_pages_written=sum(self._span_pages(t.start, t.start
-                                                  + t.length)
-                                 for t in tasks),
-            new_kv_tokens=n_true, wall0=w0, wall1=self._wall())
+        with self.tm.phase("engine.chunk_launch") as args:
+            pack = self.sched.pack_chunks(tasks)
+            finals = []
+            for t in tasks:
+                t.req.prefill_pos = t.start + t.length
+                self.prefill_tokens += t.length
+                self.sched.note_work(t.length)
+                self.sched.chunks_run += 1
+                if t.req.prefill_pos >= len(t.req.target):
+                    t.req.state = RequestState.DECODING
+                    self._phase(t.req, "DECODING", t.slot)
+                    self._table_dirty = True    # unmask the slot's row
+                    self._lens_np[t.slot] = len(t.req.target)
+                    finals.append((t.req, t.slot, self.sched.work_clock))
+            # per-row block-table rows from the host allocator (dead rows
+            # keep the all-null table so every page walk lands on the null
+            # page)
+            rows, width = pack.tokens.shape
+            tables = np.zeros((rows, self.allocator.table.shape[1]),
+                              np.int32)
+            live = pack.row_slots >= 0
+            tables[live] = self.allocator.table[pack.row_slots[live]]
+            batch = {"tokens": jnp.asarray(pack.tokens),
+                     "offset": jnp.asarray(pack.offsets),
+                     "true_lens": jnp.asarray(pack.true_lens),
+                     "final_slot": jnp.asarray(pack.final_slots)}
+            self.jit_calls += 1
+            self.sched.packs_run += 1
+            self.cache, self.tokens, self.lens = self._prefill_chunks(
+                self.params, batch, self.cache, jnp.asarray(tables),
+                self.tokens, self.lens, self._next_key())
+            n_true = sum(t.length for t in tasks)
+            args.update(rows=rows, live_rows=len(tasks), true_tokens=n_true)
+            self._note_launch(
+                "chunk_batch", padded_tokens=rows * width,
+                kv_pages_read=sum(self._row_pages(t.slot,
+                                                  t.start + t.length)
+                                  for t in tasks),
+                kv_pages_written=sum(self._span_pages(t.start,
+                                                      t.start + t.length)
+                                     for t in tasks),
+                new_kv_tokens=n_true, **args)
         return finals
 
     def _run_spec_verify(self, tasks: List[DraftTask]) -> SpecBatch:
@@ -1317,42 +1329,43 @@ class ServeEngine:
         dead - rollback is free), and leaves the per-row acceptance
         counts in _spec_nacc for the host to fetch WITH the tick's
         tokens - no extra device->host sync."""
-        pack = self.sched.pack_drafts(tasks, self._lens_np)
-        # per-row block-table rows from the host allocator (dead rows
-        # keep the all-null table, copied - never aliased - like
-        # _run_chunk_batch)
-        tables = np.zeros((pack.tokens.shape[0],
-                           self.allocator.table.shape[1]), np.int32)
-        live = pack.row_slots < self.scfg.max_batch
-        tables[live] = self.allocator.table[pack.row_slots[live]]
-        batch = {"tokens": jnp.asarray(pack.tokens),
-                 "offset": jnp.asarray(pack.offsets),
-                 "true_lens": jnp.asarray(pack.true_lens),
-                 "q_lens": jnp.asarray(pack.q_lens),
-                 "draft_lens": jnp.asarray(pack.draft_lens),
-                 "row_slot": jnp.asarray(pack.row_slots)}
-        self.jit_calls += 1
-        self.decode_launches += 1
-        ps = self.scfg.page_size
-        self._note_kv_pages_read(sum(-(-int(t) // ps)
-                                     for t in pack.true_lens[live]))
-        w0 = self._wall()
-        self.cache, self.tokens, self.lens, self._spec_nacc = \
-            self._spec_verify(self.params, batch, self.cache,
-                              jnp.asarray(tables), self.tokens, self.lens,
-                              self._next_key())
-        n_q = sum(1 + len(t.draft) for t in pack.tasks)
-        self._note_launch(
-            "spec_verify", rows=int(pack.tokens.shape[0]),
-            live_rows=len(pack.tasks), true_tokens=n_q,
-            padded_tokens=int(pack.tokens.shape[0] * pack.tokens.shape[1]),
-            kv_pages_read=sum(self._row_pages(t.slot,
-                                              t.offset + 1 + len(t.draft))
-                              for t in pack.tasks),
-            kv_pages_written=sum(
-                self._span_pages(t.offset, t.offset + 1 + len(t.draft))
-                for t in pack.tasks),
-            new_kv_tokens=n_q, wall0=w0, wall1=self._wall())
+        with self.tm.phase("engine.verify_launch") as args:
+            pack = self.sched.pack_drafts(tasks, self._lens_np)
+            # per-row block-table rows from the host allocator (dead rows
+            # keep the all-null table, copied - never aliased - like
+            # _run_chunk_batch)
+            rows, width = pack.tokens.shape
+            tables = np.zeros((rows, self.allocator.table.shape[1]),
+                              np.int32)
+            live = pack.row_slots < self.scfg.max_batch
+            tables[live] = self.allocator.table[pack.row_slots[live]]
+            batch = {"tokens": jnp.asarray(pack.tokens),
+                     "offset": jnp.asarray(pack.offsets),
+                     "true_lens": jnp.asarray(pack.true_lens),
+                     "q_lens": jnp.asarray(pack.q_lens),
+                     "draft_lens": jnp.asarray(pack.draft_lens),
+                     "row_slot": jnp.asarray(pack.row_slots)}
+            self.jit_calls += 1
+            self.decode_launches += 1
+            ps = self.scfg.page_size
+            self._note_kv_pages_read(sum(-(-int(t) // ps)
+                                         for t in pack.true_lens[live]))
+            self.cache, self.tokens, self.lens, self._spec_nacc = \
+                self._spec_verify(self.params, batch, self.cache,
+                                  jnp.asarray(tables), self.tokens,
+                                  self.lens, self._next_key())
+            n_q = sum(1 + len(t.draft) for t in pack.tasks)
+            args.update(rows=rows, live_rows=len(pack.tasks),
+                        true_tokens=n_q)
+            self._note_launch(
+                "spec_verify", padded_tokens=rows * width,
+                kv_pages_read=sum(self._row_pages(
+                    t.slot, t.offset + 1 + len(t.draft))
+                    for t in pack.tasks),
+                kv_pages_written=sum(
+                    self._span_pages(t.offset, t.offset + 1 + len(t.draft))
+                    for t in pack.tasks),
+                new_kv_tokens=n_q, **args)
         return pack
 
     # ------------------------------------------------------------------
@@ -1476,66 +1489,64 @@ class ServeEngine:
         is gone).  batched=False keeps one launch per chunk and per-slot
         emission: the sequential parity oracle."""
         w0 = self.sched.work_clock
-        wp0 = self._wall()
         # admission FIRST (it can preempt: a decoding victim shed here
         # must not join this tick's decode batch): reserve slots + pages
         # for as many queued requests as the policy head allows (no
         # prompt computation yet).  When the head cannot be placed and
         # outranks a running request, shed victims one at a time and
         # retry; otherwise head-of-line backpressure as before.
-        while True:
-            req = self.sched.peek()
-            if req is None:
-                break
-            resuming = req.state is RequestState.RESUMING
-            placed = False
+        with self.tm.phase("engine.admit"):
             while True:
-                slot = self._free_slot()
-                if slot is not None and self._reserve_chunked(slot, req):
-                    placed = True
+                req = self.sched.peek()
+                if req is None:
                     break
-                if not self._try_preempt(req):
+                resuming = req.state is RequestState.RESUMING
+                placed = False
+                while True:
+                    slot = self._free_slot()
+                    if slot is not None \
+                            and self._reserve_chunked(slot, req):
+                        placed = True
+                        break
+                    if not self._try_preempt(req):
+                        break
+                if not placed:
                     break
-            if not placed:
-                break
-            self.sched.pop(req)
-            if resuming:
-                self.sched.resumes += 1
-                req.n_resumes += 1
-                self._event(req, "RESUME", req.slot)
+                self._pop_admitted(req)
+                if resuming:
+                    self.sched.resumes += 1
+                    req.n_resumes += 1
+                    self._event(req, "RESUME", req.slot)
         if self._table_dirty:
             # a preemption zeroed a lane (or freed pages that admission
             # just re-allocated): the device table must mask it to the
             # null page BEFORE this tick's launches touch the pool
             self._sync_table()
-        decode_slots = [i for i, r in enumerate(self.slots)
-                        if r is not None
-                        and r.state is RequestState.DECODING]
-        prefilling = [(i, r) for i, r in enumerate(self.slots)
-                      if r is not None
-                      and r.state is RequestState.PREFILLING]
-        # speculative drafting: DECODING slots may propose chains out of
-        # the budget left after every decode slot took its guaranteed
-        # token; prefill planning gets what remains after drafts, so the
-        # tick's total work stays bounded by tick_token_budget
-        spec_tasks: List[DraftTask] = []
-        spec_tokens = 0
-        if self.speculative and decode_slots:
-            room = self.scfg.tick_token_budget - len(decode_slots)
-            spec_tasks = self.sched.plan_drafts(
-                [(i, self.slots[i]) for i in decode_slots], room)
-            spec_tokens = sum(len(t.draft) for t in spec_tasks)
-        budget = self.sched.prefill_budget(len(decode_slots) + spec_tokens)
-        chunks = self.sched.plan_chunks(prefilling, budget)
-        self._tick_profile = (len(chunks), len(decode_slots))
-        tr = self.tm.tracer
-        if tr is not None:
-            # the tick's host-side planning phase: admission (incl. any
-            # preemption), draft planning, and chunk planning
-            tr.add_span("plan", "tick", TRACK_ENGINE, self.sched.ticks,
-                        w0, self.sched.work_clock, wp0, tr.now(),
-                        n_chunks=len(chunks), n_decode=len(decode_slots),
+        with self.tm.phase("engine.plan") as plan:
+            decode_slots = [i for i, r in enumerate(self.slots)
+                            if r is not None
+                            and r.state is RequestState.DECODING]
+            prefilling = [(i, r) for i, r in enumerate(self.slots)
+                          if r is not None
+                          and r.state is RequestState.PREFILLING]
+            # speculative drafting: DECODING slots may propose chains out
+            # of the budget left after every decode slot took its
+            # guaranteed token; prefill planning gets what remains after
+            # drafts, so the tick's total work stays bounded by
+            # tick_token_budget
+            spec_tasks: List[DraftTask] = []
+            spec_tokens = 0
+            if self.speculative and decode_slots:
+                room = self.scfg.tick_token_budget - len(decode_slots)
+                spec_tasks = self.sched.plan_drafts(
+                    [(i, self.slots[i]) for i in decode_slots], room)
+                spec_tokens = sum(len(t.draft) for t in spec_tasks)
+            budget = self.sched.prefill_budget(len(decode_slots)
+                                               + spec_tokens)
+            chunks = self.sched.plan_chunks(prefilling, budget)
+            plan.update(n_chunks=len(chunks), n_decode=len(decode_slots),
                         n_drafts=len(spec_tasks))
+        self._tick_profile = (len(chunks), len(decode_slots))
         finals = []
         if chunks:
             if self.scfg.batched:
@@ -1553,45 +1564,43 @@ class ServeEngine:
         if spec_tasks:
             spec_pack = self._run_spec_verify(spec_tasks)
         if plain_slots:
-            live = np.zeros((len(self.slots),), bool)
-            live[plain_slots] = True
-            self.jit_calls += 1
-            self.decode_launches += 1
-            self._note_kv_pages_read(sum(
-                -(-(int(self._lens_np[i]) + 1) // self.scfg.page_size)
-                for i in plain_slots))
-            pages_read = sum(self._row_pages(i, int(self._lens_np[i]) + 1)
-                             for i in plain_slots)
-            lw0 = self._wall()
-            self.cache, self.tokens, self.lens = self._decode_fused(
-                self.params, self.cache, self.tokens, self.lens,
-                jnp.asarray(live), self._next_key())
-            self._note_launch("decode", rows=len(self.slots),
-                              live_rows=len(plain_slots),
-                              true_tokens=len(plain_slots),
-                              padded_tokens=len(self.slots),
-                              kv_pages_read=pages_read,
-                              kv_pages_written=len(plain_slots),
-                              new_kv_tokens=len(plain_slots), wall0=lw0,
-                              wall1=self._wall())
+            with self.tm.phase("engine.decode_launch",
+                               live_rows=len(plain_slots)):
+                live = np.zeros((len(self.slots),), bool)
+                live[plain_slots] = True
+                self.jit_calls += 1
+                self.decode_launches += 1
+                self._note_kv_pages_read(sum(
+                    -(-(int(self._lens_np[i]) + 1) // self.scfg.page_size)
+                    for i in plain_slots))
+                pages_read = sum(self._row_pages(i,
+                                                 int(self._lens_np[i]) + 1)
+                                 for i in plain_slots)
+                self.cache, self.tokens, self.lens = self._decode_fused(
+                    self.params, self.cache, self.tokens, self.lens,
+                    jnp.asarray(live), self._next_key())
+                self._note_launch("decode", rows=len(self.slots),
+                                  live_rows=len(plain_slots),
+                                  true_tokens=len(plain_slots),
+                                  padded_tokens=len(self.slots),
+                                  kv_pages_read=pages_read,
+                                  kv_pages_written=len(plain_slots),
+                                  new_kv_tokens=len(plain_slots))
             self.sched.note_work(len(plain_slots))
             self._lens_np[plain_slots] += 1
         gen_work = len(plain_slots)
         if finals or plain_slots or spec_pack is not None:
             # THE device->host transfer: every sampled token of the tick
             # (plus, speculating, every chain's acceptance count)
-            wf0 = self._wall()
-            if spec_pack is not None:
-                self.host_syncs += 1
-                toks, naccs = (np.asarray(x) for x in jax.device_get(
-                    (self.tokens, self._spec_nacc)))
-            else:
-                toks = self._fetch_tokens()
-            tr = self.tm.tracer
-            if tr is not None:
-                tr.add_span("device_get", "tick", TRACK_ENGINE,
-                            self.sched.ticks, self.sched.work_clock,
-                            self.sched.work_clock, wf0, tr.now())
+            with self.tm.phase("engine.device_get"):
+                if spec_pack is not None:
+                    self.host_syncs += 1
+                    toks, naccs = (np.asarray(x) for x in jax.device_get(
+                        (self.tokens, self._spec_nacc)))
+                else:
+                    toks = self._fetch_tokens()
+        # (nothing was fetched only when there is nothing to emit)
+        with self.tm.phase("engine.emit"):
             for req, slot, work in finals:
                 if self._emit(req, int(toks[slot, 0]), work=work):
                     self._finish(req)
@@ -1616,12 +1625,12 @@ class ServeEngine:
                 req = self.slots[i]
                 if self._emit(req, int(toks[i, 0])):
                     self._finish(req)
-        self.sched.note_tick(gen_work,
-                             self.sched.work_clock - w0 - gen_work)
-        if self._finished_this_tick:
-            self._maybe_evict_watermark()
-        if self._table_dirty:
-            self._sync_table()
+            self.sched.note_tick(gen_work,
+                                 self.sched.work_clock - w0 - gen_work)
+            if self._finished_this_tick:
+                self._maybe_evict_watermark()
+            if self._table_dirty:
+                self._sync_table()
         return self._finished_this_tick
 
     # ------------------------------------------------------------------
@@ -1667,34 +1676,27 @@ class ServeEngine:
         """One engine iteration.  Monolithic: admit (full prefills) + one
         batched decode step.  Chunked: one token-budgeted round of decode
         + prefill chunks.  Returns requests that finished this tick.
-        Every tick appends a dispatch-accounting row to launch_log:
-        (jit_calls, host_syncs, host_wall_s, n_chunk_tasks, n_decode)."""
-        self._finished_this_tick = []
-        self._tick_profile = (0, 0)
-        self._expire_deadlines()
-        j0, s0 = self.jit_calls, self.host_syncs
-        tick0 = self.sched.ticks
-        work0 = self.sched.work_clock
-        wt0 = self._wall()
-        t0 = time.perf_counter()
-        out = self._tick_chunked() if self.chunked \
-            else self._tick_monolithic()
-        self.tm.ticks.append(TickRecord(
-            self.jit_calls - j0, self.host_syncs - s0,
-            time.perf_counter() - t0, *self._tick_profile))
-        tr = self.tm.tracer
-        if tr is not None:
-            tr.add_span("tick", "tick", TRACK_ENGINE, tick0, work0,
-                        self.sched.work_clock, wt0, tr.now(),
-                        jit_calls=self.jit_calls - j0,
-                        host_syncs=self.host_syncs - s0,
-                        n_chunks=self._tick_profile[0],
-                        n_decode=self._tick_profile[1])
+        Every tick appends its dispatch accounting to self.tm.ticks and
+        runs inside the engine.tick phase (Telemetry.phase)."""
+        with self.tm.phase("engine.tick") as args:
+            self._finished_this_tick = []
+            self._tick_profile = (0, 0)
+            self._expire_deadlines()
+            j0, s0 = self.jit_calls, self.host_syncs
+            t0 = time.perf_counter()
+            out = self._tick_chunked() if self.chunked \
+                else self._tick_monolithic()
+            rec = TickRecord(self.jit_calls - j0, self.host_syncs - s0,
+                             time.perf_counter() - t0, *self._tick_profile)
+            self.tm.ticks.append(rec)
+            args.update(jit_calls=rec.jit_calls, host_syncs=rec.host_syncs,
+                        n_chunks=rec.n_chunk_tasks, n_decode=rec.n_decode)
         return out
 
     def _tick_monolithic(self) -> List[Request]:
         w0 = self.sched.work_clock
-        self._admit()
+        with self.tm.phase("engine.admit"):
+            self._admit()
         if self._finished_this_tick and self.paged:
             # a request can finish AT admission (stop token / length 1 on
             # its first sampled token); its pages just went back to the
@@ -1715,40 +1717,43 @@ class ServeEngine:
         # masked token/length updates, then ONE device->host transfer for
         # every lane's sampled token (was: one int() sync + two .at[i]
         # dispatches PER SLOT)
-        live = np.zeros((len(self.slots),), bool)
-        live[active] = True
-        self.jit_calls += 1
-        self.decode_launches += 1
-        pages_read = 0
-        if self.paged:
-            self._note_kv_pages_read(sum(
-                -(-(int(self._lens_np[i]) + 1) // self.scfg.page_size)
-                for i in active))
-            pages_read = sum(self._row_pages(i, int(self._lens_np[i]) + 1)
-                             for i in active)
-        lw0 = self._wall()
-        self.cache, self.tokens, self.lens = self._decode_fused(
-            self.params, self.cache, self.tokens, self.lens,
-            jnp.asarray(live), self._next_key())
-        self._note_launch("decode", rows=len(self.slots),
-                          live_rows=len(active), true_tokens=len(active),
-                          padded_tokens=len(self.slots),
-                          kv_pages_read=pages_read,
-                          kv_pages_written=len(active) if self.paged else 0,
-                          new_kv_tokens=len(active), wall0=lw0,
-                          wall1=self._wall())
+        with self.tm.phase("engine.decode_launch", live_rows=len(active)):
+            live = np.zeros((len(self.slots),), bool)
+            live[active] = True
+            self.jit_calls += 1
+            self.decode_launches += 1
+            pages_read = 0
+            if self.paged:
+                self._note_kv_pages_read(sum(
+                    -(-(int(self._lens_np[i]) + 1) // self.scfg.page_size)
+                    for i in active))
+                pages_read = sum(self._row_pages(i,
+                                                 int(self._lens_np[i]) + 1)
+                                 for i in active)
+            self.cache, self.tokens, self.lens = self._decode_fused(
+                self.params, self.cache, self.tokens, self.lens,
+                jnp.asarray(live), self._next_key())
+            self._note_launch("decode", rows=len(self.slots),
+                              live_rows=len(active), true_tokens=len(active),
+                              padded_tokens=len(self.slots),
+                              kv_pages_read=pages_read,
+                              kv_pages_written=len(active)
+                              if self.paged else 0,
+                              new_kv_tokens=len(active))
         self.sched.note_work(len(active))
         self._lens_np[active] += 1
-        toks = self._fetch_tokens()
-        for i in active:
-            req = self.slots[i]
-            if self._emit(req, int(toks[i, 0])):
-                self._finish(req)
-        self.sched.note_tick(len(active),
-                             self.sched.work_clock - w0 - len(active))
-        if self._finished_this_tick and self.paged:
-            self._maybe_evict_watermark()
-            self._sync_table()
+        with self.tm.phase("engine.device_get"):
+            toks = self._fetch_tokens()
+        with self.tm.phase("engine.emit"):
+            for i in active:
+                req = self.slots[i]
+                if self._emit(req, int(toks[i, 0])):
+                    self._finish(req)
+            self.sched.note_tick(len(active),
+                                 self.sched.work_clock - w0 - len(active))
+            if self._finished_this_tick and self.paged:
+                self._maybe_evict_watermark()
+                self._sync_table()
         return self._finished_this_tick
 
     def run_until_done(self, max_ticks: int = 10_000,

@@ -131,10 +131,14 @@ class Request:
     # snapshot replaces `prompt` as the chunk path's target (None until the
     # request is preempted mid-decode)
     resume_tokens: Optional[List[int]] = None
-    # --- latency accounting (wall seconds + engine work-clock tokens) ----
+    # --- latency accounting (time.perf_counter seconds + engine work-clock
+    # tokens) --------------------------------------------------------------
     # stamps are carried across preempt/resume, never reset: TTFT/TBT stay
-    # monotone and a resume delay shows up as a (real) latency gap
+    # monotone and a resume delay shows up as a (real) latency gap.
+    # t_admit: the first slot and page reservation (None while queued), so
+    # TTFT splits into the queue wait and the prefill that follows it
     t_submit: float = 0.0
+    t_admit: Optional[float] = None
     w_submit: int = 0
     token_wall: List[float] = field(default_factory=list)
     token_work: List[int] = field(default_factory=list)
@@ -338,7 +342,7 @@ class TokenBudgetScheduler:
 
     # -- queue / admission policy -----------------------------------------
     def submit(self, req: Request):
-        req.t_submit = time.time()
+        req.t_submit = time.perf_counter()
         req.w_submit = self.work_clock
         self.queue.append(req)
 

@@ -26,6 +26,12 @@ sit on:
                     reproducible and testable, while the wall-clock view
                     stays human-meaningful in Perfetto.
 
+  Telemetry.phase   the one way to name an engine phase (engine.tick,
+                    engine.admit, engine.device_get, ...): always a
+                    jax.profiler.TraceAnnotation, so a profiler run lays
+                    the engine's phases on the device ops' clock, and with
+                    the tracer on also a span on the engine track.
+
   LaunchRecord      per kernel launch: rows launched, true vs padded
                     tokens, and KV pages read / written - counted from
                     the PageAllocator's block-table accounting, so the
@@ -39,9 +45,9 @@ sit on:
 
   export_chrome_trace  Chrome trace-event JSON (the format Perfetto and
                     chrome://tracing load directly): request lifecycle
-                    spans on per-slot tracks, engine phases and kernel
-                    launches on engine tracks, preempt/resume/speculation
-                    instants as arrows-free instant events.
+                    spans on per-slot tracks, nested engine phases on the
+                    engine track, preempt/resume/speculation instants as
+                    arrows-free instant events.
 
 Everything here is host-side Python over counts the engine already
 computes: enabling telemetry adds ZERO jitted calls and ZERO device->host
@@ -50,12 +56,15 @@ and the spans themselves never read a device array.
 """
 from __future__ import annotations
 
+import contextlib
 import json
 import time
 from collections import deque
 from dataclasses import dataclass, field
-from typing import (Any, Dict, Iterable, Iterator, List, Optional, Sequence,
-                    Tuple)
+from typing import (Any, Callable, Dict, Iterable, Iterator, List, Optional,
+                    Sequence, Tuple)
+
+from jax.profiler import TraceAnnotation
 
 __all__ = [
     "Counter", "Gauge", "Histogram", "LaunchRecord", "MetricError",
@@ -287,7 +296,7 @@ class MetricsRegistry:
 
 # track ids for the Chrome-trace export: requests live on per-slot tracks
 # (track = slot index); these engine-level tracks sit alongside them
-TRACK_ENGINE = -1      # per-tick engine phases (plan / launches / fetch)
+TRACK_ENGINE = -1      # engine phases (Telemetry.phase), prefix instants
 TRACK_QUEUE = -2       # requests waiting for admission (QUEUED / RESUMING)
 
 
@@ -297,7 +306,7 @@ class Span:
     phase.  `work0`/`work1` are deterministic work-clock stamps; `wall0`/
     `wall1` are seconds since the tracer's epoch."""
     name: str
-    cat: str                     # "request" | "tick" | "launch"
+    cat: str                     # "request" | "phase"
     track: int                   # slot index, TRACK_ENGINE, or TRACK_QUEUE
     tick: int                    # engine tick index at span START
     work0: int
@@ -411,17 +420,12 @@ class LaunchRecord:
 
 @dataclass(frozen=True)
 class TickRecord:
-    """One tick's dispatch accounting - the typed record behind the
-    legacy ``launch_log`` 5-tuple compatibility view."""
+    """One tick's dispatch accounting (ServeEngine.tm.ticks)."""
     jit_calls: int
     host_syncs: int
     host_wall_s: float
     n_chunk_tasks: int
     n_decode: int
-
-    def as_tuple(self) -> tuple:
-        return (self.jit_calls, self.host_syncs, self.host_wall_s,
-                self.n_chunk_tasks, self.n_decode)
 
 
 # ===========================================================================
@@ -431,14 +435,17 @@ class TickRecord:
 class Telemetry:
     """One engine's telemetry surface: the shared metrics registry
     (always on - it IS the stats() backing store), the span tracer
-    (optional, ServeConfig.telemetry), per-launch movement records, and
-    the per-tick dispatch records behind the launch_log view."""
+    (optional, ServeConfig.telemetry), the engine's phases, per-launch
+    movement records, and per-tick dispatch records.  `clock` returns the
+    engine's (tick index, work clock) for the phase spans' stamps."""
 
     def __init__(self, registry: Optional[MetricsRegistry] = None,
                  tracer: Optional[SpanTracer] = None,
-                 launch_capacity: int = 65536):
+                 launch_capacity: int = 65536,
+                 clock: Callable[[], Tuple[int, int]] = lambda: (0, 0)):
         self.registry = registry if registry is not None else MetricsRegistry()
         self.tracer = tracer
+        self.clock = clock
         self.launches: deque = deque(maxlen=launch_capacity)
         self.ticks: List[TickRecord] = []
         # open request-phase spans: uid -> (phase, track, tick0, work0, wall0)
@@ -481,20 +488,26 @@ class Telemetry:
         """uid -> open phase name (diagnostics; drained traces are empty)."""
         return {uid: rec[0] for uid, rec in self._open.items()}
 
-    # -- launches ----------------------------------------------------------
-    def launch(self, rec: LaunchRecord, wall0: float, wall1: float):
-        """Record one kernel launch: a movement record plus a span on the
-        engine track."""
-        self.launches.append(rec)
+    # -- engine phases -----------------------------------------------------
+    @contextlib.contextmanager
+    def phase(self, name: str, **args) -> Iterator[Dict[str, Any]]:
+        """One engine phase: a jax.profiler.TraceAnnotation always (it
+        records nothing unless a profiler runs, and then sits on the
+        profiler's clock beside the device ops) and, with the tracer on, a
+        span on the engine track.  Yields `args`; the body may add values
+        it learns inside the phase, and both records carry them.  Phases
+        nest, so a phase's own time is its span minus its children."""
         tr = self.tracer
         if tr is not None:
-            tr.add_span(rec.kind, "launch", TRACK_ENGINE, rec.tick,
-                        rec.work_clock, rec.work_clock, wall0, wall1,
-                        rows=rec.rows, live_rows=rec.live_rows,
-                        true_tokens=rec.true_tokens,
-                        padded_tokens=rec.padded_tokens,
-                        kv_pages_read=rec.kv_pages_read,
-                        kv_pages_written=rec.kv_pages_written)
+            tick, work0 = self.clock()
+            wall0 = tr.now()
+        with TraceAnnotation(name) as ann:
+            yield args
+            if args and ann.is_enabled():
+                ann.set_metadata(**args)
+        if tr is not None:
+            tr.add_span(name, "phase", TRACK_ENGINE, tick, work0,
+                        self.clock()[1], wall0, tr.now(), **args)
 
 
 # ===========================================================================
@@ -622,18 +635,15 @@ def movement_breakdown(launches: Iterable[LaunchRecord], cfg, scfg,
 
 _PID_ENGINE = 0
 _PID_REQUESTS = 1
-# engine-track tids inside the engine process
-_TID_TICKS = 0
-_TID_LAUNCHES = 1
 
 
 def _track_ids(track: int, n_slots: int) -> Tuple[int, int]:
-    """Map a telemetry track to a (pid, tid) pair: engine phases and
-    launches live in the engine process; request phases live in the
-    requests process, one thread per slot, with the admission queue as
-    the thread after the last slot."""
+    """Map a telemetry track to a (pid, tid) pair: engine phases live on
+    the engine process's one thread (they nest); request phases live in
+    the requests process, one thread per slot, with the admission queue
+    as the thread after the last slot."""
     if track == TRACK_ENGINE:
-        return _PID_ENGINE, _TID_LAUNCHES
+        return _PID_ENGINE, 0
     if track == TRACK_QUEUE:
         return _PID_REQUESTS, n_slots
     return _PID_REQUESTS, track
@@ -664,10 +674,8 @@ def export_chrome_trace(path, tracer: SpanTracer, n_slots: int,
     events: List[Dict[str, Any]] = [
         {"ph": "M", "pid": _PID_ENGINE, "tid": 0, "name": "process_name",
          "args": {"name": "engine"}},
-        {"ph": "M", "pid": _PID_ENGINE, "tid": _TID_TICKS,
-         "name": "thread_name", "args": {"name": "ticks"}},
-        {"ph": "M", "pid": _PID_ENGINE, "tid": _TID_LAUNCHES,
-         "name": "thread_name", "args": {"name": "launches"}},
+        {"ph": "M", "pid": _PID_ENGINE, "tid": 0, "name": "thread_name",
+         "args": {"name": "phases"}},
         {"ph": "M", "pid": _PID_REQUESTS, "tid": 0, "name": "process_name",
          "args": {"name": "requests"}},
         {"ph": "M", "pid": _PID_REQUESTS, "tid": n_slots,
@@ -680,8 +688,6 @@ def export_chrome_trace(path, tracer: SpanTracer, n_slots: int,
     for rec in tracer.records():
         if isinstance(rec, Span):
             pid, tid = _track_ids(rec.track, n_slots)
-            if rec.cat == "tick":
-                pid, tid = _PID_ENGINE, _TID_TICKS
             ts, dur = ts_span(rec)
             events.append({"ph": "X", "name": rec.name, "cat": rec.cat,
                            "pid": pid, "tid": tid, "ts": ts, "dur": dur,

@@ -251,15 +251,15 @@ def test_one_launch_per_busy_tick(model_f32):
                            and r.state is RequestState.PREFILLING
                            for r in eng.slots):
         eng.tick()
-        calls, syncs, _wall, n_chunks, n_decode = eng.launch_log[-1]
-        if n_chunks and n_decode:
+        t = eng.tm.ticks[-1]
+        if t.n_chunk_tasks and t.n_decode:
             busy += 1
-            assert calls == 2, eng.launch_log[-1]
-            assert syncs == 1, eng.launch_log[-1]
-        assert calls <= 2 and syncs <= 1
+            assert t.jit_calls == 2, t
+            assert t.host_syncs == 1, t
+        assert t.jit_calls <= 2 and t.host_syncs <= 1
     assert busy >= 3          # the steady-state shape really occurred
     eng.run_until_done(max_ticks=10_000)
-    assert all(r[0] <= 2 and r[1] <= 1 for r in eng.launch_log)
+    assert all(t.jit_calls <= 2 and t.host_syncs <= 1 for t in eng.tm.ticks)
 
 
 def test_monolithic_tick_single_sync(model_f32):
@@ -272,9 +272,9 @@ def test_monolithic_tick_single_sync(model_f32):
     eng.tick()                                  # admissions + first decode
     for _ in range(4):                          # pure decode ticks
         eng.tick()
-        calls, syncs, _wall, _c, n_decode = eng.launch_log[-1]
-        assert n_decode == 3
-        assert calls == 1 and syncs == 1, eng.launch_log[-1]
+        t = eng.tm.ticks[-1]
+        assert t.n_decode == 3
+        assert t.jit_calls == 1 and t.host_syncs == 1, t
     eng.run_until_done(max_ticks=10_000)
 
 

@@ -13,8 +13,9 @@ Chrome trace-event schema validation for both the wall and the work
 clock, launch-record KV-page accounting against the PageAllocator (the
 block-table-derived per-launch counts must sum exactly to the engine's
 analytic kv_pages_read counter), the movement-breakdown byte model,
-preempt/resume lifecycle instants, the speculative counters, and the
-legacy launch_log / stats() compatibility views.
+preempt/resume lifecycle instants, the speculative counters, the
+per-tick dispatch records and stats() views, and the engine's phases in
+a jax.profiler trace plus the admission stamps and queue-wait histogram.
 """
 import json
 from pathlib import Path
@@ -256,10 +257,10 @@ def test_telemetry_off_is_bit_identical_and_free(model_f32):
     outs_on, eng_on = serve_all(model, params, _base(telemetry=True),
                                 prompts)
     assert outs_on == outs_off
-    # launch_log rows are (jit_calls, host_syncs, host_wall_s, n_chunks,
-    # n_decode); compare everything but the wall-time field
+    # the per-tick dispatch records, everything but the wall-time field
     def dispatch(eng):
-        return [(t[0], t[1], t[3], t[4]) for t in eng.launch_log]
+        return [(t.jit_calls, t.host_syncs, t.n_chunk_tasks, t.n_decode)
+                for t in eng.tm.ticks]
     assert dispatch(eng_on) == dispatch(eng_off)
     assert eng_on.jit_calls == eng_off.jit_calls
     assert eng_on.host_syncs == eng_off.host_syncs
@@ -490,16 +491,15 @@ def test_spec_counters_reach_registry(model_f32):
 # ===========================================================================
 
 def test_launch_log_and_stats_compat(traced_run):
-    """launch_log stays the 5-tuple view PR-4-era consumers read, and
-    stats() keeps its flat keys - both now computed from the registry
-    and the typed TickRecords."""
+    """The typed per-tick TickRecords account for every jitted call and
+    sync, and stats() keeps its flat keys - both computed from the
+    registry and the TickRecords."""
     eng, outs, _ = traced_run
-    log = eng.launch_log
-    assert log and all(len(t) == 5 for t in log)
-    assert all(isinstance(t, tuple) for t in log)
-    assert sum(t[0] for t in log) == eng.jit_calls
-    assert sum(t[1] for t in log) == eng.host_syncs
-    assert [t.as_tuple() for t in eng.tm.ticks] == log
+    ticks = eng.tm.ticks
+    assert ticks and all(isinstance(t, TickRecord) for t in ticks)
+    assert sum(t.jit_calls for t in ticks) == eng.jit_calls
+    assert sum(t.host_syncs for t in ticks) == eng.host_syncs
+    assert all(t.host_wall_s >= 0.0 for t in ticks)
     st = eng.stats()
     for key in ("jit_calls", "host_syncs", "prefill_tokens", "gen_tokens",
                 "ticks", "chunks_run", "preemptions", "resumes",
@@ -518,3 +518,107 @@ def test_launch_log_and_stats_compat(traced_run):
     assert snap["serve_jit_calls_total"]["value"] == eng.jit_calls
     prom = eng.prometheus_metrics()
     assert f"serve_jit_calls_total {eng.jit_calls}" in prom
+
+
+# ===========================================================================
+# engine phases on the profiler's clock, admission stamps
+# ===========================================================================
+
+CHUNKED_PHASES = {"engine.tick", "engine.admit", "engine.table_upload",
+                  "engine.plan", "engine.chunk_launch",
+                  "engine.decode_launch", "engine.device_get",
+                  "engine.emit"}
+MONOLITHIC_PHASES = {"engine.tick", "engine.admit", "engine.prefill_launch",
+                     "engine.decode_launch", "engine.device_get",
+                     "engine.emit"}
+
+
+@pytest.mark.parametrize("chunked", [True, False])
+def test_engine_phases_in_profiler_trace(model_f32, tmp_path, chunked):
+    """One tick that admits a prompt while another request decodes,
+    recorded by jax.profiler: every phase of the tick is in the
+    .xplane.pb, nested in engine.tick, and the launches carry their
+    args.  The tracer is off: the annotations do not depend on it."""
+    from jax.profiler import ProfileData
+    model, params = model_f32
+    eng = ServeEngine(model, params, _base(chunked=chunked,
+                                           prefix_cache=False,
+                                           max_new_tokens=4))
+    eng.submit(list(range(1, 12)))
+    while not any(r is not None and r.out_tokens for r in eng.slots):
+        eng.tick()
+    eng.submit(list(range(3, 17)))
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        eng.tick()
+    finally:
+        jax.profiler.stop_trace()
+    (path,) = tmp_path.rglob("*.xplane.pb")
+    events = [e for plane in ProfileData.from_file(str(path)).planes
+              if plane.name.startswith("/host:")
+              for line in plane.lines for e in line.events
+              if e.name.startswith("engine.")]
+    names = {e.name for e in events}
+    assert names == (CHUNKED_PHASES if chunked else MONOLITHIC_PHASES)
+    (tick,) = [e for e in events if e.name == "engine.tick"]
+    t0, t1 = tick.start_ns, tick.start_ns + tick.duration_ns
+    for e in events:
+        assert t0 <= e.start_ns and e.start_ns + e.duration_ns <= t1, e.name
+    stats = {e.name: dict(e.stats) for e in events}
+    # a monolithic admission prefills whole, so the newcomer decodes too
+    n_decode = 1 if chunked else 2
+    assert stats["engine.decode_launch"] == {"live_rows": n_decode}
+    assert stats["engine.tick"]["n_decode"] == n_decode
+    if chunked:
+        assert stats["engine.chunk_launch"] == {"rows": 1, "live_rows": 1,
+                                                "true_tokens": 14}
+    else:
+        assert stats["engine.prefill_launch"] == {"true_tokens": 14}
+    assert eng.tm.tracer is None
+
+
+@pytest.fixture(scope="module")
+def preempt_run(model_f32):
+    """The capacity-capped priority burst of test_preempt_resume_events:
+    background requests are preempted and resumed."""
+    model, params = model_f32
+    items = priority_burst(model.cfg.vocab_size, background_lens=(96, 96),
+                           burst_lens=(64,), burst_tick=2)
+    scfg = ServeConfig(max_batch=3, max_seq=256, max_new_tokens=8,
+                       paged=True, page_size=PAGE, num_pages=200,
+                       chunked=True, prefill_chunk=16,
+                       tick_token_budget=24, preemption=True,
+                       max_chunks_per_tick=1, usable_pages=28,
+                       telemetry=True)
+    eng = ServeEngine(model, params, scfg)
+    _, done = replay(eng, items)
+    assert eng.sched.resumes >= 1
+    return eng, done
+
+
+def test_admit_stamp_survives_preempt_resume(preempt_run):
+    """t_admit is the first slot and page reservation: set once, on the
+    clock of t_submit and token_wall, and kept through preempt -> resume
+    (a resume that reset it would stamp it after the RESUME instant)."""
+    eng, done = preempt_run
+    for r in done:
+        assert r.t_submit <= r.t_admit <= r.token_wall[0]
+    tr = eng.tm.tracer
+    resumed = {dict(e.args)["uid"]: e.wall for e in tr.events()
+               if e.name.endswith(":RESUME")}
+    assert resumed
+    by_uid = {r.uid: r for r in done}
+    for uid, wall in resumed.items():
+        assert by_uid[uid].n_preemptions >= 1
+        assert by_uid[uid].t_admit < tr.epoch + wall
+
+
+def test_queue_wait_histogram_counts_first_admissions(preempt_run):
+    """serve_queue_wait_seconds observes each request's first admission
+    only: its count is the number of requests, not of admissions."""
+    eng, done = preempt_run
+    hist = eng.tm.registry.get("serve_queue_wait_seconds")
+    assert hist.count == len(done) == eng.stats()["requests"]
+    assert hist.sum == pytest.approx(sum(r.t_admit - r.t_submit
+                                         for r in done))
+    assert eng.sched.resumes >= 1       # admissions outnumber requests
