@@ -59,8 +59,10 @@ def run():
     # vs K single-row launches (the sequential per-chunk oracle)
     Kc, Sc, Hqc, Hkvc, Dc, psc = 4, 128, 8, 4, 64, 32
     n_pages, n_max = 64, 16
-    kp = rn(n_pages, psc, Hkvc, Dc, dtype=jnp.float32)
-    vp = rn(n_pages, psc, Hkvc, Dc, dtype=jnp.float32)
+    # a one-layer stacked pool, head-major, K and V side by side in lanes
+    pool = ops.pack_kv(rn(Hkvc, n_pages, psc, Dc, dtype=jnp.float32),
+                       rn(Hkvc, n_pages, psc, Dc, dtype=jnp.float32),
+                       2 * Dc)[None]
     qc = rn(Kc, Sc, Hqc, Dc, dtype=jnp.float32)
     rng = np.random.default_rng(0)
     perm = rng.permutation(np.arange(1, n_pages))
@@ -75,9 +77,9 @@ def run():
     off_j = jnp.asarray(offsets)
     tls_j = off_j + Sc
     single = jax.jit(lambda q, row, off: ops.paged_prefill_attention(
-        q, kp, vp, row, off, impl="ref"))
+        q, pool, 0, row, off, impl="ref"))
     batched = jax.jit(lambda q: ops.batched_paged_prefill_attention(
-        q, kp, vp, tbl_j, off_j, tls_j, impl="ref"))
+        q, pool, 0, tbl_j, off_j, tls_j, impl="ref"))
     _, us = timed(lambda: [single(qc[row:row + 1], tbl_j[row],
                                   off_j[row]).block_until_ready()
                            for row in range(Kc)], reps=3)

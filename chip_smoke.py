@@ -9,7 +9,9 @@ Phases, each of which must pass (none is caught and skipped):
   (a) device check: JAX must find a TPU.  There is no CPU fallback.
   (b) kernel parity at full width: the Pallas paged decode and batched
       chunk-prefill kernels against their jnp references (impl="ref"),
-      on a shuffled block table, within two bf16 ulps at unit scale.
+      on a shuffled block table, within two bf16 ulps at unit scale; the
+      page write equal to the scatter; the copy-on-write page copy in
+      place.
   (c) the engine: ServeEngine (paged + chunked + batched, Pallas kernels)
       serves 12 requests with seeded prompt lengths of 64-1536 tokens
       through granite-3-2b (40 layers, d_model 2048, 32 heads / 8 KV,
@@ -48,6 +50,7 @@ from repro.kernels import ops  # noqa: E402
 from repro.models import build_model  # noqa: E402
 from repro.serve.engine import ServeEngine  # noqa: E402
 from repro.serve.scheduler import RequestState  # noqa: E402
+from repro.serve.serve_step import copy_page  # noqa: E402
 
 ARCH = "granite-3-2b"
 SERVE = dict(paged=True, chunked=True, batched=True, page_size=128,
@@ -104,16 +107,19 @@ def kernel_parity(cfg, seed: int):
     ps, batch = SERVE["page_size"], SERVE["max_batch"]
     n_max = SERVE["max_seq"] // ps
     n_pages = batch * n_max + 1                  # page 0 is the null page
-    kq, kk, kv, kc = jax.random.split(jax.random.PRNGKey(seed), 4)
-    k_pages = jax.random.normal(kk, (hkv, n_pages, ps, d), jnp.bfloat16)
-    v_pages = jax.random.normal(kv, (hkv, n_pages, ps, d), jnp.bfloat16)
+    kq, kp, kc = jax.random.split(jax.random.PRNGKey(seed), 3)
+    # a two-layer stacked pool (K and V pages per layer), read at layer 1
+    pages = [jax.random.normal(k, (hkv, n_pages, ps, d), jnp.bfloat16)
+             for k in jax.random.split(kp, 4)]
+    pool = jnp.stack([ops.pack_kv(pages[0], pages[1], 2 * d),
+                      ops.pack_kv(pages[2], pages[3], 2 * d)])
     rng = np.random.default_rng(seed)
     tables = rng.permutation(np.arange(1, n_pages)).reshape(batch, n_max)
     tables = tables.astype(np.int32)
     lens = rng.integers(1, SERVE["max_seq"] + 1, size=batch).astype(np.int32)
     lens[:2] = (SERVE["max_seq"], 1)
     q = jax.random.normal(kq, (batch, 1, hq, d), jnp.bfloat16)
-    args = (q, k_pages, v_pages, jnp.asarray(tables), jnp.asarray(lens))
+    args = (q, pool, 1, jnp.asarray(tables), jnp.asarray(lens))
     _agree("paged_flash_decode", ops.paged_flash_decode(*args, impl="pallas"),
            ops.paged_flash_decode(*args, impl="ref"))
 
@@ -125,12 +131,50 @@ def kernel_parity(cfg, seed: int):
     rows = tables[:4].copy()
     rows[3] = 0
     qc = jax.random.normal(kc, (4, chunk, hq, d), jnp.bfloat16)
-    args = (qc, k_pages, v_pages, jnp.asarray(rows), jnp.asarray(offs),
+    args = (qc, pool, 1, jnp.asarray(rows), jnp.asarray(offs),
             jnp.asarray(true_lens))
     got = ops.batched_paged_prefill_attention(*args, impl="pallas")
     _agree("batched_paged_prefill_attention", got,
            ops.batched_paged_prefill_attention(*args, impl="ref"))
     require(not bool(jnp.any(got[3])), "dead chunk row is not exactly zero")
+
+    # the page write, compiled, must store exactly what the scatter
+    # stores: decode rows (the last one idle, on the null page 0) and
+    # chunk rows of which two belong to one sequence and share the page
+    # where the first ends mid-page
+    k, v = (jax.random.normal(kk, (batch, 1, hkv, d), jnp.bfloat16)
+            for kk in jax.random.split(kc))
+    dec = (k, v, jnp.asarray(tables), jnp.asarray(lens - 1),
+           jnp.asarray(lens), 1)
+    rows = np.stack([tables[0], tables[1], tables[0], np.zeros(n_max)])
+    starts = np.array([0, 1300, 300, 0], np.int32)
+    ends = np.array([300, 1500, 300 + chunk, 0], np.int32)
+    k, v = (jax.random.normal(kk, (4, chunk, hkv, d), jnp.bfloat16)
+            for kk in jax.random.split(kq))
+    chunks = (k, v, jnp.asarray(rows, jnp.int32), jnp.asarray(starts),
+              jnp.asarray(ends), chunk)
+    for name, (k, v, *where) in (("decode", dec), ("chunks", chunks)):
+        got, want = (ops.kv_page_write(
+            pool, 1, k, v, ops.kv_write_plan(*where, ps, impl=impl),
+            impl=impl) for impl in ("pallas", "ref"))
+        require(bool(jnp.array_equal(got, want)),
+                f"kv_page_write ({name}) differs from the scatter")
+        print(f"[b] kv_page_write ({name}): equal to the scatter")
+
+    # the copy-on-write page copy as the engine runs it (pool donated):
+    # in place, with at most the copied page as a temporary
+    src, dst = int(tables[0, 0]), int(tables[1, 0])
+    page = np.asarray(pool[:, :, src])
+    copy = jax.jit(copy_page, donate_argnums=(0,))
+    mem = copy.lower(pool, src, dst).compile().memory_analysis()
+    require(mem.alias_size_in_bytes == pool.nbytes
+            and mem.temp_size_in_bytes <= pool.nbytes // n_pages,
+            f"copy_page is not in place: {mem}")
+    pool = copy(pool, src, dst)
+    require(bool((np.asarray(pool[:, :, dst]) == page).all()),
+            "copy_page did not copy the page")
+    print(f"[b] copy_page: in place, temporaries "
+          f"{mem.temp_size_in_bytes} B")
 
 
 def prompts(cfg, seed: int):

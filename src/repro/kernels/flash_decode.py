@@ -11,15 +11,19 @@ Two cache layouts share the same online-softmax inner step:
   flash_decode        dense (B, S_max, Hkv, D) caches - one contiguous
                       KV strip per sequence, moved head-major (B, Hkv, S, D)
                       before the launch.
-  paged_flash_decode  a global head-major (Hkv, P, page, D) page pool shared
-                      by all sequences; each grid step gathers one head's
-                      page through a scalar-prefetched block table (SMEM), so
-                      the BlockSpec index map IS the page-table walk and the
+  paged_flash_decode  the stacked, lane-dense page pool (L, Hkv, P, page, W)
+                      shared by all sequences and layers, K in lanes [0, D)
+                      and V in lanes [D, 2D) of each token row
+                      (kernels/kv_write.py).  Each grid step gathers one
+                      head's page of layer l through a scalar-prefetched
+                      block table: the index map (l, h, bt[b, j], 0, 0) IS
+                      the page-table walk, one DMA brings K and V together,
+                      and the kernel splits them by lanes in VMEM.  The
                       (m, l, acc) merge never leaves VMEM scratch.
 
-Both KV BlockSpecs are (1, 1, rows, D) over a head-major array: the TPU
+The KV BlockSpecs keep a head off the second-minor axis of a block: the TPU
 tiles the last two block dimensions by (8, 128) unless they span the whole
-array dimension, so a head must not sit on the second-minor axis of a block.
+array dimension.
 """
 from __future__ import annotations
 
@@ -59,16 +63,16 @@ def _online_merge(s, mask, v, acc_ref, m_ref, l_ref):
     acc_ref[...] = acc_ref[...] * alpha + pv
 
 
-def _online_softmax_step(q_ref, k_ref, v_ref, acc_ref, m_ref, l_ref, *,
+def _online_softmax_step(q_ref, k, v, acc_ref, m_ref, l_ref, *,
                          k_first, valid, window: int, scale: float,
                          softcap: float = 0.0):
     """One KV-block update of the running (m, l, acc) triple in VMEM.
 
-    Shared by the dense and the paged decode kernels - only how the KV block
-    got into VMEM differs (contiguous BlockSpec walk vs block-table gather).
+    k, v: (bk, D) float32.  Shared by the dense and the paged decode
+    kernels - only how the KV block got into VMEM differs (contiguous
+    BlockSpec walk vs block-table gather of a lane-packed page).
     """
     q = q_ref[0, 0].astype(jnp.float32) * scale              # (G, D)
-    k = k_ref[0, 0].astype(jnp.float32)                      # (bk, D)
     s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                             preferred_element_type=jnp.float32)  # (G, bk)
     if softcap > 0.0:
@@ -77,8 +81,14 @@ def _online_softmax_step(q_ref, k_ref, v_ref, acc_ref, m_ref, l_ref, *,
     mask = pos < valid
     if window > 0:
         mask = mask & (pos >= valid - window)
-    v = v_ref[0, 0].astype(jnp.float32)                      # (bk, D)
     _online_merge(s, mask, v, acc_ref, m_ref, l_ref)
+
+
+def split_kv(kv_ref, head_dim: int):
+    """K and V of one lane-packed (.., rows, W) page block as float32
+    (rows, D) arrays: K in lanes [0, D), V in [D, 2D)."""
+    kv = kv_ref[0, 0, 0].astype(jnp.float32)
+    return kv[:, :head_dim], kv[:, head_dim:2 * head_dim]
 
 
 def _decode_kernel(len_ref, q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref,
@@ -101,9 +111,10 @@ def _decode_kernel(len_ref, q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref,
 
     @pl.when(run)
     def _compute():
-        _online_softmax_step(q_ref, k_ref, v_ref, acc_ref, m_ref, l_ref,
-                             k_first=k_first, valid=valid, window=window,
-                             scale=scale, softcap=softcap)
+        _online_softmax_step(q_ref, k_ref[0, 0].astype(jnp.float32),
+                             v_ref[0, 0].astype(jnp.float32), acc_ref,
+                             m_ref, l_ref, k_first=k_first, valid=valid,
+                             window=window, scale=scale, softcap=softcap)
 
     @pl.when(j == nk - 1)
     def _finalize():
@@ -169,12 +180,13 @@ def flash_decode(q, k_cache, v_cache, cache_len, *, window: int = 0,
 # paged decode: KV pages gathered through a scalar-prefetched block table
 # ===========================================================================
 
-def _paged_decode_kernel(bt_ref, len_ref, q_ref, k_ref, v_ref, o_ref,
+def _paged_decode_kernel(layer_ref, bt_ref, len_ref, q_ref, kv_ref, o_ref,
                          acc_ref, m_ref, l_ref, *, window: int, scale: float,
                          softcap: float, page_size: int):
-    """bt_ref: (B, n_max) block table, len_ref: (B,) valid lengths - both
-    scalar-prefetched into SMEM; the k/v BlockSpec index maps already walked
-    the table, so k_ref/v_ref hold page j of THIS sequence."""
+    """layer_ref: (1,) layer index, bt_ref: (B, n_max) block table,
+    len_ref: (B,) valid lengths - all scalar-prefetched into SMEM; the
+    pool's BlockSpec index map already walked the table, so kv_ref holds
+    page j of THIS sequence at this layer, K and V side by side."""
     b = pl.program_id(0)
     j = pl.program_id(2)
     nk = pl.num_programs(2)
@@ -193,7 +205,8 @@ def _paged_decode_kernel(bt_ref, len_ref, q_ref, k_ref, v_ref, o_ref,
 
     @pl.when(run)
     def _compute():
-        _online_softmax_step(q_ref, k_ref, v_ref, acc_ref, m_ref, l_ref,
+        k, v = split_kv(kv_ref, q_ref.shape[-1])
+        _online_softmax_step(q_ref, k, v, acc_ref, m_ref, l_ref,
                              k_first=k_first, valid=valid, window=window,
                              scale=scale, softcap=softcap)
 
@@ -205,15 +218,17 @@ def _paged_decode_kernel(bt_ref, len_ref, q_ref, k_ref, v_ref, o_ref,
 
 @functools.partial(jax.jit, static_argnames=("window", "scale",
                                              "logit_softcap"))
-def paged_flash_decode(q, k_pages, v_pages, block_table, cache_len, *,
+def paged_flash_decode(q, kv_pages, layer, block_table, cache_len, *,
                        window: int = 0,
                        scale: Optional[float] = None,
                        logit_softcap: float = 0.0) -> jax.Array:
     """Decode against a paged KV cache.
 
     q:           (B, 1, Hq, D)
-    k/v_pages:   (Hkv, P, page_size, D) head-major global page pool (all
-                 sequences)
+    kv_pages:    (L, Hkv, P, page_size, W) stacked, lane-dense page pool
+                 (all sequences, all layers): K in lanes [0, D), V in
+                 [D, 2D)
+    layer:       scalar int32, the layer whose pages are read
     block_table: (B, n_max) int32 - page ids per sequence, position-major;
                  unused entries must point at a valid page (the engine keeps
                  page 0 as a never-allocated null page)
@@ -221,7 +236,7 @@ def paged_flash_decode(q, k_pages, v_pages, block_table, cache_len, *,
     Returns (B, 1, Hq, D).
     """
     B, _, Hq, D = q.shape
-    Hkv, _, ps, _ = k_pages.shape
+    _, Hkv, _, ps, W = kv_pages.shape
     G = Hq // Hkv
     n_max = block_table.shape[1]
     scale = scale if scale is not None else 1.0 / math.sqrt(D)
@@ -229,25 +244,26 @@ def paged_flash_decode(q, k_pages, v_pages, block_table, cache_len, *,
     if cache_len.ndim == 0:
         cache_len = jnp.full((B,), cache_len, jnp.int32)
     block_table = jnp.asarray(block_table, jnp.int32)
+    layer = jnp.asarray(layer, jnp.int32).reshape(1)
 
     qg = q.reshape(B, Hkv, G, D)
     kernel = functools.partial(_paged_decode_kernel, window=window,
                                scale=scale, softcap=logit_softcap,
                                page_size=ps)
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,       # block table + lengths land in SMEM
+        # layer + block table + lengths land in SMEM
+        num_scalar_prefetch=3,
         grid=(B, Hkv, n_max),
         in_specs=[
-            pl.BlockSpec((1, 1, G, D), lambda b, h, j, bt, cl: (b, h, 0, 0)),
+            pl.BlockSpec((1, 1, G, D),
+                         lambda b, h, j, l, bt, cl: (b, h, 0, 0)),
             # the index map IS the page-table walk: head h of page j of
-            # sequence b
-            pl.BlockSpec((1, 1, ps, D),
-                         lambda b, h, j, bt, cl: (h, bt[b, j], 0, 0)),
-            pl.BlockSpec((1, 1, ps, D),
-                         lambda b, h, j, bt, cl: (h, bt[b, j], 0, 0)),
+            # sequence b, at layer l - K and V in one block
+            pl.BlockSpec((1, 1, 1, ps, W),
+                         lambda b, h, j, l, bt, cl: (l[0], h, bt[b, j], 0, 0)),
         ],
         out_specs=pl.BlockSpec((1, 1, G, D),
-                               lambda b, h, j, bt, cl: (b, h, 0, 0)),
+                               lambda b, h, j, l, bt, cl: (b, h, 0, 0)),
         scratch_shapes=[
             pltpu.VMEM((G, D), jnp.float32),
             pltpu.VMEM((G, 1), jnp.float32),
@@ -262,5 +278,5 @@ def paged_flash_decode(q, k_pages, v_pages, block_table, cache_len, *,
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=backend.interpret_mode(),
         name="paged_flash_decode",
-    )(block_table, cache_len, qg, k_pages, v_pages)
+    )(layer, block_table, cache_len, qg, kv_pages)
     return o.reshape(B, 1, Hq, D)

@@ -193,25 +193,28 @@ def _tp_active(tp_mesh) -> bool:
     return tp_mesh is not None and dict(tp_mesh.shape).get("model", 1) > 1
 
 
-def _tp_head_sharded(fn, tp_mesh, n_pools: int, n_scalars: int):
+# the stacked (L, Hkv, P, page_size, W) pool, sharded on its head axis
+def _pool_heads():
+    from jax.sharding import PartitionSpec as P
+    return P(None, "model", None, None, None)
+
+
+def _tp_head_sharded(fn, tp_mesh, n_scalars: int):
     """shard_map a paged attention kernel on the HEAD axis of a serve mesh.
 
-    The wrapped kernel sees q and n_pools page pools with their head axis
-    (axis 2 of q's (B,S,H,D), axis 0 of a head-major (Hkv,P,ps,D) pool)
-    split across "model" plus
-    n_scalars replicated block-table/length operands, computes its local
-    head slice — per-head attention math never mixes heads, so the slice
-    is the exact per-head result — and all-gathers outputs back to the
-    full head axis.  With tiled=True the gather re-concatenates head
-    blocks in device order, so the output is bit-identical to the
-    unsharded kernel and everything downstream (output projection, FFN,
-    sampling) runs replicated with the tp=1 float summation order.  The
-    block table rides in replicated, the kernels' scalar-prefetch state.
+    The wrapped kernel fn(q, kv_pages, *scalars) sees q and the stacked
+    page pool with their head axis (axis 2 of q's (B,S,H,D), axis 1 of the
+    (L,Hkv,P,ps,W) pool) split across "model" plus n_scalars replicated
+    layer/block-table/length operands, computes its local head slice —
+    per-head attention math never mixes heads, so the slice is the exact
+    per-head result — and all-gathers outputs back to the full head axis.
+    With tiled=True the gather re-concatenates head blocks in device
+    order, so the output is bit-identical to the unsharded kernel and
+    everything downstream (output projection, FFN, sampling) runs
+    replicated with the tp=1 float summation order.  The block table
+    rides in replicated, the kernels' scalar-prefetch state.
     """
     from jax.sharding import PartitionSpec as P
-
-    q_heads = P(None, None, "model", None)
-    pool_heads = P("model", None, None, None)
 
     def local(*args):
         o = fn(*args)
@@ -219,48 +222,104 @@ def _tp_head_sharded(fn, tp_mesh, n_pools: int, n_scalars: int):
 
     return jax.shard_map(
         local, mesh=tp_mesh,
-        in_specs=(q_heads,) + (pool_heads,) * n_pools + (P(),) * n_scalars,
+        in_specs=(P(None, None, "model", None), _pool_heads())
+        + (P(),) * n_scalars,
         out_specs=P(), check_vma=False)
 
 
-def paged_flash_decode(q, k_pages, v_pages, block_table, cache_len, *,
+def pack_kv(k, v, lanes: int):
+    """(.., Hkv, D) K and V -> (.., Hkv, lanes) pool rows: K in lanes
+    [0, D), V in [D, 2D), zeros after (a pool may be wider than 2D; the
+    model's is not)."""
+    kv = jnp.concatenate([k, v], axis=-1)
+    pad = lanes - kv.shape[-1]
+    if pad:
+        kv = jnp.pad(kv, [(0, 0)] * (kv.ndim - 1) + [(0, pad)])
+    return kv
+
+
+def kv_write_plan(tables, starts, ends, n_tokens: int, page_size: int, *,
+                  impl: Optional[str] = None):
+    """Where a step's new K/V rows go in the page pool, computed once per
+    step and reused by every layer's kv_page_write.
+
+    Row r writes its n_tokens tokens at absolute positions starts[r] + i
+    through the block-table row tables[r] (R, n_max); positions at or past
+    ends[r] (pad) are not written.  The Pallas path sorts the write's grid
+    steps by page (kernels/kv_write.py write_plan); the ref scatter takes
+    the three arrays as they are."""
+    impl = impl or default_impl()
+    if impl == "pallas":
+        from . import kv_write
+        return kv_write.write_plan(tables, starts, ends, n_tokens, page_size)
+    return tuple(jnp.asarray(a, jnp.int32) for a in (tables, starts, ends))
+
+
+def kv_page_write(kv_pages, layer, k, v, plan, *,
+                  impl: Optional[str] = None, tp_mesh=None) -> jax.Array:
+    """Write new K/V into layer `layer` of the stacked page pool.
+
+    kv_pages: (L, Hkv, P, page_size, W); k, v: (R, S, Hkv, D); plan:
+    kv_write_plan(tables, starts, ends, S, page_size) for the same impl.
+    A chunk may start and end mid-page, and two rows may share a page.
+    The Pallas path rewrites only the touched blocks of the layer's
+    pages, in place (kernels/kv_write.py); the ref path is the XLA
+    scatter.  tp_mesh writes each device's head slice of the head-sharded
+    pool under shard_map.  Returns the pool."""
+    impl = impl or default_impl()
+    if impl == "pallas":
+        from . import kv_write
+        write = kv_write.kv_page_write
+    else:
+        def write(kv_pages, layer, new, plan):
+            return ref.kv_page_write(kv_pages, layer, new, *plan)
+    if _tp_active(tp_mesh):
+        from jax.sharding import PartitionSpec as P
+        write = jax.shard_map(
+            write, mesh=tp_mesh,
+            in_specs=(_pool_heads(), P(), P(None, None, "model", None), P()),
+            out_specs=_pool_heads(), check_vma=False)
+    return write(kv_pages, layer, pack_kv(k, v, kv_pages.shape[-1]), plan)
+
+
+def paged_flash_decode(q, kv_pages, layer, block_table, cache_len, *,
                        window: int = 0, logit_softcap: float = 0.0,
                        scale: Optional[float] = None,
                        impl: Optional[str] = None,
                        tp_mesh=None) -> jax.Array:
     """Decode against a paged KV cache (vLLM-style block table).
 
-    q: (B,1,Hq,D); k_pages/v_pages: (Hkv, P, page_size, D) head-major
-    global page pool;
+    q: (B,1,Hq,D); kv_pages: (L, Hkv, P, page_size, W) stacked lane-dense
+    page pool (K in lanes [0, D), V in [D, 2D)), read at layer `layer`;
     block_table: (B, n_max) int32 page ids; cache_len: (B,) valid lengths.
     The Pallas path walks the block table from SMEM inside the BlockSpec
     index maps, keeping the (m, l, acc) merge VMEM-resident; the ref path
     gathers pages and reuses the chunked dense decode.
 
     tp_mesh (a launch/mesh.py serve mesh with a "model" axis > 1) runs the
-    kernel under shard_map with q and the pools head-sharded and the block
+    kernel under shard_map with q and the pool head-sharded and the block
     table replicated; the output comes back replicated (bit-identical to
     tp=1 — see _tp_head_sharded)."""
     impl = impl or default_impl()
     if _tp_active(tp_mesh):
-        def run(qc, kp, vp, bt, cl):
-            return paged_flash_decode(qc, kp, vp, bt, cl, window=window,
+        def run(qc, kp, l, bt, cl):
+            return paged_flash_decode(qc, kp, l, bt, cl, window=window,
                                       logit_softcap=logit_softcap,
                                       scale=scale, impl=impl)
-        return _tp_head_sharded(run, tp_mesh, 2, 2)(
-            q, k_pages, v_pages, block_table, cache_len)
+        return _tp_head_sharded(run, tp_mesh, 3)(
+            q, kv_pages, layer, block_table, cache_len)
     if impl == "pallas":
         from . import flash_decode as fd
-        return fd.paged_flash_decode(q, k_pages, v_pages, block_table,
+        return fd.paged_flash_decode(q, kv_pages, layer, block_table,
                                      cache_len, window=window,
                                      logit_softcap=logit_softcap,
                                      scale=scale)
-    return ref.paged_flash_decode(q, k_pages, v_pages, block_table,
+    return ref.paged_flash_decode(q, kv_pages, layer, block_table,
                                   cache_len, window=window,
                                   logit_softcap=logit_softcap, scale=scale)
 
 
-def batched_paged_prefill_attention(q, k_pages, v_pages, page_tables,
+def batched_paged_prefill_attention(q, kv_pages, layer, page_tables,
                                     q_offsets, true_lens, q_lens=None, *,
                                     window: int = 0,
                                     logit_softcap: float = 0.0,
@@ -272,44 +331,45 @@ def batched_paged_prefill_attention(q, k_pages, v_pages, page_tables,
 
     q: (K,S,Hq,D) chunk queries; row k sits at absolute positions
     q_offsets[k] + arange(S) (its K/V already written into its pages),
-    zero-padded past true_lens[k] - q_offsets[k]; page_tables: (K,n_max)
-    per-row block-table rows; true_lens: (K,) per-row prefill cursors
-    (dead padding rows carry 0 and an all-null table row, returning
-    zero).  Each real row attends causally over every earlier position
-    and the chunk itself.  The Pallas path walks every row's table from
-    SMEM inside one grid (K, heads, kv-pages) launch with the (m, l,
-    acc) merge VMEM-resident (kernels/paged_prefill.py); the ref path
-    gathers pages per row and applies the offset causal mask.
+    zero-padded past true_lens[k] - q_offsets[k]; kv_pages: the stacked
+    (L, Hkv, P, page_size, W) pool, read at layer `layer`; page_tables:
+    (K,n_max) per-row block-table rows; true_lens: (K,) per-row prefill
+    cursors (dead padding rows carry 0 and an all-null table row,
+    returning zero).  Each real row attends causally over every earlier
+    position and the chunk itself.  The Pallas path walks every row's
+    table from SMEM inside one grid (K, heads, kv-pages) launch with the
+    (m, l, acc) merge VMEM-resident (kernels/paged_prefill.py); the ref
+    path gathers pages per row and applies the offset causal mask.
 
-    tp_mesh shards q and the pools on heads under shard_map with the
+    tp_mesh shards q and the pool on heads under shard_map with the
     per-row tables/offsets/cursors replicated (see paged_flash_decode)."""
     impl = impl or default_impl()
     if _tp_active(tp_mesh):
         if q_lens is None:
-            def run(qc, kp, vp, pt, qo, tl):
+            def run(qc, kp, l, pt, qo, tl):
                 return batched_paged_prefill_attention(
-                    qc, kp, vp, pt, qo, tl, None, window=window,
+                    qc, kp, l, pt, qo, tl, None, window=window,
                     logit_softcap=logit_softcap, scale=scale, impl=impl)
-            return _tp_head_sharded(run, tp_mesh, 2, 3)(
-                q, k_pages, v_pages, page_tables, q_offsets, true_lens)
+            return _tp_head_sharded(run, tp_mesh, 4)(
+                q, kv_pages, layer, page_tables, q_offsets, true_lens)
 
-        def run(qc, kp, vp, pt, qo, tl, ql):
+        def run(qc, kp, l, pt, qo, tl, ql):
             return batched_paged_prefill_attention(
-                qc, kp, vp, pt, qo, tl, ql, window=window,
+                qc, kp, l, pt, qo, tl, ql, window=window,
                 logit_softcap=logit_softcap, scale=scale, impl=impl)
-        return _tp_head_sharded(run, tp_mesh, 2, 4)(
-            q, k_pages, v_pages, page_tables, q_offsets, true_lens, q_lens)
+        return _tp_head_sharded(run, tp_mesh, 5)(
+            q, kv_pages, layer, page_tables, q_offsets, true_lens, q_lens)
     if impl == "pallas":
         from . import paged_prefill as pp
         return pp.batched_paged_prefill_attention(
-            q, k_pages, v_pages, page_tables, q_offsets, true_lens, q_lens,
+            q, kv_pages, layer, page_tables, q_offsets, true_lens, q_lens,
             window=window, logit_softcap=logit_softcap, scale=scale)
     return ref.batched_paged_prefill_attention(
-        q, k_pages, v_pages, page_tables, q_offsets, true_lens, q_lens,
+        q, kv_pages, layer, page_tables, q_offsets, true_lens, q_lens,
         window=window, logit_softcap=logit_softcap, scale=scale)
 
 
-def paged_prefill_attention(q, k_pages, v_pages, page_row, q_offset, *,
+def paged_prefill_attention(q, kv_pages, layer, page_row, q_offset, *,
                             window: int = 0, logit_softcap: float = 0.0,
                             scale: Optional[float] = None,
                             impl: Optional[str] = None) -> jax.Array:
@@ -319,16 +379,17 @@ def paged_prefill_attention(q, k_pages, v_pages, page_row, q_offset, *,
     q: (1,S,Hq,D) chunk queries at absolute positions q_offset + arange(S)
     (chunk K/V already written into its pages) - the uncached suffix after
     a prefix-cache hit, or any chunk of a token-budget scheduled prefill;
-    page_row: (n_max,) the sequence's block-table row.  Each row attends
-    causally over every earlier position and the chunk itself."""
+    kv_pages/layer: the stacked pool and the layer read; page_row:
+    (n_max,) the sequence's block-table row.  Each row attends causally
+    over every earlier position and the chunk itself."""
     impl = impl or default_impl()
     if impl == "pallas":
         from . import paged_prefill as pp
-        return pp.paged_prefill_attention(q, k_pages, v_pages, page_row,
+        return pp.paged_prefill_attention(q, kv_pages, layer, page_row,
                                           q_offset, window=window,
                                           logit_softcap=logit_softcap,
                                           scale=scale)
-    return ref.paged_prefill_attention(q, k_pages, v_pages, page_row,
+    return ref.paged_prefill_attention(q, kv_pages, layer, page_row,
                                        q_offset, window=window,
                                        logit_softcap=logit_softcap,
                                        scale=scale)

@@ -43,11 +43,15 @@ whole GQA groups.  Nothing in here is sharding-aware - the kernel sees a
 smaller head count and is otherwise bit-identical.
 
 Same construction as paged_flash_decode (kernels/flash_decode.py): the
-block tables are scalar-prefetched into SMEM, the BlockSpec index map IS
-the page-table walk, and the running (m, l, acc) online-softmax state
-stays in VMEM scratch across KV pages.  The only extra ingredient over
-the decode kernel is a 2-D causal mask - each chunk row r masks columns
-> offset[k] + r - computed branch-free from the prefetched offset.
+pool is the stacked, lane-dense (L, Hkv, P, page, W) array with K in lanes
+[0, D) and V in [D, 2D) of each token row (kernels/kv_write.py), the layer
+index and block tables are scalar-prefetched into SMEM, the BlockSpec
+index map (l, h, tbl[k, j], 0, 0) IS the page-table walk - one DMA brings
+a head's K and V page together, split by lanes in VMEM - and the running
+(m, l, acc) online-softmax state stays in VMEM scratch across KV pages.
+The only extra ingredient over the decode kernel is a 2-D causal mask -
+each chunk row r masks columns > offset[k] + r - computed branch-free
+from the prefetched offset.
 
 The grid walks the FULL block-table row (n_max pages, a static shape);
 pages at or past the row's true_len are skipped with pl.when, so the cost
@@ -66,20 +70,21 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .flash_decode import _online_merge
+from .flash_decode import _online_merge, split_kv
 from . import backend
 
 NEG_INF = -1e30
 
 
-def _chunk_kernel(tbl_ref, off_ref, tl_ref, ql_ref, q_ref, k_ref, v_ref,
+def _chunk_kernel(layer_ref, tbl_ref, off_ref, tl_ref, ql_ref, q_ref, kv_ref,
                   o_ref, acc_ref, m_ref, l_ref, *, page_size: int,
                   window: int, scale: float, softcap: float, gq: int,
                   s_suf: int):
-    """tbl_ref: (K, n_max) block-table rows, off_ref/tl_ref/ql_ref: (K,)
-    per-row chunk start / prefill cursor / real query count - all
-    scalar-prefetched; k_ref/v_ref hold page j of row b's sequence (the
-    index map already walked the table).  Query rows at or past ql are
+    """layer_ref: (1,) layer index, tbl_ref: (K, n_max) block-table rows,
+    off_ref/tl_ref/ql_ref: (K,) per-row chunk start / prefill cursor /
+    real query count - all scalar-prefetched; kv_ref holds page j of row
+    b's sequence at this layer, K and V side by side (the index map
+    already walked the table).  Query rows at or past ql are
     PAD lanes (a speculative verify row drafts fewer than S - 1 tokens):
     their output is forced to exactly zero in the finalize, so ragged
     verify batches stay bit-deterministic whatever the pad rows hold."""
@@ -106,7 +111,7 @@ def _chunk_kernel(tbl_ref, off_ref, tl_ref, ql_ref, q_ref, k_ref, v_ref,
     @pl.when(run)
     def _compute():
         q = q_ref[0, 0].astype(jnp.float32) * scale          # (S*G, D)
-        k = k_ref[0, 0].astype(jnp.float32)                  # (ps, D)
+        k, v = split_kv(kv_ref, q.shape[-1])                 # (ps, D) each
         s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32)
         if softcap > 0.0:
@@ -118,7 +123,6 @@ def _chunk_kernel(tbl_ref, off_ref, tl_ref, ql_ref, q_ref, k_ref, v_ref,
         mask = col <= row
         if window > 0:
             mask = mask & (col > row - window)
-        v = v_ref[0, 0].astype(jnp.float32)                  # (ps, D)
         _online_merge(s, mask, v, acc_ref, m_ref, l_ref)
 
     @pl.when(j == nk - 1)
@@ -133,7 +137,7 @@ def _chunk_kernel(tbl_ref, off_ref, tl_ref, ql_ref, q_ref, k_ref, v_ref,
 
 @functools.partial(jax.jit, static_argnames=("window", "scale",
                                              "logit_softcap"))
-def batched_paged_prefill_attention(q, k_pages, v_pages, page_tables,
+def batched_paged_prefill_attention(q, kv_pages, layer, page_tables,
                                     q_offsets, true_lens, q_lens=None, *,
                                     window: int = 0,
                                     scale: Optional[float] = None,
@@ -147,10 +151,12 @@ def batched_paged_prefill_attention(q, k_pages, v_pages, page_tables,
                  (attn_prefill_chunks_paged does both), as must all K/V
                  for positions < q_offsets[k] (cached prefix pages and/or
                  earlier chunks - which may be other rows of the SAME
-                 launch: the per-layer scatter lands before this kernel
-                 reads the pool, so packing two chunks of one sequence is
-                 exact as long as their offsets are ordered).
-    k/v_pages:   (Hkv, P, page_size, D) head-major global page pool
+                 launch: the per-layer page write lands before this
+                 kernel reads the pool, so packing two chunks of one
+                 sequence is exact as long as their offsets are ordered).
+    kv_pages:    (L, Hkv, P, page_size, W) stacked, lane-dense page pool,
+                 K in lanes [0, D) and V in [D, 2D)
+    layer:       scalar int32, the layer whose pages are read
     page_tables: (K, n_max) int32 - per-row block-table rows,
                  position-major; entries past the reservation point at the
                  null page 0 and are never touched by the causal mask
@@ -170,9 +176,10 @@ def batched_paged_prefill_attention(q, k_pages, v_pages, page_tables,
     Returns (K, S, Hq, D).
     """
     K, S, Hq, D = q.shape
-    Hkv, _, ps, _ = k_pages.shape
+    _, Hkv, _, ps, W = kv_pages.shape
     G = Hq // Hkv
     n_max = page_tables.shape[1]
+    layer = jnp.asarray(layer, jnp.int32).reshape(1)
     scale = scale if scale is not None else 1.0 / math.sqrt(D)
     page_tables = jnp.asarray(page_tables, jnp.int32)
     off = jnp.asarray(q_offsets, jnp.int32).reshape(K)
@@ -189,22 +196,20 @@ def batched_paged_prefill_attention(q, k_pages, v_pages, page_tables,
                                scale=scale, softcap=logit_softcap, gq=G,
                                s_suf=S)
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        # tables + offsets + true_lens + q_lens in SMEM
-        num_scalar_prefetch=4,
+        # layer + tables + offsets + true_lens + q_lens in SMEM
+        num_scalar_prefetch=5,
         grid=(K, Hkv, n_max),
         in_specs=[
             pl.BlockSpec((1, 1, S * G, D),
-                         lambda b, h, j, tbl, off, tl, ql: (b, h, 0, 0)),
-            # the index map IS the page-table walk: head h of page j of row b
-            pl.BlockSpec((1, 1, ps, D),
-                         lambda b, h, j, tbl, off, tl, ql:
-                         (h, tbl[b, j], 0, 0)),
-            pl.BlockSpec((1, 1, ps, D),
-                         lambda b, h, j, tbl, off, tl, ql:
-                         (h, tbl[b, j], 0, 0)),
+                         lambda b, h, j, l, tbl, off, tl, ql: (b, h, 0, 0)),
+            # the index map IS the page-table walk: head h of page j of
+            # row b, at layer l - K and V in one block
+            pl.BlockSpec((1, 1, 1, ps, W),
+                         lambda b, h, j, l, tbl, off, tl, ql:
+                         (l[0], h, tbl[b, j], 0, 0)),
         ],
         out_specs=pl.BlockSpec((1, 1, S * G, D),
-                               lambda b, h, j, tbl, off, tl, ql:
+                               lambda b, h, j, l, tbl, off, tl, ql:
                                (b, h, 0, 0)),
         scratch_shapes=[
             pltpu.VMEM((S * G, D), jnp.float32),
@@ -220,12 +225,12 @@ def batched_paged_prefill_attention(q, k_pages, v_pages, page_tables,
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=backend.interpret_mode(),
         name="batched_paged_prefill_attention",
-    )(page_tables, off, tl, ql, qg, k_pages, v_pages)
+    )(layer, page_tables, off, tl, ql, qg, kv_pages)
     return o.reshape(K, Hkv, S, G, D).transpose(0, 2, 1, 3, 4).reshape(
         K, S, Hq, D)
 
 
-def paged_prefill_attention(q, k_pages, v_pages, page_row, q_offset, *,
+def paged_prefill_attention(q, kv_pages, layer, page_row, q_offset, *,
                             window: int = 0,
                             scale: Optional[float] = None,
                             logit_softcap: float = 0.0) -> jax.Array:
@@ -238,6 +243,6 @@ def paged_prefill_attention(q, k_pages, v_pages, page_row, q_offset, *,
     contract.  Returns (1, S, Hq, D)."""
     off = jnp.asarray(q_offset, jnp.int32).reshape(1)
     return batched_paged_prefill_attention(
-        q, k_pages, v_pages, jnp.asarray(page_row, jnp.int32)[None],
+        q, kv_pages, layer, jnp.asarray(page_row, jnp.int32)[None],
         off, off + q.shape[1], window=window, scale=scale,
         logit_softcap=logit_softcap)

@@ -203,42 +203,64 @@ def _flash_decode_head_major(q, k, v, cache_len, *, scale, window,
     return o.reshape(B, 1, Hq, D).astype(q.dtype)
 
 
-def _gather_pages(pages, tables):
-    """Contiguous (R, n_max * page_size, Hkv, D) strips of the pages each
-    row of `tables` (R, n_max) names, from a head-major (Hkv, P, page_size,
-    D) pool."""
-    Hkv, _, _, D = pages.shape
-    R = tables.shape[0]
-    return jnp.moveaxis(pages[:, tables], 0, 3).reshape(R, -1, Hkv, D)
+def _gather_pages(kv_pages, layer, tables):
+    """Contiguous head-major (R, Hkv, n_max * page_size, W) strips of the
+    pages each row of `tables` (R, n_max) names, at layer `layer` of the
+    stacked lane-dense (L, Hkv, P, page_size, W) pool."""
+    R, n_max = tables.shape
+    _, Hkv, _, ps, W = kv_pages.shape
+    # the head slice splits the layer and table indices, so their
+    # dimensions lead: (R, n_max, Hkv, ps, W)
+    kv = jnp.moveaxis(kv_pages[layer, :, tables], 2, 1)
+    return kv.reshape(R, Hkv, n_max * ps, W)
 
 
-def paged_flash_decode(q, k_pages, v_pages, block_table, cache_len, *,
+def kv_page_write(kv_pages, layer, new, tables, starts, ends):
+    """Write token rows into layer `layer` of the stacked page pool
+    (reference of kernels/kv_write.py, an XLA scatter).
+
+    new: (R, S, Hkv, W) packed K/V rows; row r's token i lands at
+    absolute position starts[r] + i through the block-table row
+    tables[r] (R, n_max); positions at or past ends[r] are not written."""
+    R, S = new.shape[:2]
+    _, _, P, ps, _ = kv_pages.shape
+    tables = jnp.asarray(tables, jnp.int32)
+    pos = jnp.asarray(starts, jnp.int32)[:, None] + jnp.arange(S)[None, :]
+    page = jnp.take_along_axis(
+        tables, jnp.minimum(pos // ps, tables.shape[1] - 1), axis=1)
+    # out-of-range page ids are dropped by the scatter
+    page = jnp.where(pos < jnp.asarray(ends, jnp.int32)[:, None], page, P)
+    # the scalar layer and the (R, S) index arrays are split by the head
+    # slice, so the update is (R, S, Hkv, W)
+    return kv_pages.at[layer, :, page, pos % ps].set(
+        new.astype(kv_pages.dtype), mode="drop")
+
+
+def paged_flash_decode(q, kv_pages, layer, block_table, cache_len, *,
                        scale: Optional[float] = None,
                        window: int = 0,
                        logit_softcap: float = 0.0) -> jax.Array:
     """Decode against a paged KV cache (reference oracle).
 
-    q: (B, 1, Hq, D); k_pages/v_pages: (Hkv, P, page_size, D) head-major
-    global page pool; block_table: (B, n_max) int32 page ids
-    (position-major, unused entries pointing at any valid page);
-    cache_len: (B,) or scalar.
+    q: (B, 1, Hq, D); kv_pages: (L, Hkv, P, page_size, W) stacked
+    lane-dense pool (K in lanes [0, D), V in [D, 2D)); layer: the layer
+    read; block_table: (B, n_max) int32 page ids (position-major, unused
+    entries pointing at any valid page); cache_len: (B,) or scalar.
 
     Gathers each sequence's pages into a contiguous head-major strip and
     runs the chunked dense decode - the ground truth the Pallas
     block-table kernel is validated against, and the portable
     paged-serving path off-TPU.
     """
-    Hkv, _, _, D = k_pages.shape
+    D = q.shape[-1]
     block_table = jnp.asarray(block_table, jnp.int32)
-    B = block_table.shape[0]
-    k = k_pages[:, block_table].reshape(Hkv, B, -1, D)
-    v = v_pages[:, block_table].reshape(Hkv, B, -1, D)
-    return _flash_decode_head_major(q, k, v, cache_len, scale=scale,
-                                    window=window,
+    kv = jnp.moveaxis(_gather_pages(kv_pages, layer, block_table), 1, 0)
+    return _flash_decode_head_major(q, kv[..., :D], kv[..., D:2 * D],
+                                    cache_len, scale=scale, window=window,
                                     logit_softcap=logit_softcap)
 
 
-def batched_paged_prefill_attention(q, k_pages, v_pages, page_tables,
+def batched_paged_prefill_attention(q, kv_pages, layer, page_tables,
                                     q_offsets, true_lens, q_lens=None, *,
                                     scale: Optional[float] = None,
                                     window: int = 0,
@@ -249,8 +271,9 @@ def batched_paged_prefill_attention(q, k_pages, v_pages, page_tables,
 
     q: (K, S, Hq, D) chunk queries; row k sits at absolute positions
     q_offsets[k] + arange(S), zero-padded past its real length (ragged
-    rows share one static S); k/v_pages: (Hkv, P, page_size, D)
-    head-major global pool; page_tables: (K, n_max) per-row block-table rows (each row's
+    rows share one static S); kv_pages: (L, Hkv, P, page_size, W) stacked
+    lane-dense pool, read at layer `layer`; page_tables: (K, n_max)
+    per-row block-table rows (each row's
     chunk K/V already written into its pages, as is all K/V for positions
     < q_offsets[k]); true_lens: (K,) each row's prefill cursor after its
     last REAL token - columns at or past it are masked, the gather-level
@@ -266,13 +289,14 @@ def batched_paged_prefill_attention(q, k_pages, v_pages, page_tables,
     chunked / prefix-cached serving path off-TPU.
     """
     K, S, Hq, D = q.shape
-    Hkv = k_pages.shape[0]
+    Hkv = kv_pages.shape[1]
     G = _gqa_expand(Hq, Hkv)
     sc = scale if scale is not None else 1.0 / math.sqrt(D)
     LOG2E = 1.4426950408889634
     page_tables = jnp.asarray(page_tables, jnp.int32)
-    k = _gather_pages(k_pages, page_tables)              # (K, n_max*ps, ...)
-    v = _gather_pages(v_pages, page_tables)
+    # (K, n_max*ps, Hkv, W) strips
+    kv = jnp.moveaxis(_gather_pages(kv_pages, layer, page_tables), 1, 2)
+    k, v = kv[..., :D], kv[..., D:2 * D]
     Skv = k.shape[1]
     qf = (q.astype(jnp.float32) * sc).reshape(K, S, Hkv, G, D)
     s = jnp.einsum("bshgd,bkhd->bshgk", qf, k.astype(jnp.float32))
@@ -303,7 +327,7 @@ def batched_paged_prefill_attention(q, k_pages, v_pages, page_tables,
     return o.reshape(K, S, Hq, D).astype(q.dtype)
 
 
-def paged_prefill_attention(q, k_pages, v_pages, page_row, q_offset, *,
+def paged_prefill_attention(q, kv_pages, layer, page_row, q_offset, *,
                             scale: Optional[float] = None,
                             window: int = 0,
                             logit_softcap: float = 0.0) -> jax.Array:
@@ -313,7 +337,7 @@ def paged_prefill_attention(q, k_pages, v_pages, page_row, q_offset, *,
     (true_len = q_offset + S), the historical single-row contract."""
     off = jnp.asarray(q_offset, jnp.int32).reshape(1)
     return batched_paged_prefill_attention(
-        q, k_pages, v_pages, jnp.asarray(page_row, jnp.int32)[None],
+        q, kv_pages, layer, jnp.asarray(page_row, jnp.int32)[None],
         off, off + q.shape[1], scale=scale, window=window,
         logit_softcap=logit_softcap)
 

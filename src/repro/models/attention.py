@@ -88,95 +88,77 @@ def attn_prefill(params, x, cfg: ModelConfig, cache_k, cache_v, *,
     return y, cache_k, cache_v
 
 
-def attn_prefill_paged(params, x, cfg: ModelConfig, k_pages, v_pages,
-                       page_ids, *, window: int = 0,
-                       impl: Optional[str] = None):
+def layer_window(cfg: ModelConfig, flag, attend):
+    """Run `attend(window)` under the gemma3 local:global per-layer cond
+    (window must be static for masking, so both paths live under lax.cond;
+    flag > 0 marks a global layer)."""
+    if cfg.sliding_window and cfg.global_every:
+        return jax.lax.cond(flag > 0,
+                            lambda: attend(0),
+                            lambda: attend(cfg.sliding_window))
+    return attend(cfg.sliding_window)
+
+
+def attn_prefill_paged(params, x, cfg: ModelConfig, kv_pages, layer, flag,
+                       plan, *, impl: Optional[str] = None):
     """Prefill one sequence's prompt into its KV pages.
 
     x: (1, S, D) with S a multiple of the page size (pad the prompt
     upstream; trailing pad K/V is masked by `lens` at decode time and gets
-    overwritten as decode advances).  k/v_pages: (Hkv, P, page_size, D)
-    head-major global pool; page_ids: (S // page_size,) pages owned by this sequence,
-    position-major.  Returns (y, k_pages, v_pages)."""
+    overwritten as decode advances).  kv_pages: the stacked (L, Hkv, P,
+    page_size, W) pool, written at layer `layer` (flag: the layer's
+    is-global flag, layer_window); plan: ops.kv_write_plan of positions
+    [0, S) through the sequence's pages, computed once for all layers.
+    Returns (y, kv_pages)."""
     q, k, v = _qkv(params, x, cfg)
     S = x.shape[1]
-    page_size = k_pages.shape[2]
-    n = S // page_size
     if cfg.use_rope:
         pos = jnp.arange(S)
         q = rope(q, pos, cfg.rope_theta, cfg.rope_scaling)
         k = rope(k, pos, cfg.rope_theta, cfg.rope_scaling)
-    kp = k[0].reshape(n, page_size, cfg.n_kv_heads, cfg.head_dim)
-    vp = v[0].reshape(n, page_size, cfg.n_kv_heads, cfg.head_dim)
-    k_pages = k_pages.at[:, page_ids].set(
-        jnp.moveaxis(kp, 2, 0).astype(k_pages.dtype))
-    v_pages = v_pages.at[:, page_ids].set(
-        jnp.moveaxis(vp, 2, 0).astype(v_pages.dtype))
-    o = ops.flash_attention(q, k, v, causal=True, window=window,
-                            logit_softcap=cfg.attn_logit_softcap, impl=impl)
+    kv_pages = ops.kv_page_write(kv_pages, layer, k, v, plan, impl=impl)
+    o = layer_window(cfg, flag, lambda w: ops.flash_attention(
+        q, k, v, causal=True, window=w,
+        logit_softcap=cfg.attn_logit_softcap, impl=impl))
     y = dense(params["wo"], o.reshape(1, S, cfg.n_heads * cfg.head_dim))
-    return y, k_pages, v_pages
+    return y, kv_pages
 
 
-def _tp_pool_constrain(pages, tp_mesh):
-    """Pin a KV page pool to its head-sharded layout on the serve mesh.
-
-    The engine commits the pools head-sharded at init; this re-asserts the
-    layout on the scatter output inside jit (per-layer pool slices inside
-    the layer scan carry no committed sharding of their own), so the
-    scatter stays a local per-shard write instead of a resharding round
-    trip.  The scattered K/V values are computed from replicated
-    activations, so the write is pure data movement - sharding it cannot
-    change any attention result."""
-    if tp_mesh is None:
-        return pages
-    from jax.sharding import NamedSharding, PartitionSpec as P
-    return jax.lax.with_sharding_constraint(
-        pages, NamedSharding(tp_mesh, P("model", None, None, None)))
-
-
-def attn_decode_paged(params, x, cfg: ModelConfig, k_pages, v_pages,
-                      block_table, lens, *, window: int = 0,
-                      impl: Optional[str] = None, tp_mesh=None):
+def attn_decode_paged(params, x, cfg: ModelConfig, kv_pages, layer, flag,
+                      block_table, lens, plan, *, impl: Optional[str] = None,
+                      tp_mesh=None):
     """Single-token decode through the block table.
 
-    x: (B, 1, D); k/v_pages: (Hkv, P, page_size, D) head-major global pool;
-    block_table: (B, n_max) page ids; lens: (B,) current lengths (the new
-    token's K/V is scattered into page lens // page_size at offset
-    lens % page_size).  Idle slots (lens == 0, block-table row zeroed) write
-    into the reserved null page 0, never into live pages.
-    tp_mesh: head-shard the pools and the decode kernel across the serve
-    mesh's "model" axis (kernels/ops.py paged_flash_decode); the attention
+    x: (B, 1, D); kv_pages: the stacked (L, Hkv, P, page_size, W) pool,
+    written and read at layer `layer` (flag: the layer's is-global flag,
+    layer_window); block_table: (B, n_max) page ids; lens: (B,) current
+    lengths (the new token's K/V goes into page lens // page_size at
+    offset lens % page_size; plan: ops.kv_write_plan of that write,
+    computed once for all layers).  Idle slots (lens == 0, block-table
+    row zeroed) write into the reserved null page 0, never into live
+    pages.
+    tp_mesh: head-shard the pool, the page write and the decode kernel
+    across the serve mesh's "model" axis (kernels/ops.py); the attention
     output gathers back to replicated so wo and everything after run with
-    tp=1 numerics.  Returns (y, k_pages, v_pages)."""
+    tp=1 numerics.  Returns (y, kv_pages)."""
     B = x.shape[0]
     q, k, v = _qkv(params, x, cfg)
     if cfg.use_rope:
         q = rope(q, lens[:, None], cfg.rope_theta, cfg.rope_scaling)
         k = rope(k, lens[:, None], cfg.rope_theta, cfg.rope_scaling)
-    page_size = k_pages.shape[2]
-    bidx = jnp.arange(B)
-    page = block_table[bidx, lens // page_size]
-    off = lens % page_size
-    # k[:, 0] is (B, Hkv, D); the pool is indexed (Hkv, page, off)
-    k_pages = k_pages.at[:, page, off].set(
-        jnp.moveaxis(k[:, 0], 1, 0).astype(k_pages.dtype))
-    v_pages = v_pages.at[:, page, off].set(
-        jnp.moveaxis(v[:, 0], 1, 0).astype(v_pages.dtype))
-    k_pages = _tp_pool_constrain(k_pages, tp_mesh)
-    v_pages = _tp_pool_constrain(v_pages, tp_mesh)
-    o = ops.paged_flash_decode(q, k_pages, v_pages, block_table, lens + 1,
-                               window=window,
-                               logit_softcap=cfg.attn_logit_softcap,
-                               impl=impl, tp_mesh=tp_mesh)
+    kv_pages = ops.kv_page_write(kv_pages, layer, k, v, plan, impl=impl,
+                                 tp_mesh=tp_mesh)
+    o = layer_window(cfg, flag, lambda w: ops.paged_flash_decode(
+        q, kv_pages, layer, block_table, lens + 1, window=w,
+        logit_softcap=cfg.attn_logit_softcap, impl=impl, tp_mesh=tp_mesh))
     y = dense(params["wo"], o.reshape(B, 1, cfg.n_heads * cfg.head_dim))
-    return y, k_pages, v_pages
+    return y, kv_pages
 
 
-def attn_prefill_chunks_paged(params, x, cfg: ModelConfig, k_pages, v_pages,
-                              page_tables, offsets, true_lens, *,
-                              q_lens=None, window: int = 0,
-                              impl: Optional[str] = None, tp_mesh=None):
+def attn_prefill_chunks_paged(params, x, cfg: ModelConfig, kv_pages, layer,
+                              flag, page_tables, offsets, true_lens, plan, *,
+                              q_lens=None, impl: Optional[str] = None,
+                              tp_mesh=None):
     """Prefill a RAGGED BATCH of mid-prompt chunks - K chunks of K
     different sequences, each at its own prompt position - into their
     pages, in one pass.
@@ -186,67 +168,33 @@ def attn_prefill_chunks_paged(params, x, cfg: ModelConfig, k_pages, v_pages,
     length (true_lens[k] - offsets[k]).  Pages already holding K/V for
     positions < offsets[k] (cached prefix + earlier chunks) sit at the
     front of row k's block-table row page_tables[k].  Each row's chunk
-    K/V is scattered token-by-token through its table row - a chunk need
-    not start on a page boundary - with PAD positions redirected to the
-    null page 0, so two chunks of the SAME sequence packed into one batch
-    never collide (row A's pad tail would otherwise race row B's real
-    writes at the same positions).  Then all rows' queries attend over
-    every earlier position AND their own chunk via the offset-causal
-    batched kernel (kernels/paged_prefill.py), so packing the
-    scheduler's whole per-tick chunk plan into ONE launch is exact.
-    Dead padding rows (true_len == 0, all-null table row) write only to
-    the null page and return garbage rows the caller discards.
-    Returns (y, k_pages, v_pages)."""
+    K/V goes into layer `layer` of the stacked pool through its table row
+    (ops.kv_page_write, with plan the ops.kv_write_plan of the rows,
+    computed once for all layers) - a chunk need not start on a page
+    boundary, and
+    PAD positions are not written, so two chunks of the SAME sequence
+    packed into one batch never collide (row A's pad tail would otherwise
+    race row B's real writes at the same positions).  Then all rows'
+    queries attend over every earlier position AND their own chunk via
+    the offset-causal batched kernel (kernels/paged_prefill.py), so
+    packing the scheduler's whole per-tick chunk plan into ONE launch is
+    exact.  Dead padding rows (true_len == 0, all-null table row) write
+    nothing and return garbage rows the caller discards.  flag: the
+    layer's is-global flag (layer_window).  Returns (y, kv_pages)."""
     q, k, v = _qkv(params, x, cfg)
     K, S = x.shape[:2]
-    page_size = k_pages.shape[2]
-    n_max = page_tables.shape[1]
     pos = jnp.asarray(offsets, jnp.int32)[:, None] + jnp.arange(S)[None, :]
     if cfg.use_rope:
         q = rope(q, pos, cfg.rope_theta, cfg.rope_scaling)
         k = rope(k, pos, cfg.rope_theta, cfg.rope_scaling)
-    valid = pos < jnp.asarray(true_lens, jnp.int32)[:, None]    # (K, S)
-    pidx = jnp.minimum(pos // page_size, n_max - 1)
-    pages = jnp.where(valid, jnp.take_along_axis(page_tables, pidx, axis=1),
-                      0)
-    offs = jnp.where(valid, pos % page_size, 0)
-    # k is (K, S, Hkv, D); the pool is indexed (Hkv, page, off)
-    k_pages = k_pages.at[:, pages, offs].set(
-        jnp.moveaxis(k, 2, 0).astype(k_pages.dtype))
-    v_pages = v_pages.at[:, pages, offs].set(
-        jnp.moveaxis(v, 2, 0).astype(v_pages.dtype))
-    k_pages = _tp_pool_constrain(k_pages, tp_mesh)
-    v_pages = _tp_pool_constrain(v_pages, tp_mesh)
-    o = ops.batched_paged_prefill_attention(
-        q, k_pages, v_pages, page_tables, offsets, true_lens, q_lens,
-        window=window, logit_softcap=cfg.attn_logit_softcap, impl=impl,
-        tp_mesh=tp_mesh)
+    kv_pages = ops.kv_page_write(kv_pages, layer, k, v, plan, impl=impl,
+                                 tp_mesh=tp_mesh)
+    o = layer_window(cfg, flag, lambda w: ops.batched_paged_prefill_attention(
+        q, kv_pages, layer, page_tables, offsets, true_lens, q_lens,
+        window=w, logit_softcap=cfg.attn_logit_softcap, impl=impl,
+        tp_mesh=tp_mesh))
     y = dense(params["wo"], o.reshape(K, S, cfg.n_heads * cfg.head_dim))
-    return y, k_pages, v_pages
-
-
-def attn_prefill_chunk_paged(params, x, cfg: ModelConfig, k_pages, v_pages,
-                             page_row, offset, *, window: int = 0,
-                             impl: Optional[str] = None):
-    """Prefill one MID-PROMPT chunk of one sequence's prompt into its
-    pages: the K=1 special case of attn_prefill_chunks_paged.
-
-    x: (1, S, D) holds a contiguous run of prompt tokens at absolute
-    positions offset + arange(S) - the uncached suffix after a
-    prefix-cache hit (serve/prefix_cache.py), or a single budget chunk.
-    Every position of x is treated as real (true_len = offset + S): the
-    historical single-row contract, where trailing pad K/V lands in the
-    sequence's own reserved pages and is masked by `lens` at decode time.
-    Returns (y, k_pages, v_pages)."""
-    off = jnp.asarray(offset, jnp.int32).reshape(1)
-    return attn_prefill_chunks_paged(
-        params, x, cfg, k_pages, v_pages,
-        jnp.asarray(page_row, jnp.int32)[None], off, off + x.shape[1],
-        window=window, impl=impl)
-
-
-# the prefix-cache suffix is the final-chunk special case
-attn_prefill_suffix_paged = attn_prefill_chunk_paged
+    return y, kv_pages
 
 
 def attn_decode(params, x, cfg: ModelConfig, cache_k, cache_v, lens, *,

@@ -146,9 +146,11 @@ def build_model(cfg: ModelConfig) -> Model:
     def init_cache(batch_size: int, max_len: int, enc_len: int = 0, *,
                    page_size: int = 0, num_pages: int = 0):
         """Dense layout by default; page_size > 0 selects the paged layout:
-        a global head-major (L, Hkv, num_pages, page_size, D) page pool
-        shared by all sequences plus a (batch, ceil(max_len/page_size)) block table.  Page
-        0 is reserved as the null page (see serve/paged_cache.py)."""
+        one stacked (L, Hkv, num_pages, page_size, 2 * head_dim) page pool
+        shared by all sequences and layers - K in lanes [0, D) and V in
+        [D, 2D) of each token row - plus a (batch, ceil(max_len/page_size))
+        block table.  Page 0 is reserved as the null page (see
+        serve/paged_cache.py)."""
         dt = pdtype(cfg)
         if page_size > 0:
             if fam not in ("dense", "moe", "vlm"):
@@ -160,9 +162,8 @@ def build_model(cfg: ModelConfig) -> Model:
             if num_pages <= 0:
                 num_pages = dense_equivalent_pages(batch_size, max_len,
                                                    page_size)
-            shp = (L, cfg.n_kv_heads, num_pages, page_size, cfg.head_dim)
-            return {"k_pages": jnp.zeros(shp, dt),
-                    "v_pages": jnp.zeros(shp, dt),
+            shp = (L, cfg.n_kv_heads, num_pages, page_size, 2 * cfg.head_dim)
+            return {"kv_pages": jnp.zeros(shp, dt),
                     "block_table": jnp.zeros((batch_size, n_max), jnp.int32)}
         if fam in ("dense", "moe", "vlm"):
             L = cfg.n_layers
@@ -419,7 +420,7 @@ def build_model(cfg: ModelConfig) -> Model:
                 x = x + jnp.take(tbl, jnp.minimum(lens, 65535),
                                  axis=0)[:, None].astype(x.dtype)
             if fam in ("dense", "moe", "vlm"):
-                if "k_pages" in cache:
+                if "kv_pages" in cache:
                     if seq_parallel:
                         raise ValueError(
                             "paged decode does not compose with the "

@@ -22,10 +22,11 @@ import jax
 import jax.numpy as jnp
 
 from ..configs.base import ModelConfig
+from ..kernels import ops
 from ..sharding import constrain
 from .attention import (attn_decode, attn_decode_paged, attn_forward,
-                        attn_init, attn_prefill, attn_prefill_chunk_paged,
-                        attn_prefill_chunks_paged, attn_prefill_paged)
+                        attn_init, attn_prefill, attn_prefill_chunks_paged,
+                        attn_prefill_paged, layer_window)
 from .layers import apply_norm, grad_cast, mlp, mlp_init, norm_init, pdtype
 from .mamba2 import (mamba2_decode, mamba2_forward, mamba2_init,
                      mamba2_init_state, mamba2_prefill)
@@ -118,17 +119,6 @@ def stack_init(key, cfg: ModelConfig):
     return _stack_trees(layers)
 
 
-def _windowed(cfg: ModelConfig, flag, attn_call):
-    """Run `attn_call(window)` under the gemma3 local:global per-layer cond
-    (window must be static for masking, so both paths live under lax.cond).
-    Shared by all four cache-walking stacks below."""
-    if cfg.sliding_window and cfg.global_every:
-        return jax.lax.cond(flag > 0,
-                            lambda: attn_call(0),
-                            lambda: attn_call(cfg.sliding_window))
-    return attn_call(cfg.sliding_window)
-
-
 def _ffn_tail(p, x, cfg: ModelConfig):
     """Post-attention half of a block: norm -> (moe|mlp) -> residual."""
     y_in = apply_norm(p["n2"], x, cfg)
@@ -177,7 +167,7 @@ def stack_prefill(params, x, cfg: ModelConfig, cache, *, impl=None):
         p, ck, cv, flag = xs
         x = constrain(x, "btd")
         h_in = apply_norm(p["n1"], x, cfg)
-        h, ck, cv = _windowed(
+        h, ck, cv = layer_window(
             cfg, flag,
             lambda w: attn_prefill(p["attn"], h_in, cfg, ck, cv, window=w,
                                    impl=impl))
@@ -195,7 +185,7 @@ def stack_decode(params, x, cfg: ModelConfig, cache, lens, *, impl=None,
     def body(x, xs):
         p, ck, cv, flag = xs
         h_in = apply_norm(p["n1"], x, cfg)
-        h, ck, cv = _windowed(
+        h, ck, cv = layer_window(
             cfg, flag,
             lambda w: attn_decode(p["attn"], h_in, cfg, ck, cv, lens,
                                   window=w, impl=impl,
@@ -207,29 +197,41 @@ def stack_decode(params, x, cfg: ModelConfig, cache, lens, *, impl=None,
     return x, {"k": ck, "v": cv}
 
 
+def _scan_paged(cfg: ModelConfig, params, x, kv_pages, attn):
+    """Scan the decoder layers with the stacked page pool as carry: layer
+    l's block runs `attn(p, h_in, kv_pages, l, flag) -> (h, kv_pages)`,
+    and nothing of pool size passes through the scan's xs or ys.  The
+    callers compute the write's plan (ops.kv_write_plan) before the scan:
+    it is the same for every layer."""
+    def body(carry, xs):
+        x, kv = carry
+        p, flag, l = xs
+        x = constrain(x, "btd")
+        h, kv = attn(p["attn"], apply_norm(p["n1"], x, cfg), kv, l, flag)
+        return (_ffn_tail(p, x + h, cfg), kv), None
+
+    (x, kv_pages), _ = jax.lax.scan(
+        body, (x, kv_pages),
+        (params, _layer_windows(cfg), jnp.arange(cfg.n_layers)))
+    return x, kv_pages
+
+
 def stack_prefill_paged(params, x, cfg: ModelConfig, cache, page_ids, *,
                         impl=None):
     """Paged prefill of ONE sequence (B=1), x: (1, S, D) with S a multiple
-    of the page size.  cache: {"k_pages"/"v_pages": (L, Hkv, P, page, D),
+    of the page size.  cache: {"kv_pages": (L, Hkv, P, page, W),
     "block_table": (B, n_max)}; page_ids: (S // page,) pages owned by the
     sequence.  The block table itself is host-managed (serve/paged_cache.py)
     and passes through untouched."""
-    flags = _layer_windows(cfg)
-
-    def body(x, xs):
-        p, kp, vp, flag = xs
-        x = constrain(x, "btd")
-        h_in = apply_norm(p["n1"], x, cfg)
-        h, kp, vp = _windowed(
-            cfg, flag,
-            lambda w: attn_prefill_paged(p["attn"], h_in, cfg, kp, vp,
-                                         page_ids, window=w, impl=impl))
-        return _ffn_tail(p, x + h, cfg), (kp, vp)
-
-    x, (kp, vp) = jax.lax.scan(
-        body, x, (params, cache["k_pages"], cache["v_pages"], flags))
-    return x, {"k_pages": kp, "v_pages": vp,
-               "block_table": cache["block_table"]}
+    S, ps = x.shape[1], cache["kv_pages"].shape[3]
+    zero = jnp.zeros((1,), jnp.int32)
+    plan = ops.kv_write_plan(jnp.asarray(page_ids, jnp.int32)[None], zero,
+                             zero + S, S, ps, impl=impl)
+    x, kv = _scan_paged(
+        cfg, params, x, cache["kv_pages"],
+        lambda p, h, kv, l, flag: attn_prefill_paged(
+            p, h, cfg, kv, l, flag, plan, impl=impl))
+    return x, {"kv_pages": kv, "block_table": cache["block_table"]}
 
 
 def stack_prefill_chunks_paged(params, x, cfg: ModelConfig, cache,
@@ -242,30 +244,19 @@ def stack_prefill_chunks_paged(params, x, cfg: ModelConfig, cache,
     page_tables: (K, n_max) per-row block-table rows - pages already
     holding K/V (cached prefix + earlier chunks) first, then the pages
     each chunk and decode will fill.  Two chunks of the SAME sequence may
-    share a batch (ordered offsets): each layer scatters every row's K/V
+    share a batch (ordered offsets): each layer writes every row's K/V
     before its attention reads the pool, so the later chunk sees the
     earlier one exactly as if they had run back to back.  The block table
     itself is host-managed (serve/paged_cache.py) and passes through
     untouched."""
-    flags = _layer_windows(cfg)
-
-    def body(x, xs):
-        p, kp, vp, flag = xs
-        x = constrain(x, "btd")
-        h_in = apply_norm(p["n1"], x, cfg)
-        h, kp, vp = _windowed(
-            cfg, flag,
-            lambda w: attn_prefill_chunks_paged(p["attn"], h_in, cfg, kp,
-                                                vp, page_tables, offsets,
-                                                true_lens, q_lens=q_lens,
-                                                window=w, impl=impl,
-                                                tp_mesh=tp_mesh))
-        return _ffn_tail(p, x + h, cfg), (kp, vp)
-
-    x, (kp, vp) = jax.lax.scan(
-        body, x, (params, cache["k_pages"], cache["v_pages"], flags))
-    return x, {"k_pages": kp, "v_pages": vp,
-               "block_table": cache["block_table"]}
+    plan = ops.kv_write_plan(page_tables, offsets, true_lens, x.shape[1],
+                             cache["kv_pages"].shape[3], impl=impl)
+    x, kv = _scan_paged(
+        cfg, params, x, cache["kv_pages"],
+        lambda p, h, kv, l, flag: attn_prefill_chunks_paged(
+            p, h, cfg, kv, l, flag, page_tables, offsets, true_lens, plan,
+            q_lens=q_lens, impl=impl, tp_mesh=tp_mesh))
+    return x, {"kv_pages": kv, "block_table": cache["block_table"]}
 
 
 def stack_prefill_chunk_paged(params, x, cfg: ModelConfig, cache, page_row,
@@ -287,23 +278,16 @@ stack_prefill_suffix_paged = stack_prefill_chunk_paged
 def stack_decode_paged(params, x, cfg: ModelConfig, cache, lens, *,
                        impl=None, tp_mesh=None):
     """Batched single-token decode through the block table (all layers share
-    one table; each layer owns its own page pool slab)."""
-    flags = _layer_windows(cfg)
+    one table; the stacked pool holds every layer's pages)."""
     bt = cache["block_table"]
-
-    def body(x, xs):
-        p, kp, vp, flag = xs
-        h_in = apply_norm(p["n1"], x, cfg)
-        h, kp, vp = _windowed(
-            cfg, flag,
-            lambda w: attn_decode_paged(p["attn"], h_in, cfg, kp, vp, bt,
-                                        lens, window=w, impl=impl,
-                                        tp_mesh=tp_mesh))
-        return _ffn_tail(p, x + h, cfg), (kp, vp)
-
-    x, (kp, vp) = jax.lax.scan(
-        body, x, (params, cache["k_pages"], cache["v_pages"], flags))
-    return x, {"k_pages": kp, "v_pages": vp, "block_table": bt}
+    plan = ops.kv_write_plan(bt, lens, lens + 1, 1,
+                             cache["kv_pages"].shape[3], impl=impl)
+    x, kv = _scan_paged(
+        cfg, params, x, cache["kv_pages"],
+        lambda p, h, kv, l, flag: attn_decode_paged(
+            p, h, cfg, kv, l, flag, bt, lens, plan, impl=impl,
+            tp_mesh=tp_mesh))
+    return x, {"kv_pages": kv, "block_table": bt}
 
 
 # ===========================================================================

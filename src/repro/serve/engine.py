@@ -113,7 +113,8 @@ from .paged_cache import (PageAllocator, pages_needed, page_kv_bytes,
 from .prefix_cache import RadixPrefixCache
 from .scheduler import (ChunkTask, DraftTask, Request, RequestState,
                         SpecBatch, TokenBudgetScheduler)
-from .serve_step import (make_chunk_batch_step, make_chunk_prefill_step,
+from .serve_step import (copy_page, make_chunk_batch_step,
+                         make_chunk_prefill_step,
                          make_fused_decode_step, make_paged_prefill_step,
                          make_prefill_step, make_serve_step,
                          make_spec_verify_step, sample_token)
@@ -193,6 +194,7 @@ def _shared_steps(model: Model, temperature: float, top_k: int = 0,
                 make_chunk_batch_step(model, temperature=temperature,
                                       top_k=top_k, top_p=top_p,
                                       tp_mesh=tp_mesh), 2)
+            steps["copy_page"] = _jit_donating_cache(copy_page, 0)
         if model.verify_chunks is not None:
             # the speculative verify launch: one ragged batch scores every
             # draft chain and folds acceptance into tokens/lens device-side
@@ -317,8 +319,8 @@ class ServeEngine:
                 raise ValueError(f"paged serving needs an attention family, "
                                  f"got {cfg.family}")
             if scfg.max_seq % scfg.page_size:
-                # the page-multiple invariant (attn_prefill_paged reshapes
-                # prompts into whole pages) must hold at the max_seq cap too
+                # the page-multiple invariant (attn_prefill_paged writes
+                # prompts as whole pages) must hold at the max_seq cap too
                 raise ValueError(
                     f"max_seq ({scfg.max_seq}) must be a multiple of "
                     f"page_size ({scfg.page_size})")
@@ -342,7 +344,7 @@ class ServeEngine:
                 from ..launch.mesh import make_serve_mesh
                 self.tp_mesh = make_serve_mesh(scfg.tp_degree)
                 # commit placement ONCE at construction: the (L, Hkv, P,
-                # ps, D) pools head-sharded, params fully replicated, so
+                # ps, W) pool head-sharded, params fully replicated, so
                 # every jitted step compiles against stable shardings and
                 # per-tick uploads (block table, chunk packs, tokens) stay
                 # small uncommitted host arrays jit re-shards for free
@@ -351,8 +353,7 @@ class ServeEngine:
                                                  None))
                 rep = NamedSharding(self.tp_mesh, PartitionSpec())
                 self.cache = {
-                    "k_pages": jax.device_put(self.cache["k_pages"], hs),
-                    "v_pages": jax.device_put(self.cache["v_pages"], hs),
+                    "kv_pages": jax.device_put(self.cache["kv_pages"], hs),
                     "block_table": jax.device_put(
                         self.cache["block_table"], rep),
                 }
@@ -404,6 +405,7 @@ class ServeEngine:
             self._prefill_paged = steps["prefill_paged"]
             self._prefill_chunk = steps["prefill_chunk"]
             self._prefill_chunks = steps["prefill_chunks"]
+            self._copy_page_step = steps["copy_page"]
         if self.speculative:
             self._spec_verify = steps["spec_verify"]
 
@@ -1027,12 +1029,11 @@ class ServeEngine:
         return self.allocator.can_alloc(n)
 
     def _copy_page(self, src: int, dst: int):
-        """Device-side copy of one page across every layer's K and V slab
-        (the data half of copy-on-write; the allocator did the
-        bookkeeping)."""
-        for key in ("k_pages", "v_pages"):
-            slab = self.cache[key]
-            self.cache[key] = slab.at[:, :, dst].set(slab[:, :, src])
+        """Device-side copy of one page across every layer's K and V (the
+        data half of copy-on-write; the allocator did the bookkeeping), in
+        place in the donated pool (serve_step.copy_page)."""
+        self.cache["kv_pages"] = self._copy_page_step(self.cache["kv_pages"],
+                                                      src, dst)
 
     def _admit_paged(self, slot: int, req: Request) -> bool:
         """Paged cache: reserve the request's worst case up front; prefill

@@ -1,7 +1,7 @@
 """Serving steps: batched single-token decode + (dense or paged) prefill.
 
 The decode step is cache-layout agnostic: pass the dense {"k","v"} cache or
-the paged {"k_pages","v_pages","block_table"} cache and decode_step routes
+the paged {"kv_pages","block_table"} cache and decode_step routes
 to the matching kernel (kernels/flash_decode.py).
 
 Lane masking contract (what preemption and chunked prefill lean on): the
@@ -83,6 +83,15 @@ def make_chunk_prefill_step(model: Model):
         return model.prefill_chunk(params, batch, cache, page_row)
 
     return chunk_prefill_step
+
+
+def copy_page(kv_pages, src, dst):
+    """copy_page(kv_pages, src, dst) -> kv_pages: page `src` of the
+    stacked (L, Hkv, P, page_size, W) pool copied to page `dst` in every
+    layer and head (the data half of copy-on-write).  Jitted with the
+    pool donated, the update is in place: one page is read and written."""
+    page = jax.lax.dynamic_slice_in_dim(kv_pages, src, 1, axis=2)
+    return jax.lax.dynamic_update_slice_in_dim(kv_pages, page, dst, axis=2)
 
 
 def make_chunk_batch_step(model: Model, *, temperature: float,

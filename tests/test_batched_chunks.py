@@ -25,6 +25,7 @@ from repro.serve import ServeEngine, RequestState, TokenBudgetScheduler
 from repro.serve.scheduler import ChunkTask, Request, bucket_rows
 
 # shared traffic-replay harness (tests/traffic.py)
+from pools import LAYER, stack_pool
 from traffic import MIXED_LENS, mixed_prompts as _mixed_prompts, \
     serve_all as _serve
 
@@ -57,8 +58,8 @@ def test_batched_kernel_matches_single_rows(impl, window, rng):
     tables, and a dead padding row returning exactly zero."""
     S, Hq, Hkv, D, ps, n_pages, n_max = 8, 4, 2, 16, 4, 24, 8
     ks = jax.random.split(rng, 3)
-    k_pages = jax.random.normal(ks[0], (Hkv, n_pages, ps, D))
-    v_pages = jax.random.normal(ks[1], (Hkv, n_pages, ps, D))
+    pool = stack_pool(jax.random.normal(ks[0], (Hkv, n_pages, ps, D)),
+                      jax.random.normal(ks[1], (Hkv, n_pages, ps, D)))
     q = jax.random.normal(ks[2], (4, S, Hq, D))
     perm = np.random.default_rng(0).permutation(
         np.arange(1, n_pages)).astype(np.int32)
@@ -70,11 +71,11 @@ def test_batched_kernel_matches_single_rows(impl, window, rng):
     # rows 0/2 full chunks, row 1 ragged (3 real tokens), row 3 DEAD
     tls = np.array([4 + S, 17 + 3, 0 + S, 0], np.int32)
     got = ops.batched_paged_prefill_attention(
-        q, k_pages, v_pages, jnp.asarray(tables), jnp.asarray(offs),
+        q, pool, LAYER, jnp.asarray(tables), jnp.asarray(offs),
         jnp.asarray(tls), window=window, impl=impl)
     for r in range(3):
         want = ops.paged_prefill_attention(
-            q[r:r + 1], k_pages, v_pages, jnp.asarray(tables[r]),
+            q[r:r + 1], pool, LAYER, jnp.asarray(tables[r]),
             int(offs[r]), window=window, impl=impl)
         n_real = int(tls[r] - offs[r])
         err = float(jnp.abs(got[r, :n_real] - want[0, :n_real]).max())
@@ -100,14 +101,14 @@ def test_same_sequence_two_chunks_one_launch(impl, rng):
     for j in range(n_pages):
         k_pages = k_pages.at[j + 1].set(k[0, j * ps:(j + 1) * ps])
         v_pages = v_pages.at[j + 1].set(v[0, j * ps:(j + 1) * ps])
-    # the pool is head-major: (Hkv, P, ps, D)
-    k_pages, v_pages = jnp.moveaxis(k_pages, 2, 0), jnp.moveaxis(v_pages, 2, 0)
+    # the pages are head-major: (Hkv, P, ps, D)
+    pool = stack_pool(jnp.moveaxis(k_pages, 2, 0), jnp.moveaxis(v_pages, 2, 0))
     row = np.arange(1, n_pages + 1, dtype=np.int32)
     tables = np.stack([row, row])
     half = S // 2
     qb = jnp.stack([q[0, :half], q[0, half:]])
     got = ops.batched_paged_prefill_attention(
-        qb, k_pages, v_pages, jnp.asarray(tables),
+        qb, pool, LAYER, jnp.asarray(tables),
         jnp.asarray([0, half], jnp.int32),
         jnp.asarray([half, S], jnp.int32), impl=impl)
     err = float(jnp.abs(jnp.concatenate([got[0], got[1]])[None] - want).max())

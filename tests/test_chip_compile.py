@@ -3,10 +3,14 @@
 Interpret mode (how every other test runs the kernels on the CPU) never
 checks the TPU's tiling rules: the last two dimensions of a block must be
 multiples of (8, 128) or span the whole array dimension.  These tests
-compile each attention kernel of the serving path, at granite-3-2b's
-published widths (32 query heads, 8 KV heads, head_dim 64, bf16), for a
-v5e that is described but not attached.  The TPU compiler refuses here
-exactly what it would refuse on the chip.
+compile each kernel of the serving path, at granite-3-2b's published
+widths (32 query heads, 8 KV heads, head_dim 64, bf16), for a v5e that is
+described but not attached.  The TPU compiler refuses here exactly what
+it would refuse on the chip.
+
+The step guard compiles the program's own fused decode and chunk-batch
+steps the same way and reads the optimized HLO: no XLA op may copy,
+slice or restack the KV page pool, which only the Pallas calls touch.
 
 The topology is described inside a fixture, never at import time: only
 one process at a time may load the TPU library, and the test workers all
@@ -26,11 +30,26 @@ from repro.configs import get_config
 from repro.kernels import backend, ops
 from repro.kernels import flash_attention as fa
 from repro.kernels import flash_decode as fd
+from repro.kernels import kv_write as kw
 from repro.kernels import paged_prefill as pp
+from repro.models import build_model
+from repro.serve import serve_step
 
 CFG = get_config("granite-3-2b")
 HQ, HKV, D = CFG.n_heads, CFG.n_kv_heads, CFG.head_dim
+# the paged path at each head_dim a paged config stores: granite-3-2b's
+# 64 (pool rows of 128 lanes) and opt-6.7b's 128 (256 lanes)
+PAGED_CFGS = {64: CFG, 128: get_config("opt-6.7b")}
 DT = jnp.bfloat16
+
+
+def _with_head_dims(name, values):
+    """Parametrize (name, head_dim) over every value at each paged
+    head_dim; the head_dim-64 cases keep the bare value as their id."""
+    return pytest.mark.parametrize(
+        (name, "head_dim"),
+        [pytest.param(v, hd, id=str(v) if hd == 64 else f"{v}-hd{hd}")
+         for hd in sorted(PAGED_CFGS) for v in values])
 BATCH, MAX_SEQ, CHUNK = 8, 2048, 512     # chip_smoke.py's engine
 
 
@@ -55,11 +74,12 @@ def one_chip(topo):
 
 @pytest.fixture
 def for_chip(monkeypatch):
-    """Compile the kernels (interpret mode off) with the persistent
-    compilation cache off: a TPU executable written here could not be
-    read back without a chip."""
+    """Compile the kernels (interpret mode off, the model on the Pallas
+    path) with the persistent compilation cache off: a TPU executable
+    written here could not be read back without a chip."""
     from jax.experimental.compilation_cache import compilation_cache
     monkeypatch.setattr(backend, "interpret_mode", lambda: False)
+    monkeypatch.setattr(ops, "default_impl", lambda: "pallas")
     was = jax.config.jax_enable_compilation_cache
     jax.config.update("jax_enable_compilation_cache", False)
     compilation_cache.reset_cache()
@@ -74,13 +94,17 @@ def _spec(shape, dtype, sharding):
     return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
 
 
-def _paged_args(sharding, rows, q_len, page_size):
-    """q plus a head-major pool sized like chip_smoke.py's engine
-    (dense-equivalent pages + the null page), with per-row tables."""
+def _paged_args(sharding, rows, q_len, page_size, layers=2, cfg=CFG):
+    """q plus a stacked lane-dense pool sized like chip_smoke.py's engine
+    (dense-equivalent pages + the null page), a layer index and per-row
+    tables, at cfg's widths."""
     n_max = MAX_SEQ // page_size
     pages = BATCH * n_max + 1
-    pool = _spec((HKV, pages, page_size, D), DT, sharding)
-    return (_spec((rows, q_len, HQ, D), DT, sharding), pool, pool,
+    pool = _spec((layers, cfg.n_kv_heads, pages, page_size,
+                  2 * cfg.head_dim), DT, sharding)
+    return (_spec((rows, q_len, cfg.n_heads, cfg.head_dim), DT, sharding),
+            pool,
+            _spec((), jnp.int32, sharding),
             _spec((rows, n_max), jnp.int32, sharding))
 
 
@@ -95,26 +119,58 @@ def _kernel_named(compiled, name: str) -> bool:
                      rf'"tpu_custom_call"', compiled.as_text()) is not None
 
 
-@pytest.mark.parametrize("page_size", [16, 128])
-def test_paged_flash_decode_compiles(topo, one_chip, for_chip, page_size):
-    q, kp, vp, bt = _paged_args(one_chip, BATCH, 1, page_size)
+@_with_head_dims("page_size", [16, 128])
+def test_paged_flash_decode_compiles(topo, one_chip, for_chip, page_size,
+                                     head_dim):
+    q, pool, layer, bt = _paged_args(one_chip, BATCH, 1, page_size,
+                                     cfg=PAGED_CFGS[head_dim])
     lens = _spec((BATCH,), jnp.int32, one_chip)
-    compiled = fd.paged_flash_decode.lower(q, kp, vp, bt, lens).compile()
+    compiled = fd.paged_flash_decode.lower(q, pool, layer, bt,
+                                           lens).compile()
     assert _kernel_named(compiled, "paged_flash_decode")
+    # the kernel reads the pool where it lies: no relayout copy first
+    assert compiled.memory_analysis().temp_size_in_bytes == 0
 
 
-@pytest.mark.parametrize("page_size", [16, 128])
-def test_batched_paged_prefill_compiles(topo, one_chip, for_chip, page_size):
+@_with_head_dims("page_size", [16, 128])
+def test_batched_paged_prefill_compiles(topo, one_chip, for_chip, page_size,
+                                        head_dim):
     rows = 4
-    q, kp, vp, tables = _paged_args(one_chip, rows, CHUNK, page_size)
+    q, pool, layer, tables = _paged_args(one_chip, rows, CHUNK, page_size,
+                                         cfg=PAGED_CFGS[head_dim])
     vec = _spec((rows,), jnp.int32, one_chip)
     compiled = pp.batched_paged_prefill_attention.lower(
-        q, kp, vp, tables, vec, vec, vec).compile()
+        q, pool, layer, tables, vec, vec, vec).compile()
     assert _kernel_named(compiled, "batched_paged_prefill_attention")
     # nothing beyond the kernel's own output lives in HBM at a full chunk
     mem = compiled.memory_analysis()
-    assert mem.output_size_in_bytes == rows * CHUNK * HQ * D * 2
+    assert mem.output_size_in_bytes == q.size * 2
     assert mem.temp_size_in_bytes <= 4 * mem.output_size_in_bytes, mem
+
+
+@_with_head_dims("n_tokens", [1, CHUNK])
+def test_kv_page_write_compiles(topo, one_chip, for_chip, n_tokens,
+                                head_dim):
+    """The page write, decode rows (one token) and chunk rows, writes the
+    donated pool in place."""
+    cfg = PAGED_CFGS[head_dim]
+    rows = BATCH if n_tokens == 1 else 4
+    _, pool, layer, tables = _paged_args(one_chip, rows, n_tokens, 128,
+                                         cfg=cfg)
+    new = _spec((rows, n_tokens, cfg.n_kv_heads, 2 * head_dim), DT,
+                one_chip)
+    vec = _spec((rows,), jnp.int32, one_chip)
+
+    def write(pool, layer, new, tables, starts, ends):
+        plan = kw.write_plan(tables, starts, ends, n_tokens, 128)
+        return kw.kv_page_write(pool, layer, new, plan)
+
+    compiled = jax.jit(write, donate_argnums=(0,)).lower(
+        pool, layer, new, tables, vec, vec).compile()
+    assert _kernel_named(compiled, "kv_page_write")
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes == pool.size * 2
+    assert mem.temp_size_in_bytes < (1 << 20), mem
 
 
 def test_dense_flash_decode_compiles(topo, one_chip, for_chip):
@@ -138,18 +194,126 @@ def test_tp_sharded_paged_decode_compiles(topo, for_chip):
     mesh = Mesh(np.array(topo.devices).reshape(1, 4), ("data", "model"))
     rep = NamedSharding(mesh, P())
     page_size = 128
-    q, _, _, bt = _paged_args(rep, BATCH, 1, page_size)
-    pool = _spec((HKV, BATCH * MAX_SEQ // page_size + 1, page_size, D), DT,
-                 NamedSharding(mesh, P("model", None, None, None)))
+    q, _, layer, bt = _paged_args(rep, BATCH, 1, page_size)
+    pool = _spec((2, HKV, BATCH * MAX_SEQ // page_size + 1, page_size,
+                  2 * D), DT,
+                 NamedSharding(mesh, P(None, "model", None, None, None)))
     lens = _spec((BATCH,), jnp.int32, rep)
 
-    def step(q, kp, vp, bt, lens):
-        return ops.paged_flash_decode(q, kp, vp, bt, lens, impl="pallas",
-                                      tp_mesh=mesh)
+    def step(q, pool, layer, bt, lens):
+        return ops.paged_flash_decode(q, pool, layer, bt, lens,
+                                      impl="pallas", tp_mesh=mesh)
 
-    compiled = jax.jit(step).lower(q, pool, pool, bt, lens).compile()
+    compiled = jax.jit(step).lower(q, pool, layer, bt, lens).compile()
     hlo = compiled.as_text()
     assert "tpu_custom_call" in hlo and "all-gather" in hlo
     # each chip holds a quarter of the pool
     mem = compiled.memory_analysis()
-    assert mem.argument_size_in_bytes < 2 * pool.size * 2 // 4 + (1 << 20)
+    assert mem.argument_size_in_bytes < pool.size * 2 // 4 + (1 << 20)
+
+
+# ===========================================================================
+# the step guard: the KV page pool is touched only by the Pallas calls
+# ===========================================================================
+
+STEP_LAYERS, STEP_BATCH, STEP_PAGES, STEP_SEQ, STEP_ROWS = 2, 64, 480, 4096, 4
+# HLO ops that may produce an array of pool size: the pool passes through
+# the layer loop and comes out of the aliased page write, nothing else
+POOL_OPS = {"parameter", "get-tuple-element", "tuple", "while", "bitcast",
+            "custom-call"}
+
+
+# the steps at each pool width; at head_dim 128 granite-8b, not opt-6.7b,
+# whose decode step builds a 65536 x d_model sinusoid table (1 GiB of
+# temporaries that have nothing to do with the pool)
+STEP_CFGS = {64: CFG, 128: get_config("granite-8b")}
+
+
+def _compile_step(one_chip, kind, head_dim):
+    cfg = STEP_CFGS[head_dim].replace(n_layers=STEP_LAYERS)
+    model = build_model(cfg)
+
+    def spec(x):
+        return _spec(x.shape, x.dtype, one_chip)
+
+    params = jax.tree.map(spec, jax.eval_shape(model.init,
+                                               jax.random.key(0)))
+    cache = jax.tree.map(spec, jax.eval_shape(lambda: model.init_cache(
+        STEP_BATCH, STEP_SEQ, page_size=128, num_pages=STEP_PAGES)))
+    vec = lambda *s: _spec(s, jnp.int32, one_chip)          # noqa: E731
+    key = _spec((2,), jnp.uint32, one_chip)
+    if kind == "decode":
+        fn = serve_step.make_fused_decode_step(model, temperature=0.0)
+        args = (params, cache, vec(STEP_BATCH, 1), vec(STEP_BATCH),
+                _spec((STEP_BATCH,), jnp.bool_, one_chip), key)
+        donate = 1
+    else:
+        fn = serve_step.make_chunk_batch_step(model, temperature=0.0)
+        batch = {"tokens": vec(STEP_ROWS, CHUNK), "offset": vec(STEP_ROWS),
+                 "true_lens": vec(STEP_ROWS), "final_slot": vec(STEP_ROWS)}
+        args = (params, batch, cache, vec(STEP_ROWS, STEP_SEQ // 128),
+                vec(STEP_BATCH, 1), vec(STEP_BATCH), key)
+        donate = 2
+    pool = max(jax.tree.leaves(cache), key=lambda x: x.size)
+    compiled = jax.jit(fn, donate_argnums=(donate,)).lower(*args).compile()
+    return compiled, pool
+
+
+def _pool_sized_ops(hlo: str, min_elems: int):
+    """(name, opcode) of every instruction outside a fusion's body whose
+    array result holds at least `min_elems` elements."""
+    found, comp = [], ""
+    for line in hlo.splitlines():
+        head = re.match(r"(?:ENTRY )?%(\S+) .*\{$", line)
+        if head and not line.startswith(" "):
+            comp = head.group(1)
+        m = re.match(r"\s*(?:ROOT )?%(\S+) = \w+\[([\d,]*)\]\S* "
+                     r"([\w-]+)\(", line)
+        if m and "fused" not in comp:
+            n = int(np.prod([int(d) for d in m.group(2).split(",") if d]))
+            if n >= min_elems:
+                found.append((m.group(1), m.group(3)))
+    return found
+
+
+@_with_head_dims("kind", ["decode", "chunk"])
+def test_step_never_copies_the_page_pool(topo, one_chip, for_chip, kind,
+                                         head_dim):
+    """granite-3-2b (head_dim 64) and granite-8b (128) widths cut to 2
+    layers, 480 pages of 128, batch 64, 4 x 512 chunk rows, the cache
+    donated: no XLA op copies, slices or restacks the pool, the pool
+    comes out in the buffer it went in, and the step's temporaries stay
+    under one layer's slab of the pool."""
+    compiled, pool = _compile_step(one_chip, kind, head_dim)
+    hlo = compiled.as_text()
+    slab = pool.size // STEP_LAYERS
+    bad = [(n, op) for n, op in _pool_sized_ops(hlo, slab)
+           if op not in POOL_OPS
+           or (op == "custom-call" and not n.startswith("kv_page_write"))]
+    assert not bad, bad
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= pool.size * 2, mem
+    assert mem.temp_size_in_bytes < slab * 2, mem
+    attention = {"decode": "paged_flash_decode",
+                 "chunk": "batched_paged_prefill_attention"}[kind]
+    assert _kernel_named(compiled, "kv_page_write")
+    assert _kernel_named(compiled, attention)
+
+
+@pytest.mark.parametrize("head_dim", sorted(PAGED_CFGS))
+def test_copy_on_write_copies_one_page_in_place(topo, one_chip, for_chip,
+                                                head_dim):
+    """The copy-on-write page copy, jitted with the pool donated as the
+    engine runs it, on the step guard's pool (2 layers, 480 pages of
+    128): the pool comes out in the buffer it went in and the only
+    temporary is at most the one page copied (every layer and head of
+    it), where an XLA copy of the pool would add the whole pool."""
+    cfg = PAGED_CFGS[head_dim]
+    pool = _spec((STEP_LAYERS, cfg.n_kv_heads, STEP_PAGES, 128,
+                  2 * head_dim), DT, one_chip)
+    page = _spec((), jnp.int32, one_chip)
+    compiled = jax.jit(serve_step.copy_page, donate_argnums=(0,)).lower(
+        pool, page, page).compile()
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes == pool.size * 2, mem
+    assert mem.temp_size_in_bytes <= pool.size * 2 // STEP_PAGES, mem

@@ -16,6 +16,7 @@ from repro.serve.scheduler import Request
 
 # shared traffic-replay harness (tests/traffic.py): seeded generators +
 # the serve loop; MIXED_LENS is the acceptance-shape mixed traffic
+from pools import LAYER, stack_pool
 from traffic import MIXED_LENS, mixed_prompts as _mixed_prompts, \
     serve_all as _serve
 
@@ -550,10 +551,10 @@ def test_chunk_attention_matches_monolithic(impl, off, s_chunk, rng):
     for j in range(n_pages):
         k_pages = k_pages.at[perm[j]].set(k[0, j * ps:(j + 1) * ps])
         v_pages = v_pages.at[perm[j]].set(v[0, j * ps:(j + 1) * ps])
-    # the pool is head-major: (Hkv, P, ps, D)
-    k_pages, v_pages = jnp.moveaxis(k_pages, 2, 0), jnp.moveaxis(v_pages, 2, 0)
+    # the pages are head-major: (Hkv, P, ps, D)
+    pool = stack_pool(jnp.moveaxis(k_pages, 2, 0), jnp.moveaxis(v_pages, 2, 0))
     got = ops.paged_prefill_attention(
-        q[:, off:off + s_chunk], k_pages, v_pages,
+        q[:, off:off + s_chunk], pool, LAYER,
         jnp.asarray(perm, jnp.int32), off, impl=impl)
     assert float(jnp.abs(got - want).max()) <= 1e-5
 
@@ -588,8 +589,8 @@ def test_model_chunked_prefill_composes_exactly(model_f32):
                                                 page_row)
         assert int(cursor[0]) == start + n
     assert float(jnp.abs(logits - logits_mono).max()) <= 1e-4
-    for key in ("k_pages", "v_pages"):
-        assert float(jnp.abs(cache[key] - cache_mono[key]).max()) <= 1e-4
+    assert float(jnp.abs(cache["kv_pages"]
+                         - cache_mono["kv_pages"]).max()) <= 1e-4
     d1, _ = m.decode_step(params, jnp.asarray([[7]]), jnp.asarray([40]),
                           cache_mono)
     d2, _ = m.decode_step(params, jnp.asarray([[7]]), jnp.asarray([40]),

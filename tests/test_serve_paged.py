@@ -11,13 +11,14 @@ from repro.models import build_model
 from repro.serve import OutOfPages, PageAllocator, ServeEngine
 from repro.serve.paged_cache import (dense_kv_bytes, paged_kv_bytes,
                                      pages_needed)
+from pools import LAYER, stack_pool
 from traffic import mixed_prompts, serve_all
 
 
-def _paged_from_dense(kc, vc, page_size, seed=0):
-    """Scatter a dense (B, S, Hkv, D) cache into a SHUFFLED head-major
-    (Hkv, P, page_size, D) page pool and the matching block table (page 0
-    kept as the null page)."""
+def _paged_from_dense(kc, vc, page_size, seed=0, lanes=128):
+    """Scatter a dense (B, S, Hkv, D) cache into a SHUFFLED stacked page
+    pool (tests/pools.py, the data at layer LAYER) and the matching block
+    table (page 0 kept as the null page)."""
     B, S, Hkv, D = kc.shape
     n_max = S // page_size
     n_pool = B * n_max + 1
@@ -30,8 +31,9 @@ def _paged_from_dense(kc, vc, page_size, seed=0):
         for j in range(n_max):
             k_pages[bt[b, j]] = np.asarray(kc[b, j*page_size:(j+1)*page_size])
             v_pages[bt[b, j]] = np.asarray(vc[b, j*page_size:(j+1)*page_size])
-    return (jnp.asarray(np.moveaxis(k_pages, 2, 0)),
-            jnp.asarray(np.moveaxis(v_pages, 2, 0)), jnp.asarray(bt))
+    return (stack_pool(jnp.asarray(np.moveaxis(k_pages, 2, 0)),
+                       jnp.asarray(np.moveaxis(v_pages, 2, 0)), lanes),
+            jnp.asarray(bt))
 
 
 # ===========================================================================
@@ -40,17 +42,18 @@ def _paged_from_dense(kc, vc, page_size, seed=0):
 
 @pytest.mark.parametrize("impl", ["ref", "pallas"])
 @pytest.mark.parametrize("window", [0, 10])
-def test_paged_decode_matches_dense(impl, window, rng):
+@pytest.mark.parametrize("lanes", [32, 128])
+def test_paged_decode_matches_dense(impl, window, lanes, rng):
     B, S, Hq, Hkv, D, ps = 3, 64, 4, 2, 16, 8
     ks = jax.random.split(rng, 3)
     q = jax.random.normal(ks[0], (B, 1, Hq, D))
     kc = jax.random.normal(ks[1], (B, S, Hkv, D))
     vc = jax.random.normal(ks[2], (B, S, Hkv, D))
     lens = jnp.array([S - 5, S // 2, 1])
-    k_pages, v_pages, bt = _paged_from_dense(kc, vc, ps)
+    pool, bt = _paged_from_dense(kc, vc, ps, lanes=lanes)
 
     o_dense = ops.flash_decode(q, kc, vc, lens, window=window, impl="ref")
-    o_paged = ops.paged_flash_decode(q, k_pages, v_pages, bt, lens,
+    o_paged = ops.paged_flash_decode(q, pool, LAYER, bt, lens,
                                      window=window, impl=impl)
     assert float(jnp.abs(o_paged - o_dense).max()) <= 1e-5
 
@@ -63,9 +66,9 @@ def test_paged_decode_gqa_single_head(rng):
     kc = jax.random.normal(ks[1], (B, S, H, D))
     vc = jax.random.normal(ks[2], (B, S, H, D))
     lens = jnp.array([S, 3])
-    k_pages, v_pages, bt = _paged_from_dense(kc, vc, ps)
+    pool, bt = _paged_from_dense(kc, vc, ps)
     o_dense = ops.flash_decode(q, kc, vc, lens, impl="ref")
-    o_paged = ops.paged_flash_decode(q, k_pages, v_pages, bt, lens,
+    o_paged = ops.paged_flash_decode(q, pool, LAYER, bt, lens,
                                      impl="pallas")
     assert float(jnp.abs(o_paged - o_dense).max()) <= 1e-5
 
@@ -222,8 +225,8 @@ def test_decode_softcap_kernel_parity(impl, rng):
 
     got = ops.flash_decode(q, kc, vc, lens, logit_softcap=cap, impl=impl)
     assert float(jnp.abs(got - want).max()) <= 1e-5
-    k_pages, v_pages, bt = _paged_from_dense(kc, vc, ps)
-    got_p = ops.paged_flash_decode(q, k_pages, v_pages, bt, lens,
+    pool, bt = _paged_from_dense(kc, vc, ps)
+    got_p = ops.paged_flash_decode(q, pool, LAYER, bt, lens,
                                    logit_softcap=cap, impl=impl)
     assert float(jnp.abs(got_p - want).max()) <= 1e-5
     # softcap must actually change the result (guard against silent no-op)

@@ -34,6 +34,7 @@ from repro.configs.base import ServeConfig
 from repro.launch.mesh import make_serve_mesh
 from repro.models import build_model
 from repro.serve import FleetConfig, FleetRouter, ServeEngine
+from pools import LAYER, stack_pool
 from traffic import assert_greedy_equivalent, replay_fleet
 
 multi_device = pytest.mark.skipif(
@@ -205,14 +206,16 @@ def test_tp_stats_surface(model_f32):
 def _random_paged(rng, tp, hkv_mult, gqa, n_rows, d=8, page_size=4,
                   n_pages=24, n_max=6):
     """Random head-sharded-compatible paged attention inputs: Hkv a
-    multiple of tp, Hq = Hkv * gqa, block tables drawing distinct pages
-    (page 0 reserved null, as the engine lays it out)."""
+    multiple of tp, Hq = Hkv * gqa, a stacked pool (tests/pools.py, the
+    data at layer LAYER), block tables drawing distinct pages (page 0
+    reserved null, as the engine lays it out)."""
     hkv = tp * hkv_mult
     hq = hkv * gqa
-    kp = jnp.asarray(rng.standard_normal((hkv, n_pages, page_size, d)),
-                     jnp.float32)
-    vp = jnp.asarray(rng.standard_normal((hkv, n_pages, page_size, d)),
-                     jnp.float32)
+    pool = stack_pool(
+        jnp.asarray(rng.standard_normal((hkv, n_pages, page_size, d)),
+                    jnp.float32),
+        jnp.asarray(rng.standard_normal((hkv, n_pages, page_size, d)),
+                    jnp.float32))
     tables = np.zeros((n_rows, n_max), np.int32)
     lens = np.zeros((n_rows,), np.int32)
     for r in range(n_rows):
@@ -221,7 +224,7 @@ def _random_paged(rng, tp, hkv_mult, gqa, n_rows, d=8, page_size=4,
         need = -(-n // page_size)
         tables[r, :need] = rng.choice(
             np.arange(1, n_pages), size=need, replace=False)
-    return hq, kp, vp, jnp.asarray(tables), jnp.asarray(lens)
+    return hq, pool, jnp.asarray(tables), jnp.asarray(lens)
 
 
 def _decode_kernel_bitwise(hkv_mult, gqa, n_rows, seed):
@@ -231,12 +234,12 @@ def _decode_kernel_bitwise(hkv_mult, gqa, n_rows, seed):
     from repro.kernels import ops
     tp = 2
     rng = np.random.default_rng(seed)
-    hq, kp, vp, tables, lens = _random_paged(rng, tp, hkv_mult, gqa,
-                                             n_rows)
+    hq, pool, tables, lens = _random_paged(rng, tp, hkv_mult, gqa, n_rows)
     q = jnp.asarray(rng.standard_normal((n_rows, 1, hq, 8)), jnp.float32)
     mesh = make_serve_mesh(tp)
-    o_ref = ops.paged_flash_decode(q, kp, vp, tables, lens)
-    o_tp = ops.paged_flash_decode(q, kp, vp, tables, lens, tp_mesh=mesh)
+    o_ref = ops.paged_flash_decode(q, pool, LAYER, tables, lens)
+    o_tp = ops.paged_flash_decode(q, pool, LAYER, tables, lens,
+                                  tp_mesh=mesh)
     assert o_tp.dtype == o_ref.dtype and o_tp.shape == o_ref.shape
     assert bool(jnp.all(o_tp == o_ref)), \
         float(jnp.abs(o_tp - o_ref).max())
@@ -250,8 +253,7 @@ def _chunk_kernel_bitwise(hkv_mult, gqa, n_rows, ragged, seed):
     tp = 2
     s = 8
     rng = np.random.default_rng(seed)
-    hq, kp, vp, tables, lens = _random_paged(rng, tp, hkv_mult, gqa,
-                                             n_rows)
+    hq, pool, tables, lens = _random_paged(rng, tp, hkv_mult, gqa, n_rows)
     # chunk rows sit at the tail of each row's span: offset + S <= len
     # is not required (the kernel masks by true_lens), so offsets may
     # overhang short rows exactly like a padded final chunk does
@@ -260,10 +262,11 @@ def _chunk_kernel_bitwise(hkv_mult, gqa, n_rows, ragged, seed):
     q_lens = jnp.asarray(rng.integers(1, s + 1, size=n_rows), jnp.int32) \
         if ragged else None
     mesh = make_serve_mesh(tp)
-    o_ref = ops.batched_paged_prefill_attention(q, kp, vp, tables, offs,
-                                                lens, q_lens)
-    o_tp = ops.batched_paged_prefill_attention(q, kp, vp, tables, offs,
-                                               lens, q_lens, tp_mesh=mesh)
+    o_ref = ops.batched_paged_prefill_attention(q, pool, LAYER, tables,
+                                                offs, lens, q_lens)
+    o_tp = ops.batched_paged_prefill_attention(q, pool, LAYER, tables,
+                                               offs, lens, q_lens,
+                                               tp_mesh=mesh)
     assert bool(jnp.all(o_tp == o_ref)), \
         float(jnp.abs(o_tp - o_ref).max())
 
