@@ -11,6 +11,10 @@ import dataclasses
 from dataclasses import dataclass, field
 from typing import Optional, Tuple
 
+# OPT's learned position table (OPTLearnedPositionalEmbedding): position p
+# reads row p + 2 of a table of max_seq + 2 rows
+LEARNED_POS_OFFSET = 2
+
 
 @dataclass(frozen=True)
 class ModelConfig:
@@ -26,7 +30,9 @@ class ModelConfig:
 
     # --- numerics / layers -------------------------------------------------
     norm: str = "rmsnorm"       # rmsnorm | layernorm | nonparam_ln
-    act: str = "silu"           # silu | gelu
+    norm_eps: float = 1e-6
+    act: str = "silu"           # silu (gated) | gelu | relu
+    bias: bool = False          # biases on q/k/v/out and the MLP's in/out
     dtype: str = "bfloat16"
     tie_embeddings: bool = True
     qk_norm: bool = False       # gemma3-style per-head RMS norm of q and k
@@ -37,7 +43,11 @@ class ModelConfig:
     sliding_window: int = 0     # 0 = full attention
     global_every: int = 0       # gemma3: every Nth layer is global, rest local
     attn_logit_softcap: float = 0.0
-    use_rope: bool = True       # olmo/whisper use learned/sinusoidal instead
+    use_rope: bool = True       # False: absolute positions (OPT, whisper)
+    # absolute positions added to the token embedding where use_rope is off:
+    # "sinusoidal" (computed per position) or "learned" (OPT's table of
+    # max_seq + LEARNED_POS_OFFSET rows, position p at row p + the offset)
+    abs_positions: str = "sinusoidal"
 
     # --- MoE ----------------------------------------------------------------
     moe_experts: int = 0
@@ -70,6 +80,14 @@ class ModelConfig:
             object.__setattr__(self, "head_dim", self.d_model // self.n_heads)
         assert self.n_heads % max(self.n_kv_heads, 1) == 0, \
             f"{self.name}: n_heads must be divisible by n_kv_heads"
+        assert self.act in ("silu", "gelu", "relu"), self.act
+        assert self.abs_positions in ("sinusoidal", "learned"), \
+            self.abs_positions
+
+    @property
+    def learned_positions(self) -> bool:
+        """Absolute positions read from a learned table (OPT)."""
+        return not self.use_rope and self.abs_positions == "learned"
 
     # ---- derived -----------------------------------------------------------
     @property
@@ -96,6 +114,9 @@ class ModelConfig:
             ffn = n_ffn_mats * d * f * self.moe_experts + d * self.moe_experts
         else:
             ffn = n_ffn_mats * d * f
+        if self.bias:
+            attn += (self.n_heads + 2 * self.n_kv_heads) * hd + d
+            ffn += 0 if self.moe_experts else f + d
         if self.rwkv:
             per_layer = d * d * 4 + d * f * 2 + 10 * d
         elif self.family in ("ssm", "hybrid"):
@@ -107,6 +128,8 @@ class ModelConfig:
         else:
             per_layer = attn + ffn
         total = self.n_layers * per_layer + v * d * (1 if self.tie_embeddings else 2)
+        if self.learned_positions:
+            total += (self.max_seq + LEARNED_POS_OFFSET) * d
         if self.shared_attn_every:
             total += attn + n_ffn_mats * d * f
         if self.encoder_layers:

@@ -1,4 +1,10 @@
-"""OPT-6.7B: the paper's MHA evaluation model.  [arXiv:2205.01068]"""
+"""OPT-6.7B: the paper's MHA evaluation model, as published.
+
+Pre-LN decoder: learned absolute positions (row = position + 2, a table of
+max_position_embeddings + 2 rows), biases on the q/k/v/out and fc1/fc2
+projections, ReLU MLP, affine LayerNorm eps 1e-5, tied unembedding.
+[arXiv:2205.01068; hf:facebook/opt-6.7b config.json]
+"""
 from .base import ModelConfig
 
 
@@ -7,8 +13,9 @@ def get_config() -> ModelConfig:
         name="opt-6.7b", family="dense",
         n_layers=32, d_model=4096, n_heads=32, n_kv_heads=32,
         d_ff=16384, vocab_size=50272, head_dim=128,
-        norm="layernorm", act="gelu", use_rope=False,
-        tie_embeddings=True,
+        norm="layernorm", norm_eps=1e-5, act="relu", bias=True,
+        use_rope=False, abs_positions="learned",
+        tie_embeddings=True, max_seq=2048,
     )
 
 

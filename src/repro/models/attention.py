@@ -11,7 +11,7 @@ import jax.numpy as jnp
 from ..configs.base import ModelConfig
 from ..kernels import ops
 from ..sharding import constrain
-from .layers import dense, dense_init, pdtype, rms_head_norm, rope
+from .layers import bias_init, dense_init, linear, pdtype, rms_head_norm, rope
 
 
 def attn_init(key, cfg: ModelConfig):
@@ -26,16 +26,24 @@ def attn_init(key, cfg: ModelConfig):
     if cfg.qk_norm:
         p["q_norm"] = jnp.ones((hd,), dt)
         p["k_norm"] = jnp.ones((hd,), dt)
+    if cfg.bias:
+        kb = jax.random.split(ks[4], 4)
+        for k_, (name, n) in zip(kb, (("bq", nq), ("bk", nkv), ("bv", nkv),
+                                      ("bo", d))):
+            p[name] = bias_init(k_, n, dt)
     return p
 
 
 def _qkv(params, x, cfg: ModelConfig, kv_x=None):
+    """q, k, v of x (k and v of kv_x for cross-attention), biases added
+    where the config has them: before RoPE and before any cache write."""
     B = x.shape[0]
     kv_src = x if kv_x is None else kv_x
-    q = dense(params["wq"], x).reshape(B, x.shape[1], cfg.n_heads, cfg.head_dim)
-    k = dense(params["wk"], kv_src).reshape(
+    q = linear(params, "q", x, cfg).reshape(B, x.shape[1], cfg.n_heads,
+                                            cfg.head_dim)
+    k = linear(params, "k", kv_src, cfg).reshape(
         B, kv_src.shape[1], cfg.n_kv_heads, cfg.head_dim)
-    v = dense(params["wv"], kv_src).reshape(
+    v = linear(params, "v", kv_src, cfg).reshape(
         B, kv_src.shape[1], cfg.n_kv_heads, cfg.head_dim)
     if cfg.qk_norm:
         q = rms_head_norm(q, params["q_norm"])
@@ -62,7 +70,8 @@ def attn_forward(params, x, cfg: ModelConfig, *, causal: bool = True,
                             logit_softcap=cfg.attn_logit_softcap, impl=impl)
     o = constrain(o, "bshd")
     B, S = x.shape[:2]
-    return dense(params["wo"], o.reshape(B, S, cfg.n_heads * cfg.head_dim))
+    return linear(params, "o", o.reshape(B, S, cfg.n_heads * cfg.head_dim),
+                  cfg)
 
 
 def attn_prefill(params, x, cfg: ModelConfig, cache_k, cache_v, *,
@@ -84,7 +93,8 @@ def attn_prefill(params, x, cfg: ModelConfig, cache_k, cache_v, *,
     o = ops.flash_attention(q, k, v, causal=True, window=window,
                             logit_softcap=cfg.attn_logit_softcap, impl=impl)
     B = x.shape[0]
-    y = dense(params["wo"], o.reshape(B, S, cfg.n_heads * cfg.head_dim))
+    y = linear(params, "o", o.reshape(B, S, cfg.n_heads * cfg.head_dim),
+               cfg)
     return y, cache_k, cache_v
 
 
@@ -120,7 +130,8 @@ def attn_prefill_paged(params, x, cfg: ModelConfig, kv_pages, layer, flag,
     o = layer_window(cfg, flag, lambda w: ops.flash_attention(
         q, k, v, causal=True, window=w,
         logit_softcap=cfg.attn_logit_softcap, impl=impl))
-    y = dense(params["wo"], o.reshape(1, S, cfg.n_heads * cfg.head_dim))
+    y = linear(params, "o", o.reshape(1, S, cfg.n_heads * cfg.head_dim),
+               cfg)
     return y, kv_pages
 
 
@@ -151,7 +162,8 @@ def attn_decode_paged(params, x, cfg: ModelConfig, kv_pages, layer, flag,
     o = layer_window(cfg, flag, lambda w: ops.paged_flash_decode(
         q, kv_pages, layer, block_table, lens + 1, window=w,
         logit_softcap=cfg.attn_logit_softcap, impl=impl, tp_mesh=tp_mesh))
-    y = dense(params["wo"], o.reshape(B, 1, cfg.n_heads * cfg.head_dim))
+    y = linear(params, "o", o.reshape(B, 1, cfg.n_heads * cfg.head_dim),
+               cfg)
     return y, kv_pages
 
 
@@ -193,7 +205,8 @@ def attn_prefill_chunks_paged(params, x, cfg: ModelConfig, kv_pages, layer,
         q, kv_pages, layer, page_tables, offsets, true_lens, q_lens,
         window=w, logit_softcap=cfg.attn_logit_softcap, impl=impl,
         tp_mesh=tp_mesh))
-    y = dense(params["wo"], o.reshape(K, S, cfg.n_heads * cfg.head_dim))
+    y = linear(params, "o", o.reshape(K, S, cfg.n_heads * cfg.head_dim),
+               cfg)
     return y, kv_pages
 
 
@@ -229,5 +242,6 @@ def attn_decode(params, x, cfg: ModelConfig, cache_k, cache_v, lens, *,
         o = ops.flash_decode(q, cache_k, cache_v, attend_len, window=window,
                              logit_softcap=cfg.attn_logit_softcap,
                              impl=impl)
-    y = dense(params["wo"], o.reshape(B, 1, cfg.n_heads * cfg.head_dim))
+    y = linear(params, "o", o.reshape(B, 1, cfg.n_heads * cfg.head_dim),
+               cfg)
     return y, cache_k, cache_v

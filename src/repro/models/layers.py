@@ -7,7 +7,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
-from ..configs.base import ModelConfig
+from ..configs.base import LEARNED_POS_OFFSET, ModelConfig
 
 
 def pdtype(cfg: ModelConfig):
@@ -66,7 +66,19 @@ def norm_init(cfg: ModelConfig, d: Optional[int] = None):
     return {}   # nonparam_ln (OLMo): no learned affine
 
 
-def apply_norm(params, x, cfg: ModelConfig, eps: float = 1e-6):
+def linear(params, name: str, x, cfg: ModelConfig):
+    """x @ params["w" + name], plus params["b" + name] where the config
+    has biases (wq/bq, w_in/b_in, ...)."""
+    y = dense(params["w" + name], x)
+    return y + params["b" + name].astype(y.dtype) if cfg.bias else y
+
+
+def bias_init(key, d: int, dtype):
+    return (jax.random.normal(key, (d,), jnp.float32) * 0.02).astype(dtype)
+
+
+def apply_norm(params, x, cfg: ModelConfig):
+    eps = cfg.norm_eps
     # grad_cast: the norm computes in fp32, which would otherwise make the
     # cotangent of its input fp32 - and that cotangent is exactly what the
     # sequence-parallel gather/reduce-scatter transpose pair carries.
@@ -115,12 +127,17 @@ def rope(x: jax.Array, positions: jax.Array, theta: float,
                             x2 * cos + x1 * sin], -1).astype(x.dtype)
 
 
-def sinusoidal_positions(seq: int, d: int, offset: int = 0) -> jax.Array:
-    pos = jnp.arange(offset, offset + seq, dtype=jnp.float32)[:, None]
+def sinusoidal_rows(positions: jax.Array, d: int) -> jax.Array:
+    """The sinusoid rows (..., d) of the given positions, float32: sines of
+    position x frequency in the first half, cosines in the second."""
     half = d // 2
     freqs = jnp.exp(-jnp.arange(half, dtype=jnp.float32) * (math.log(10_000.0) / half))
-    ang = pos * freqs[None, :]
+    ang = positions.astype(jnp.float32)[..., None] * freqs
     return jnp.concatenate([jnp.sin(ang), jnp.cos(ang)], -1)
+
+
+def sinusoidal_positions(seq: int, d: int) -> jax.Array:
+    return sinusoidal_rows(jnp.arange(seq), d)
 
 
 # --------------------------------------------------------------------------
@@ -137,18 +154,24 @@ def mlp_init(key, cfg: ModelConfig, d: Optional[int] = None,
          "w_out": dense_init(ks[1], f, d, dt, scale=1.0 / math.sqrt(f))}
     if cfg.act == "silu":
         p["w_gate"] = dense_init(ks[2], d, f, dt)
+    if cfg.bias:
+        kb = jax.random.split(jax.random.fold_in(key, 1), 2)
+        p["b_in"] = bias_init(kb[0], f, dt)
+        p["b_out"] = bias_init(kb[1], d, dt)
     return p
 
 
 def mlp(params, x, cfg: ModelConfig):
-    h = dense(params["w_in"], x)
+    h = linear(params, "_in", x, cfg)
     if cfg.act == "silu":
         h = jax.nn.silu(dense(params["w_gate"], x).astype(jnp.float32)) \
             * h.astype(jnp.float32)
         h = h.astype(x.dtype)
+    elif cfg.act == "relu":
+        h = jax.nn.relu(h)
     else:
         h = jax.nn.gelu(h.astype(jnp.float32)).astype(x.dtype)
-    return dense(params["w_out"], h)
+    return linear(params, "_out", h, cfg)
 
 
 # --------------------------------------------------------------------------
@@ -163,6 +186,11 @@ def embed_init(key, cfg: ModelConfig):
         k2 = jax.random.fold_in(key, 1)
         p["lm_head"] = (jax.random.normal(
             k2, (cfg.vocab_size, cfg.d_model), jnp.float32) * 0.02).astype(dt)
+    if cfg.learned_positions:
+        k3 = jax.random.fold_in(key, 2)
+        p["pos_embed"] = (jax.random.normal(
+            k3, (cfg.max_seq + LEARNED_POS_OFFSET, cfg.d_model), jnp.float32)
+            * 0.02).astype(dt)
     return p
 
 

@@ -14,11 +14,11 @@ from typing import Any, Callable, Dict, Optional
 import jax
 import jax.numpy as jnp
 
-from ..configs.base import ModelConfig
+from ..configs.base import LEARNED_POS_OFFSET, ModelConfig
 from ..sharding import constrain
 from . import transformer as T
 from .layers import (apply_norm, embed, embed_init, norm_init, pdtype,
-                     sinusoidal_positions, unembed)
+                     sinusoidal_positions, sinusoidal_rows, unembed)
 
 
 @dataclass(frozen=True)
@@ -76,13 +76,24 @@ def build_model(cfg: ModelConfig) -> Model:
         return params
 
     # ---------------- embedding helpers ------------------------------------
-    def _embed_tokens(params, tokens, offset: int = 0):
+    def _add_positions(params, x, pos):
+        """x (..., d_model) plus the absolute-position rows of `pos` (x's
+        leading shape) where the model has absolute positions: rows
+        pos + LEARNED_POS_OFFSET of the learned table (clamped to it: only pad
+        positions of a padded chunk can lie past it, and their rows are
+        dropped), or the sinusoid rows of exactly these positions."""
+        if cfg.use_rope or cfg.rwkv:
+            return x
+        if cfg.learned_positions:
+            rows = jnp.take(params["tok"]["pos_embed"], pos + LEARNED_POS_OFFSET,
+                            axis=0, mode="clip")
+        else:
+            rows = sinusoidal_rows(pos, cfg.d_model)
+        return x + rows.astype(x.dtype)
+
+    def _embed_tokens(params, tokens):
         x = embed(params["tok"], tokens, cfg)
-        if not cfg.use_rope and not cfg.rwkv:
-            # sinusoidal absolute positions (OPT / whisper decoder)
-            pos = sinusoidal_positions(tokens.shape[1], cfg.d_model, offset)
-            x = x + pos[None].astype(x.dtype)
-        return x
+        return _add_positions(params, x, jnp.arange(tokens.shape[1])[None])
 
     def _assemble_input(params, batch):
         x = _embed_tokens(params, batch["tokens"])
@@ -277,11 +288,8 @@ def build_model(cfg: ModelConfig) -> Model:
         offs = jnp.asarray(batch["offset"], jnp.int32)
         lens = jnp.asarray(batch["true_lens"], jnp.int32)
         x = embed(params["tok"], tokens, cfg)
-        if not cfg.use_rope and not cfg.rwkv:
-            # absolute sinusoidal positions start at each row's offset
-            tbl = sinusoidal_positions(65536, cfg.d_model)
-            pos = jnp.minimum(offs[:, None] + jnp.arange(S)[None, :], 65535)
-            x = x + jnp.take(tbl, pos, axis=0).astype(x.dtype)
+        # absolute positions start at each row's offset
+        x = _add_positions(params, x, offs[:, None] + jnp.arange(S)[None, :])
         x = constrain(x, "btd")
         x, cache = T.stack_prefill_chunks_paged(params["blocks"], x, cfg,
                                                 cache, page_tables, offs,
@@ -323,10 +331,7 @@ def build_model(cfg: ModelConfig) -> Model:
         lens = jnp.asarray(batch["true_lens"], jnp.int32)
         qls = jnp.asarray(batch["q_lens"], jnp.int32)
         x = embed(params["tok"], tokens, cfg)
-        if not cfg.use_rope and not cfg.rwkv:
-            tbl = sinusoidal_positions(65536, cfg.d_model)
-            pos = jnp.minimum(offs[:, None] + jnp.arange(S)[None, :], 65535)
-            x = x + jnp.take(tbl, pos, axis=0).astype(x.dtype)
+        x = _add_positions(params, x, offs[:, None] + jnp.arange(S)[None, :])
         x = constrain(x, "btd")
         x, cache = T.stack_prefill_chunks_paged(params["blocks"], x, cfg,
                                                 cache, page_tables, offs,
@@ -400,46 +405,35 @@ def build_model(cfg: ModelConfig) -> Model:
         (logits (B,1,V), new_cache).  tp_mesh head-shards the paged decode
         across the serve mesh (attention families with a paged cache
         only)."""
+        x = _add_positions(params, embed(params["tok"], tokens, cfg),
+                           lens[:, None])
         if fam == "audio":
-            x = embed(params["tok"], tokens, cfg)
-            pos = jax.vmap(lambda l: sinusoidal_positions(1, cfg.d_model, 0)
-                           )(lens)  # position folded via rope-free decoder
-            x = x + jnp.take(sinusoidal_positions(cfg.max_seq if cfg.max_seq
-                                                  < 65536 else 65536,
-                                                  cfg.d_model),
-                             lens, axis=0)[:, None].astype(x.dtype)
             el = enc_lens if enc_lens is not None \
                 else jnp.full_like(lens, cache["cross_k"].shape[2])
             x, cache = T.encdec_decode(params["blocks"], x, cfg, cache, lens,
                                        el, impl=impl,
                                        seq_parallel=seq_parallel)
-        else:
-            x = embed(params["tok"], tokens, cfg)
-            if not cfg.use_rope and not cfg.rwkv:
-                tbl = sinusoidal_positions(65536, cfg.d_model)
-                x = x + jnp.take(tbl, jnp.minimum(lens, 65535),
-                                 axis=0)[:, None].astype(x.dtype)
-            if fam in ("dense", "moe", "vlm"):
-                if "kv_pages" in cache:
-                    if seq_parallel:
-                        raise ValueError(
-                            "paged decode does not compose with the "
-                            "sequence-parallel cache layout")
-                    x, cache = T.stack_decode_paged(params["blocks"], x, cfg,
-                                                    cache, lens, impl=impl,
-                                                    tp_mesh=tp_mesh)
-                else:
-                    x, cache = T.stack_decode(params["blocks"], x, cfg, cache,
-                                              lens, impl=impl,
-                                              seq_parallel=seq_parallel)
-            elif fam == "hybrid":
-                x, cache = T.hybrid_decode(params["blocks"], x, cfg, cache,
-                                           lens, impl=impl,
-                                           seq_parallel=seq_parallel)
+        elif fam in ("dense", "moe", "vlm"):
+            if "kv_pages" in cache:
+                if seq_parallel:
+                    raise ValueError(
+                        "paged decode does not compose with the "
+                        "sequence-parallel cache layout")
+                x, cache = T.stack_decode_paged(params["blocks"], x, cfg,
+                                                cache, lens, impl=impl,
+                                                tp_mesh=tp_mesh)
             else:
-                x, cache = T.rwkv_decode(params["blocks"], x, cfg, cache,
-                                         lens, impl=impl,
-                                         seq_parallel=seq_parallel)
+                x, cache = T.stack_decode(params["blocks"], x, cfg, cache,
+                                          lens, impl=impl,
+                                          seq_parallel=seq_parallel)
+        elif fam == "hybrid":
+            x, cache = T.hybrid_decode(params["blocks"], x, cfg, cache,
+                                       lens, impl=impl,
+                                       seq_parallel=seq_parallel)
+        else:
+            x, cache = T.rwkv_decode(params["blocks"], x, cfg, cache,
+                                     lens, impl=impl,
+                                     seq_parallel=seq_parallel)
         x = apply_norm(params["final_norm"], x, cfg)
         logits = unembed(params["tok"], x, cfg)
         return logits.astype(jnp.float32), cache

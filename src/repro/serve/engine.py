@@ -268,6 +268,9 @@ class ServeEngine:
         m.counter("serve_gen_tokens_total", "Generation tokens emitted")
         m.counter("serve_decode_launches_total",
                   "Token-emitting launches (fused decode + spec verify)")
+        m.counter("serve_decode_ctx_tokens_total",
+                  "Context lengths of the live rows of token-emitting "
+                  "launches, summed (positions each row attends)")
         m.counter("serve_kv_pages_read_total",
                   "KV pages read by token-emitting launches (analytic "
                   "host-side count, not a device counter)")
@@ -418,6 +421,7 @@ class ServeEngine:
     cow_copies = _registry_counter("serve_cow_copies_total")
     gen_tokens = _registry_counter("serve_gen_tokens_total")
     decode_launches = _registry_counter("serve_decode_launches_total")
+    decode_ctx_tokens = _registry_counter("serve_decode_ctx_tokens_total")
     kv_pages_read = _registry_counter("serve_kv_pages_read_total")
     peak_pages = _registry_gauge("serve_peak_pages")
     peak_live_pages = _registry_gauge("serve_peak_live_pages")
@@ -487,7 +491,7 @@ class ServeEngine:
     def _note_launch(self, kind: str, rows: int, live_rows: int,
                      true_tokens: int, padded_tokens: int,
                      kv_pages_read: int, kv_pages_written: int,
-                     new_kv_tokens: int):
+                     new_kv_tokens: int, ctx_tokens: int = 0):
         """Record one kernel launch's data-movement attribution."""
         self.tm.launches.append(LaunchRecord(
             tick=self.sched.ticks, kind=kind, rows=rows,
@@ -495,7 +499,14 @@ class ServeEngine:
             padded_tokens=padded_tokens, kv_pages_read=kv_pages_read,
             kv_pages_written=kv_pages_written,
             new_kv_tokens=new_kv_tokens,
-            work_clock=self.sched.work_clock))
+            work_clock=self.sched.work_clock, ctx_tokens=ctx_tokens))
+
+    def _note_decode_ctx(self, rows) -> int:
+        """Count a token-emitting launch's attended context: the sum of
+        its live rows' context lengths (serve_decode_ctx_tokens_total)."""
+        n = int(sum(rows))
+        self.decode_ctx_tokens += n
+        return n
 
     def _note_kv_pages_read(self, n_pages: int):
         """Count pages a token-emitting launch read, in BOTH units: pool
@@ -598,10 +609,13 @@ class ServeEngine:
         if max_retries is not None and max_retries < 0:
             raise ValueError(f"max_retries must be >= 0 (None = "
                              f"unbounded), got {max_retries}")
-        if len(prompt) + n_new > self.scfg.max_seq:
+        # the model's own max_seq bounds it too: a learned position table
+        # has no rows past it
+        max_seq = min(self.scfg.max_seq, self.model.cfg.max_seq)
+        if len(prompt) + n_new > max_seq:
             raise ValueError(
                 f"request does not fit: {len(prompt)} prompt + {n_new} new "
-                f"tokens > max_seq {self.scfg.max_seq}")
+                f"tokens > max_seq {max_seq}")
         if self.paged:
             need = pages_needed(len(prompt) + n_new, self.scfg.page_size)
             usable = min(self.allocator.max_pages_per_seq,
@@ -1357,7 +1371,10 @@ class ServeEngine:
                                   self.lens, self._next_key())
             n_q = sum(1 + len(t.draft) for t in pack.tasks)
             args.update(rows=rows, live_rows=len(pack.tasks),
-                        true_tokens=n_q)
+                        true_tokens=n_q,
+                        ctx_tokens=self._note_decode_ctx(
+                            t.offset + 1 + len(t.draft)
+                            for t in pack.tasks))
             self._note_launch(
                 "spec_verify", padded_tokens=rows * width,
                 kv_pages_read=sum(self._row_pages(
@@ -1566,11 +1583,13 @@ class ServeEngine:
             spec_pack = self._run_spec_verify(spec_tasks)
         if plain_slots:
             with self.tm.phase("engine.decode_launch",
-                               live_rows=len(plain_slots)):
+                               live_rows=len(plain_slots)) as args:
                 live = np.zeros((len(self.slots),), bool)
                 live[plain_slots] = True
                 self.jit_calls += 1
                 self.decode_launches += 1
+                args["ctx_tokens"] = self._note_decode_ctx(
+                    int(self._lens_np[i]) + 1 for i in plain_slots)
                 self._note_kv_pages_read(sum(
                     -(-(int(self._lens_np[i]) + 1) // self.scfg.page_size)
                     for i in plain_slots))
@@ -1586,7 +1605,8 @@ class ServeEngine:
                                   padded_tokens=len(self.slots),
                                   kv_pages_read=pages_read,
                                   kv_pages_written=len(plain_slots),
-                                  new_kv_tokens=len(plain_slots))
+                                  new_kv_tokens=len(plain_slots),
+                                  ctx_tokens=args["ctx_tokens"])
             self.sched.note_work(len(plain_slots))
             self._lens_np[plain_slots] += 1
         gen_work = len(plain_slots)
@@ -1718,11 +1738,14 @@ class ServeEngine:
         # masked token/length updates, then ONE device->host transfer for
         # every lane's sampled token (was: one int() sync + two .at[i]
         # dispatches PER SLOT)
-        with self.tm.phase("engine.decode_launch", live_rows=len(active)):
+        with self.tm.phase("engine.decode_launch",
+                           live_rows=len(active)) as args:
             live = np.zeros((len(self.slots),), bool)
             live[active] = True
             self.jit_calls += 1
             self.decode_launches += 1
+            args["ctx_tokens"] = self._note_decode_ctx(
+                int(self._lens_np[i]) + 1 for i in active)
             pages_read = 0
             if self.paged:
                 self._note_kv_pages_read(sum(
@@ -1740,7 +1763,8 @@ class ServeEngine:
                               kv_pages_read=pages_read,
                               kv_pages_written=len(active)
                               if self.paged else 0,
-                              new_kv_tokens=len(active))
+                              new_kv_tokens=len(active),
+                              ctx_tokens=args["ctx_tokens"])
         self.sched.note_work(len(active))
         self._lens_np[active] += 1
         with self.tm.phase("engine.device_get"):

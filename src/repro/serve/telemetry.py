@@ -416,6 +416,8 @@ class LaunchRecord:
     kv_pages_written: int    # pages its K/V writes touch
     new_kv_tokens: int       # KV positions written (true)
     work_clock: int          # scheduler work clock AFTER the launch
+    ctx_tokens: int = 0      # token-emitting launches: the live rows'
+    #                          context lengths, summed (what each attends)
 
 
 @dataclass(frozen=True)
@@ -432,6 +434,11 @@ class TickRecord:
 # telemetry facade (what the engine holds)
 # ===========================================================================
 
+# launch records an engine keeps (the oldest drop first); readers that line
+# records up with the launch counters need all of them
+LAUNCH_CAPACITY = 65536
+
+
 class Telemetry:
     """One engine's telemetry surface: the shared metrics registry
     (always on - it IS the stats() backing store), the span tracer
@@ -441,7 +448,7 @@ class Telemetry:
 
     def __init__(self, registry: Optional[MetricsRegistry] = None,
                  tracer: Optional[SpanTracer] = None,
-                 launch_capacity: int = 65536,
+                 launch_capacity: int = LAUNCH_CAPACITY,
                  clock: Callable[[], Tuple[int, int]] = lambda: (0, 0)):
         self.registry = registry if registry is not None else MetricsRegistry()
         self.tracer = tracer

@@ -114,7 +114,7 @@ def constrain(x: jax.Array, logical: str) -> jax.Array:
 #   norms, biases, small vectors: replicated
 
 _PARAM_RULES = [
-    (r"(embed|lm_head|cls_head)$", lambda dp, tp: P(tp, dp)),
+    (r"^(embed|lm_head|cls_head)$", lambda dp, tp: P(tp, dp)),
     (r"(wq|wk|wv|w_in|w_gate|in_proj|router_dense|r_proj|k_proj|v_proj|g_proj|w_proj)$",
      lambda dp, tp: P(dp, tp)),
     (r"(wo|w_out|out_proj)$", lambda dp, tp: P(tp, dp)),

@@ -223,15 +223,17 @@ POOL_OPS = {"parameter", "get-tuple-element", "tuple", "while", "bitcast",
             "custom-call"}
 
 
-# the steps at each pool width; at head_dim 128 granite-8b, not opt-6.7b,
-# whose decode step builds a 65536 x d_model sinusoid table (1 GiB of
-# temporaries that have nothing to do with the pool)
-STEP_CFGS = {64: CFG, 128: get_config("granite-8b")}
+# the steps at each pool width (granite-3-2b at head_dim 64, granite-8b at
+# 128) and OPT-6.7B's published block (MHA at head_dim 128, learned
+# positions, biases), each case id'd by its kind and this suffix
+STEP_CFGS = {"": CFG, "-hd128": get_config("granite-8b"),
+             "-opt-6.7b": get_config("opt-6.7b")}
 
 
-def _compile_step(one_chip, kind, head_dim):
-    cfg = STEP_CFGS[head_dim].replace(n_layers=STEP_LAYERS)
+def _compile_step(one_chip, kind, arch):
+    cfg = STEP_CFGS[arch].replace(n_layers=STEP_LAYERS)
     model = build_model(cfg)
+    seq = min(STEP_SEQ, cfg.max_seq)
 
     def spec(x):
         return _spec(x.shape, x.dtype, one_chip)
@@ -239,7 +241,7 @@ def _compile_step(one_chip, kind, head_dim):
     params = jax.tree.map(spec, jax.eval_shape(model.init,
                                                jax.random.key(0)))
     cache = jax.tree.map(spec, jax.eval_shape(lambda: model.init_cache(
-        STEP_BATCH, STEP_SEQ, page_size=128, num_pages=STEP_PAGES)))
+        STEP_BATCH, seq, page_size=128, num_pages=STEP_PAGES)))
     vec = lambda *s: _spec(s, jnp.int32, one_chip)          # noqa: E731
     key = _spec((2,), jnp.uint32, one_chip)
     if kind == "decode":
@@ -251,7 +253,7 @@ def _compile_step(one_chip, kind, head_dim):
         fn = serve_step.make_chunk_batch_step(model, temperature=0.0)
         batch = {"tokens": vec(STEP_ROWS, CHUNK), "offset": vec(STEP_ROWS),
                  "true_lens": vec(STEP_ROWS), "final_slot": vec(STEP_ROWS)}
-        args = (params, batch, cache, vec(STEP_ROWS, STEP_SEQ // 128),
+        args = (params, batch, cache, vec(STEP_ROWS, seq // 128),
                 vec(STEP_BATCH, 1), vec(STEP_BATCH), key)
         donate = 2
     pool = max(jax.tree.leaves(cache), key=lambda x: x.size)
@@ -276,15 +278,17 @@ def _pool_sized_ops(hlo: str, min_elems: int):
     return found
 
 
-@_with_head_dims("kind", ["decode", "chunk"])
+@pytest.mark.parametrize(("kind", "arch"), [
+    pytest.param(kind, arch, id=kind + arch)
+    for arch in STEP_CFGS for kind in ("decode", "chunk")])
 def test_step_never_copies_the_page_pool(topo, one_chip, for_chip, kind,
-                                         head_dim):
-    """granite-3-2b (head_dim 64) and granite-8b (128) widths cut to 2
-    layers, 480 pages of 128, batch 64, 4 x 512 chunk rows, the cache
-    donated: no XLA op copies, slices or restacks the pool, the pool
-    comes out in the buffer it went in, and the step's temporaries stay
-    under one layer's slab of the pool."""
-    compiled, pool = _compile_step(one_chip, kind, head_dim)
+                                         arch):
+    """granite-3-2b (head_dim 64), granite-8b (128) and OPT-6.7B (128, MHA)
+    widths cut to 2 layers, 480 pages of 128, batch 64, 4 x 512 chunk
+    rows, the cache donated: no XLA op copies, slices or restacks the
+    pool, the pool comes out in the buffer it went in, and the step's
+    temporaries stay under one layer's slab of the pool."""
+    compiled, pool = _compile_step(one_chip, kind, arch)
     hlo = compiled.as_text()
     slab = pool.size // STEP_LAYERS
     bad = [(n, op) for n, op in _pool_sized_ops(hlo, slab)

@@ -409,6 +409,20 @@ def test_launch_records_match_page_allocator_accounting(traced_run):
         (from_records, eng.kv_pages_read)
 
 
+def test_decode_ctx_tokens_fill_the_pages_read(traced_run):
+    """serve_decode_ctx_tokens_total is the sum of the token-emitting
+    launches' ctx_tokens, and a launch's attended context fills the pages
+    it reads: ctx <= pages x page_size < ctx + live rows x page_size."""
+    eng, _, _ = traced_run
+    recs = [r for r in eng.launch_records()
+            if r.kind in ("decode", "spec_verify")]
+    assert recs
+    assert sum(r.ctx_tokens for r in recs) == eng.decode_ctx_tokens > 0
+    for r in recs:
+        assert r.live_rows <= r.ctx_tokens <= r.kv_pages_read * PAGE \
+            < r.ctx_tokens + r.live_rows * PAGE, r
+
+
 def test_movement_breakdown_byte_model(model_f32):
     """Synthetic launch records through the exact byte model: KV pages
     stream page_size tokens of K+V, weights stream once per launch,
@@ -565,9 +579,12 @@ def test_engine_phases_in_profiler_trace(model_f32, tmp_path, chunked):
     for e in events:
         assert t0 <= e.start_ns and e.start_ns + e.duration_ns <= t1, e.name
     stats = {e.name: dict(e.stats) for e in events}
-    # a monolithic admission prefills whole, so the newcomer decodes too
-    n_decode = 1 if chunked else 2
-    assert stats["engine.decode_launch"] == {"live_rows": n_decode}
+    # a monolithic admission prefills whole, so the newcomer decodes too.
+    # Context attended: chunked, 11 prompt positions + the pending token;
+    # monolithic, the first request's 12 + 1 and the newcomer's 14 + 1
+    n_decode, ctx = (1, 12) if chunked else (2, 28)
+    assert stats["engine.decode_launch"] == {"live_rows": n_decode,
+                                             "ctx_tokens": ctx}
     assert stats["engine.tick"]["n_decode"] == n_decode
     if chunked:
         assert stats["engine.chunk_launch"] == {"rows": 1, "live_rows": 1,
