@@ -292,8 +292,9 @@ def paged_flash_decode(q, kv_pages, layer, block_table, cache_len, *,
     q: (B,1,Hq,D); kv_pages: (L, Hkv, P, page_size, W) stacked lane-dense
     page pool (K in lanes [0, D), V in [D, 2D)), read at layer `layer`;
     block_table: (B, n_max) int32 page ids; cache_len: (B,) valid lengths.
-    The Pallas path walks the block table from SMEM inside the BlockSpec
-    index maps, keeping the (m, l, acc) merge VMEM-resident; the ref path
+    The Pallas path walks each row's live pages through the block table
+    in SMEM, DMAing every head of a page at once, and keeps the (m, l, acc)
+    merge VMEM-resident (kernels/flash_decode.py); the ref path
     gathers pages and reuses the chunked dense decode.
 
     tp_mesh (a launch/mesh.py serve mesh with a "model" axis > 1) runs the

@@ -42,14 +42,15 @@ and the caller requires n_kv_heads % tp_degree == 0 so every shard holds
 whole GQA groups.  Nothing in here is sharding-aware - the kernel sees a
 smaller head count and is otherwise bit-identical.
 
-Same construction as paged_flash_decode (kernels/flash_decode.py): the
-pool is the stacked, lane-dense (L, Hkv, P, page, W) array with K in lanes
+The same pool as paged_flash_decode (kernels/flash_decode.py), walked
+here through BlockSpecs, one (row, head, page) per grid step: the pool is
+the stacked, lane-dense (L, Hkv, P, page, W) array with K in lanes
 [0, D) and V in [D, 2D) of each token row (kernels/kv_write.py), the layer
 index and block tables are scalar-prefetched into SMEM, the BlockSpec
 index map (l, h, tbl[k, j], 0, 0) IS the page-table walk - one DMA brings
 a head's K and V page together, split by lanes in VMEM - and the running
 (m, l, acc) online-softmax state stays in VMEM scratch across KV pages.
-The only extra ingredient over the decode kernel is a 2-D causal mask -
+The ingredient a one-token decode does not need is a 2-D causal mask -
 each chunk row r masks columns > offset[k] + r - computed branch-free
 from the prefetched offset.
 

@@ -132,6 +132,32 @@ def test_paged_flash_decode_compiles(topo, one_chip, for_chip, page_size,
     assert compiled.memory_analysis().temp_size_in_bytes == 0
 
 
+# the benchmark cells' decode launches: (rows, KV heads, group, head_dim,
+# table width, pool pages, layers) - granite-3-2b chat's 64-row bucket and
+# the published OPT-6.7B stage's 15 rows of 2,048 tokens
+CELL_DECODE = {
+    "granite-3-2b.chat-poisson": (64, 8, 4, 64, 32, 480, 40),
+    "opt-6.7b-published.long-decode": (15, 32, 1, 128, 16, 241, 16),
+}
+
+
+@pytest.mark.parametrize("cell", sorted(CELL_DECODE))
+def test_paged_flash_decode_compiles_at_cell_shapes(topo, one_chip, for_chip,
+                                                    cell):
+    """The decode kernel at each cell's exact launch shape: its
+    double-buffered page blocks must fit the VMEM it asks for, and it
+    still reads the whole pool in place."""
+    rows, hkv, group, head_dim, n_max, pages, layers = CELL_DECODE[cell]
+    q = _spec((rows, 1, hkv * group, head_dim), DT, one_chip)
+    pool = _spec((layers, hkv, pages, 128, 2 * head_dim), DT, one_chip)
+    compiled = fd.paged_flash_decode.lower(
+        q, pool, _spec((), jnp.int32, one_chip),
+        _spec((rows, n_max), jnp.int32, one_chip),
+        _spec((rows,), jnp.int32, one_chip)).compile()
+    assert _kernel_named(compiled, "paged_flash_decode")
+    assert compiled.memory_analysis().temp_size_in_bytes == 0
+
+
 @_with_head_dims("page_size", [16, 128])
 def test_batched_paged_prefill_compiles(topo, one_chip, for_chip, page_size,
                                         head_dim):

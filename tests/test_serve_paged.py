@@ -6,7 +6,8 @@ import pytest
 
 from repro.configs import get_smoke_config
 from repro.configs.base import ServeConfig
-from repro.kernels import ops
+from repro.kernels import flash_decode as fd
+from repro.kernels import ops, ref
 from repro.models import build_model
 from repro.serve import OutOfPages, PageAllocator, ServeEngine
 from repro.serve.paged_cache import (dense_kv_bytes, paged_kv_bytes,
@@ -71,6 +72,59 @@ def test_paged_decode_gqa_single_head(rng):
     o_paged = ops.paged_flash_decode(q, pool, LAYER, bt, lens,
                                      impl="pallas")
     assert float(jnp.abs(o_paged - o_dense).max()) <= 1e-5
+
+
+def _live_page_case(G, D, rng, hkv=2, ps=128, n_max=8):
+    """A stacked f32 pool (W = 2D) and a batch whose lengths sit where the
+    kernel's page walk turns: 1, one page, one page + 1, a page-block
+    boundary +-1, the whole table row; then two dead rows (length 1, table
+    row all the null page 0).  Every live row owns n_max distinct pages, so
+    its table lists pages past its live length."""
+    block = fd.pages_per_block(ps, 2 * D, 4, n_max)
+    assert block < n_max, "the case needs a block boundary inside a row"
+    edge = block * ps
+    lens = [1, ps, ps + 1, edge - 1, edge, edge + 1, n_max * ps]
+    live, dead = len(lens), 2
+    n_pool = live * n_max + 1
+    ks = jax.random.split(rng, 3)
+    k = jax.random.normal(ks[0], (hkv, n_pool, ps, D))
+    v = jax.random.normal(ks[1], (hkv, n_pool, ps, D))
+    q = jax.random.normal(ks[2], (live + dead, 1, hkv * G, D))
+    perm = np.random.default_rng(0).permutation(np.arange(1, n_pool))
+    bt = np.zeros((live + dead, n_max), np.int32)
+    bt[:live] = perm.reshape(live, n_max)
+    lens = np.asarray(lens + [1] * dead, np.int32)
+    return q, k, v, bt, lens
+
+
+@pytest.mark.parametrize("G", [1, 4])
+@pytest.mark.parametrize("D", [64, 128])
+@pytest.mark.parametrize("mode", ["plain", "window", "softcap", "poisoned"])
+def test_paged_decode_live_pages_match_ref(G, D, mode, rng):
+    """The Pallas kernel (interpret mode) against ref.paged_flash_decode
+    at the lengths where its live-page walk turns, with dead rows, a
+    window whose first position falls mid-page inside a page block, and a
+    logit softcap.  "poisoned": every page the table lists wholly past a
+    row's live length, or wholly before its window, holds NaN, and the
+    kernel must still equal the ref on the clean pool - no dead page
+    reaches the merge."""
+    ps = 128
+    q, k, v, bt, lens = _live_page_case(G, D, rng, ps=ps)
+    window = ps + ps // 2 + 1 if mode in ("window", "poisoned") else 0
+    cap = 7.5 if mode == "softcap" else 0.0
+    pool = stack_pool(k, v, 2 * D)
+    want = ref.paged_flash_decode(q, pool, LAYER, bt, lens, window=window,
+                                  logit_softcap=cap)
+    if mode == "poisoned":
+        dead_pages = [bt[r, j] for r in range(len(lens)) if bt[r].any()
+                      for j in range(bt.shape[1])
+                      if j * ps >= lens[r] or (j + 1) * ps <= lens[r] - window]
+        assert dead_pages
+        pool = pool.at[LAYER, :, np.asarray(dead_pages)].set(jnp.nan)
+    got = ops.paged_flash_decode(q, pool, LAYER, bt, lens, window=window,
+                                 logit_softcap=cap, impl="pallas")
+    assert bool(jnp.all(jnp.isfinite(got)))
+    assert float(jnp.abs(got - want).max()) <= 1e-5
 
 
 # ===========================================================================

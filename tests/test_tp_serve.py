@@ -281,6 +281,35 @@ def test_tp_decode_kernel_bitwise_seeded(hkv_mult, gqa, n_rows, seed):
 
 
 @multi_device
+@pytest.mark.parametrize("tp,hkv,gqa,d", [(2, 4, 4, 64), (4, 4, 1, 128)])
+def test_tp_pallas_decode_kernel_bitwise(tp, hkv, gqa, d):
+    """The Pallas decode kernel (interpret mode) under the head-sharded
+    wrapper == unsharded, bitwise, down to one KV head per device: a
+    head's products and merge do not depend on how many heads the call
+    holds."""
+    from repro.kernels import ops
+    if jax.device_count() < tp:
+        pytest.skip(f"needs {tp} devices")
+    rng = np.random.default_rng(tp)
+    ps, n_max, n_rows = 128, 8, 5
+    n_pages = n_rows * n_max + 1
+    pool = stack_pool(*(jnp.asarray(rng.standard_normal((hkv, n_pages, ps,
+                                                         d)), jnp.float32)
+                        for _ in range(2)), 2 * d)
+    tables = jnp.asarray(rng.permutation(np.arange(1, n_pages))
+                         .reshape(n_rows, n_max), jnp.int32)
+    lens = jnp.asarray([1, ps + 1, 700, n_max * ps, 513], jnp.int32)
+    q = jnp.asarray(rng.standard_normal((n_rows, 1, hkv * gqa, d)),
+                    jnp.float32)
+    o_ref = ops.paged_flash_decode(q, pool, LAYER, tables, lens,
+                                   impl="pallas")
+    o_tp = ops.paged_flash_decode(q, pool, LAYER, tables, lens,
+                                  impl="pallas", tp_mesh=make_serve_mesh(tp))
+    assert bool(jnp.all(o_tp == o_ref)), \
+        float(jnp.abs(o_tp - o_ref).max())
+
+
+@multi_device
 @pytest.mark.parametrize("hkv_mult,gqa,n_rows,ragged,seed",
                          [(1, 1, 1, False, 3), (1, 2, 2, True, 4),
                           (2, 1, 3, True, 5)])
