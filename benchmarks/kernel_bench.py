@@ -56,7 +56,7 @@ def run():
 
     # ragged batched chunk prefill: K chunks of K different sequences at K
     # different prompt positions - ONE launch (the serve one-launch tick)
-    # vs K single-row launches (the sequential per-chunk oracle)
+    # vs K single-row launches
     Kc, Sc, Hqc, Hkvc, Dc, psc = 4, 128, 8, 4, 64, 32
     n_pages, n_max = 64, 16
     # a one-layer stacked pool, head-major, K and V side by side in lanes

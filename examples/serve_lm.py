@@ -3,17 +3,16 @@ end-to-end example serves a small LM with continuously-batched requests).
 
 This is the paged-serving entry point: by default requests are served
 through the paged KV cache (a global page pool walked via a block table -
-see docs/serving.md); --dense switches back to the one-strip-per-slot
-layout for comparison.  Both modes print tokens/s and allocated KV bytes.
+see docs/serving.md) under the token-budget scheduler, where prefill
+chunks of --prefill-chunk tokens interleave with decode ticks
+(docs/scheduling.md); --dense switches back to the one-strip-per-slot
+layout with whole-prompt prefill for comparison.  Greedy outputs match
+exactly in float32 (bf16 can flip an argmax tie - the two prefills
+reduce in different orders).  Both modes print tokens/s and allocated KV
+bytes.
 
     PYTHONPATH=src python examples/serve_lm.py --arch gemma3-4b --requests 12
     PYTHONPATH=src python examples/serve_lm.py --dense
-    PYTHONPATH=src python examples/serve_lm.py --chunked  # token-budget
-        # scheduler: prefill chunks interleave with decode ticks
-        # (docs/scheduling.md); greedy outputs match the monolithic
-        # schedule exactly in float32 (bf16 can flip an argmax tie - the
-        # chunk kernel and the monolithic prefill reduce in different
-        # orders)
 
 Expected output (CPU, smoke-scale model; numbers vary by machine):
 
@@ -48,16 +47,12 @@ def main():
     ap.add_argument("--page-size", type=int, default=16)
     ap.add_argument("--num-pages", type=int, default=0,
                     help="page pool size (0 = dense-equivalent capacity)")
-    ap.add_argument("--chunked", action="store_true",
-                    help="token-budget scheduler: chunked prefill mixed "
-                         "into decode ticks (paged only)")
-    ap.add_argument("--prefill-chunk", type=int, default=16)
+    ap.add_argument("--prefill-chunk", type=int, default=16,
+                    help="tokens per prefill chunk (paged)")
     ap.add_argument("--tick-budget", type=int, default=0,
-                    help="tokens of work per tick "
+                    help="tokens of work per tick, paged "
                          "(0 = max_batch + prefill_chunk)")
     args = ap.parse_args()
-    if args.chunked and args.dense:
-        ap.error("--chunked needs the paged cache (drop --dense)")
 
     cfg = get_smoke_config(args.arch)
     model = build_model(cfg)
@@ -68,10 +63,8 @@ def main():
                                   paged=not args.dense,
                                   page_size=args.page_size,
                                   num_pages=args.num_pages,
-                                  chunked=args.chunked,
                                   prefill_chunk=args.prefill_chunk,
-                                  tick_token_budget=args.tick_budget or
-                                  args.max_batch + args.prefill_chunk))
+                                  tick_token_budget=args.tick_budget))
 
     rng = np.random.default_rng(0)
     t0 = time.time()
@@ -85,12 +78,12 @@ def main():
     kv = f"paged KV: {eng.kv_cache_bytes() / 1e6:.2f} MB, " \
          f"peak {eng.peak_pages} pages" if not args.dense \
         else f"dense KV: {eng.kv_cache_bytes() / 1e6:.2f} MB"
-    sched = "chunked prefill" if args.chunked else "monolithic prefill"
+    sched = "whole-prompt prefill" if args.dense else "chunked prefill"
     print(f"served {len(done)} requests, {tokens} tokens "
           f"in {dt:.1f}s ({tokens/dt:.1f} tok/s, "
           f"continuous batching over {args.max_batch} slots, {sched}, "
           f"{kv})")
-    if args.chunked:
+    if not args.dense:
         st = eng.stats()
         print(f"  budget {st['tick_token_budget']} tok/tick, max tick "
               f"{st['max_tick_tokens']}, {st['chunks_run']} chunks, p95 "

@@ -224,15 +224,20 @@ class ServeConfig:
     eos_id: Optional[int] = None
 
     # --- token-budget scheduler (serve/scheduler.py) ------------------------
-    # chunked=True replaces monolithic admission-time prefill with
-    # Sarathi-style chunked prefill mixed into decode ticks: every tick gets
-    # `tick_token_budget` tokens of work; each decoding slot consumes 1 and
-    # the remainder is filled with prompt chunks (multiples of
-    # `prefill_chunk`), so decode latency stays flat while long prompts
-    # stream in.  Paged mode only (chunks prefill through the offset-causal
-    # block-table kernel, kernels/paged_prefill.py).
-    chunked: bool = False
-    tick_token_budget: int = 0  # tokens of work (decode + prefill) per tick
+    # The paged engine runs Sarathi-style chunked prefill mixed into decode
+    # ticks: every tick gets `tick_token_budget` tokens of work; each
+    # decoding slot consumes 1 and the remainder is filled with prompt
+    # chunks (multiples of `prefill_chunk`), so decode latency stays flat
+    # while long prompts stream in (chunks prefill through the
+    # offset-causal block-table kernel, kernels/paged_prefill.py).
+    # chunked and batched choose nothing any more: the paged engine has one
+    # path (chunked prefill, one batched chunk launch per tick), and
+    # validate() rejects False.  They stay declared until the benchmark
+    # harness stops passing them.
+    chunked: bool = True
+    # tokens of work (decode + prefill) per tick; 0 on a paged engine
+    # resolves to max_batch + prefill_chunk (validate())
+    tick_token_budget: int = 0
     admission_policy: str = "fifo"   # fifo | sjf (shortest prompt first)
     # cap on prefill chunks planned per tick (0 = budget-limited only).
     # Bounds the ragged chunk-batch width - and, at 1, pins every pack to
@@ -240,14 +245,7 @@ class ServeConfig:
     # different schedules (the deterministic-replay mode the preemption
     # parity tests and --preempt-trace bench run in).
     max_chunks_per_tick: int = 0
-    # batched=True (default) packs every prefill chunk the scheduler plans
-    # for a tick into ONE ragged batched kernel launch (K rows bucketed to
-    # a power of two to bound recompiles), samples final-chunk tokens
-    # device-side, and folds all per-slot updates into vectorized masked
-    # ops - a steady-state tick costs one prefill launch + one decode
-    # launch + one device->host transfer regardless of traffic.  False
-    # keeps the sequential one-launch-per-chunk path (the parity oracle).
-    batched: bool = True
+    batched: bool = True        # see `chunked`
 
     # --- decode-priority budget shaping (serve/scheduler.py) ----------------
     # decode_priority=True caps the prefill share of every tick at
@@ -256,7 +254,7 @@ class ServeConfig:
     # the per-tick work (and therefore the work-clock TBT of every in-flight
     # decode) up to the full budget: steady-state decode TBT is bounded by
     # n_decode + floor(max_prefill_fraction * budget) instead of budget.
-    # Chunked mode only.
+    # Paged mode only.
     decode_priority: bool = False
     max_prefill_fraction: float = 0.5   # of tick_token_budget, (0, 1]
 
@@ -270,7 +268,7 @@ class ServeConfig:
     # while only the lost remainder is re-prefilled through the chunk path.
     # Victim order: lowest-priority first; PREFILLING (most recently
     # admitted first) before DECODING (longest-remaining first).  Requires
-    # chunked=True (the resume path is the chunk path).  Equal-priority
+    # paged=True (the resume path is the chunk path).  Equal-priority
     # requests never preempt each other, so all-default-priority traffic
     # behaves exactly like preemption=False.
     preemption: bool = False
@@ -300,8 +298,8 @@ class ServeConfig:
     # untouched (admission already reserved the worst case).  Drafted
     # tokens consume tick budget like prefill tokens; the work clock
     # advances only for ACCEPTED tokens so TTFT/TBT stay comparable with
-    # speculation on or off.  Requires chunked=True and batched=True (the
-    # verify path is the batched chunk path).
+    # speculation on or off.  Requires paged=True (the verify path is the
+    # batched chunk path).
     speculative: bool = False
     spec_k: int = 4             # max drafted tokens per request per tick
     spec_ngram: int = 3         # longest n-gram the drafter matches on
@@ -366,8 +364,8 @@ class ServeConfig:
     # so every other op (projections, FFN/MoE, sampling) computes on
     # replicated values with the same float summation order as tp=1, which
     # is what keeps greedy outputs bit-identical to the single-device
-    # engine.  Requires paged=True, chunked=True, batched=True (the
-    # one-launch tick paths are the sharded paths), n_kv_heads divisible by
+    # engine.  Requires paged=True (the paged tick's launches are the
+    # sharded paths), n_kv_heads divisible by
     # tp_degree (checked by ServeEngine against the model config), and at
     # least tp_degree JAX devices (use
     # XLA_FLAGS=--xla_force_host_platform_device_count=N on CPU).
@@ -375,11 +373,25 @@ class ServeConfig:
 
     def validate(self) -> "ServeConfig":
         """Scheduler-level config validation (called by ServeEngine).
+        Returns the config to run: on a paged engine a tick_token_budget of
+        0 resolves to max_batch + prefill_chunk.
 
         Degenerate knob combinations fail HERE with a clear error instead
-        of hanging the tick loop: a chunked engine whose budget cannot fit
+        of hanging the tick loop: a paged engine whose budget cannot fit
         one decode sweep plus one prefill chunk would starve prefill
         forever (decode slots consume the whole budget every tick)."""
+        for flag in ("chunked", "batched"):
+            if not getattr(self, flag):
+                raise ValueError(
+                    f"{flag}=False is no longer supported: the monolithic "
+                    f"and sequential-chunk serving paths were removed (the "
+                    f"paged engine always runs chunked prefill in one "
+                    f"batched launch per tick; paged=False is the dense "
+                    f"engine)")
+        budget = self.max_batch + self.prefill_chunk
+        if self.paged and self.tick_token_budget == 0 and budget > 0:
+            return dataclasses.replace(
+                self, tick_token_budget=budget).validate()
         if self.temperature < 0:
             raise ValueError(f"temperature must be >= 0, "
                              f"got {self.temperature}")
@@ -391,11 +403,7 @@ class ServeConfig:
         if self.admission_policy not in ("fifo", "sjf"):
             raise ValueError(f"admission_policy must be 'fifo' or 'sjf', "
                              f"got {self.admission_policy!r}")
-        if self.chunked:
-            if not self.paged:
-                raise ValueError(
-                    "chunked prefill scheduling requires paged=True (chunks "
-                    "prefill through the block-table kernel)")
+        if self.paged:
             if self.prefill_chunk < 1 or self.prefill_chunk % self.page_size:
                 raise ValueError(
                     f"prefill_chunk ({self.prefill_chunk}) must be a "
@@ -408,9 +416,9 @@ class ServeConfig:
                     f"({self.max_batch} + {self.prefill_chunk}) or prefill "
                     f"can starve behind a full decode batch")
         if self.decode_priority:
-            if not self.chunked:
+            if not self.paged:
                 raise ValueError("decode_priority shaping requires "
-                                 "chunked=True (it caps the per-tick "
+                                 "paged=True (it caps the per-tick "
                                  "prefill share)")
             if not 0.0 < self.max_prefill_fraction <= 1.0:
                 raise ValueError(
@@ -428,11 +436,10 @@ class ServeConfig:
             raise ValueError(f"max_chunks_per_tick must be >= 0, got "
                              f"{self.max_chunks_per_tick}")
         if self.speculative:
-            if not self.chunked or not self.batched:
+            if not self.paged:
                 raise ValueError(
-                    "speculative decoding requires chunked=True and "
-                    "batched=True (draft chains verify through the "
-                    "batched chunk path)")
+                    "speculative decoding requires paged=True (draft "
+                    "chains verify through the batched chunk path)")
             if self.spec_k < 1:
                 raise ValueError(f"spec_k must be >= 1, got {self.spec_k}")
             if self.spec_ngram < 1:
@@ -445,8 +452,8 @@ class ServeConfig:
         if self.telemetry_spans < 1:
             raise ValueError(f"telemetry_spans must be >= 1, "
                              f"got {self.telemetry_spans}")
-        if self.preemption and not self.chunked:
-            raise ValueError("preemption requires chunked=True (a preempted "
+        if self.preemption and not self.paged:
+            raise ValueError("preemption requires paged=True (a preempted "
                              "request resumes through the chunked prefill "
                              "path)")
         if self.priority_aging and self.priority_age_tokens < 1:
@@ -455,13 +462,10 @@ class ServeConfig:
                 f"on, got {self.priority_age_tokens}")
         if self.tp_degree < 1:
             raise ValueError(f"tp_degree must be >= 1, got {self.tp_degree}")
-        if self.tp_degree > 1 and not (self.paged and self.chunked
-                                       and self.batched):
+        if self.tp_degree > 1 and not self.paged:
             raise ValueError(
-                f"tp_degree={self.tp_degree} requires paged=True, "
-                f"chunked=True and batched=True (tensor parallelism shards "
-                f"the paged one-launch tick paths; got paged={self.paged}, "
-                f"chunked={self.chunked}, batched={self.batched})")
+                f"tp_degree={self.tp_degree} requires paged=True (tensor "
+                f"parallelism shards the paged tick's launches)")
         if self.usable_pages:
             if not self.paged:
                 raise ValueError("usable_pages requires paged=True")

@@ -18,17 +18,17 @@ dataflow: the win of fine-grained chunking only materializes once the
 per-chunk dispatch overhead is folded away (FlatAttention / Zen-Attention
 make the same argument for tile-based NPU attention).
 
-Three callers share the kernel, all handing it queries at absolute
-positions ``offset + i`` whose K/V for positions < offset is already
-resident in the page pool:
+Every paged prefill goes through the kernel, handing it queries at
+absolute positions ``offset + i`` whose K/V for positions < offset is
+already resident in the page pool:
 
   batched chunked prefill  (serve/engine.py) - every chunk of this tick's
                    token-budget plan, packed by scheduler.pack_chunks.
-  prefix caching   (serve/prefix_cache.py) - the uncached SUFFIX after
+  prefix caching   (serve/prefix_cache.py) - a request's chunks start at
                    the longest cached prefix; offset = cached tokens.
-  single chunks    (serve/scheduler.py sequential oracle path) - the K=1
-                   special case, kept under the established
-                   ``paged_prefill_attention`` name.
+  single rows      the K=1 special case, kept under the established
+                   ``paged_prefill_attention`` name as the kernel tests'
+                   single-row oracle.
 
 Either way the queries must attend causally over EVERYTHING before them -
 earlier pages AND the chunk's own K/V, both reached through the row's

@@ -1,8 +1,8 @@
 """JAX's persistent compilation cache for the entry points.
 
-Called by the scripts a user runs (``chip_smoke.py``, ``repro.launch.serve``,
-``benchmarks/serve_bench.py``), never on library import: a library that
-picked a cache directory would override the caller's.
+Called by the scripts a user runs (``chip_smoke.py``, ``repro.launch.serve``),
+never on library import: a library that picked a cache directory would
+override the caller's.
 """
 from __future__ import annotations
 

@@ -7,15 +7,14 @@ configuration:
 
     PYTHONPATH=src python -m repro.launch.serve --arch gemma3-4b --smoke
     PYTHONPATH=src python -m repro.launch.serve --arch granite-3-2b \
-        --chunked --page-size 128 --max-seq 2048 --prefill-chunk 512
+        --paged --page-size 128 --max-seq 2048 --prefill-chunk 512
 
 --paged serves through the paged KV cache (serve/paged_cache.py): a global
 page pool + block table instead of one dense (max_batch, max_seq) strip per
-slot.  --num-pages 0 sizes the pool to dense-equivalent capacity; smaller
-pools trade admission backpressure for KV memory.  --chunked adds the
-token-budget scheduler (paged + chunked + batched, the path tensor
-parallelism runs on): prefill chunks of --prefill-chunk tokens share each
-tick with decode.  JAX's persistent compilation cache is on
+slot, under the token-budget scheduler - prefill chunks of --prefill-chunk
+tokens share each tick with decode.  --num-pages 0 sizes the pool to
+dense-equivalent capacity; smaller pools trade admission backpressure for
+KV memory.  JAX's persistent compilation cache is on
 (repro.launch.compile_cache).
 """
 import argparse
@@ -40,15 +39,13 @@ def main(argv=None):
     ap.add_argument("--max-batch", type=int, default=4)
     ap.add_argument("--max-seq", type=int, default=128)
     ap.add_argument("--paged", action="store_true",
-                    help="serve through the paged KV cache")
-    ap.add_argument("--chunked", action="store_true",
-                    help="chunked prefill under the token-budget scheduler "
-                         "(implies --paged)")
+                    help="serve through the paged KV cache, with chunked "
+                         "prefill under the token-budget scheduler")
     ap.add_argument("--page-size", type=int, default=16)
     ap.add_argument("--num-pages", type=int, default=0,
                     help="page pool size (0 = dense-equivalent)")
     ap.add_argument("--prefill-chunk", type=int, default=64,
-                    help="tokens per prefill chunk with --chunked (a "
+                    help="tokens per prefill chunk with --paged (a "
                          "multiple of --page-size)")
     args = ap.parse_args(argv)
 
@@ -56,22 +53,17 @@ def main(argv=None):
     cfg = get_smoke_config(args.arch) if args.smoke else get_config(args.arch)
     model = build_model(cfg)
     params = jax.jit(model.init)(jax.random.PRNGKey(0))
-    paged = args.paged or args.chunked
     scfg = ServeConfig(max_batch=args.max_batch, max_seq=args.max_seq,
-                       max_new_tokens=16, paged=paged, chunked=args.chunked,
+                       max_new_tokens=16, paged=args.paged,
                        page_size=args.page_size, num_pages=args.num_pages,
-                       prefill_chunk=args.prefill_chunk,
-                       tick_token_budget=(args.max_batch + args.prefill_chunk
-                                          if args.chunked else 0))
+                       prefill_chunk=args.prefill_chunk)
     eng = ServeEngine(model, params, scfg)
     rng = np.random.default_rng(0)
     for _ in range(args.requests):
         eng.submit(rng.integers(1, cfg.vocab_size, size=6).tolist())
     done = eng.run_until_done()
-    mode = f"paged (page={scfg.page_size}, pool={scfg.pool_pages()})" \
-        if paged else "dense"
-    if args.chunked:
-        mode += f", chunked ({scfg.prefill_chunk}-token chunks)"
+    mode = f"paged (page={scfg.page_size}, pool={scfg.pool_pages()}, " \
+        f"{scfg.prefill_chunk}-token chunks)" if args.paged else "dense"
     print(f"served {len(done)} requests, "
           f"{sum(len(r.out_tokens) for r in done)} tokens "
           f"[{mode} KV cache, {eng.kv_cache_bytes() / 1e6:.2f} MB]")

@@ -109,32 +109,6 @@ def layer_window(cfg: ModelConfig, flag, attend):
     return attend(cfg.sliding_window)
 
 
-def attn_prefill_paged(params, x, cfg: ModelConfig, kv_pages, layer, flag,
-                       plan, *, impl: Optional[str] = None):
-    """Prefill one sequence's prompt into its KV pages.
-
-    x: (1, S, D) with S a multiple of the page size (pad the prompt
-    upstream; trailing pad K/V is masked by `lens` at decode time and gets
-    overwritten as decode advances).  kv_pages: the stacked (L, Hkv, P,
-    page_size, W) pool, written at layer `layer` (flag: the layer's
-    is-global flag, layer_window); plan: ops.kv_write_plan of positions
-    [0, S) through the sequence's pages, computed once for all layers.
-    Returns (y, kv_pages)."""
-    q, k, v = _qkv(params, x, cfg)
-    S = x.shape[1]
-    if cfg.use_rope:
-        pos = jnp.arange(S)
-        q = rope(q, pos, cfg.rope_theta, cfg.rope_scaling)
-        k = rope(k, pos, cfg.rope_theta, cfg.rope_scaling)
-    kv_pages = ops.kv_page_write(kv_pages, layer, k, v, plan, impl=impl)
-    o = layer_window(cfg, flag, lambda w: ops.flash_attention(
-        q, k, v, causal=True, window=w,
-        logit_softcap=cfg.attn_logit_softcap, impl=impl))
-    y = linear(params, "o", o.reshape(1, S, cfg.n_heads * cfg.head_dim),
-               cfg)
-    return y, kv_pages
-
-
 def attn_decode_paged(params, x, cfg: ModelConfig, kv_pages, layer, flag,
                       block_table, lens, plan, *, impl: Optional[str] = None,
                       tp_mesh=None):

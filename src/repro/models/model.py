@@ -30,23 +30,14 @@ class Model:
     init_cache: Callable
     prefill: Callable
     decode_step: Callable
-    # paged KV-cache prompt prefill (attention families only; see
-    # serve/paged_cache.py for the host-side allocator)
-    prefill_paged: Optional[Callable] = None
-    # prefix-cached suffix prefill: only the uncached tail of the prompt is
-    # computed, attending over cached pages via the block table
-    # (serve/prefix_cache.py owns the host-side radix tree)
-    prefill_suffix: Optional[Callable] = None
-    # mid-prompt chunk prefill for token-budget scheduling: any contiguous
-    # chunk of a prompt prefills through the same offset-causal block-table
-    # kernel, attending over everything already written (cached prefix +
-    # earlier chunks).  The suffix above is the final-chunk special case.
-    # (serve/scheduler.py owns the host-side chunk planning)
-    prefill_chunk: Optional[Callable] = None
-    # ragged batched chunk prefill: K chunks of K different sequences, each
-    # with its own block-table row / offset / cursor, in ONE call - the
-    # one-launch serve tick packs a whole tick's chunk plan through this.
-    # prefill_chunk above is its K=1 special case.
+    # the one paged prefill (attention families only): K chunks of K
+    # different sequences, each with its own block-table row / offset /
+    # cursor, in ONE call through the offset-causal block-table kernel,
+    # attending over everything already written (cached prefix + earlier
+    # chunks).  A whole prompt, or a prefix-cached suffix, is one chunk.
+    # The serve tick packs its whole chunk plan through this
+    # (serve/scheduler.py plans the chunks, serve/paged_cache.py owns the
+    # pages, serve/prefix_cache.py the cached prefixes).
     prefill_chunks: Optional[Callable] = None
     # speculative verification: the same ragged chunk pass, but returning
     # EVERY position's logits (K, S, V) instead of each row's last - one
@@ -231,31 +222,6 @@ def build_model(cfg: ModelConfig) -> Model:
         return logits.astype(jnp.float32), cache, lens
 
     # ---------------- paged prefill -----------------------------------------
-    def prefill_paged(params, batch, cache, page_ids, *, impl=None):
-        """Prefill ONE sequence's prompt (B=1) into its KV pages.
-
-        batch: {"tokens": (1, S_pad), "true_lens": (1,) optional} with S_pad
-        a multiple of the page size; page_ids: (S_pad // page_size,) pages
-        owned by the sequence; cache: the paged layout from init_cache.
-        Returns (last_logits, cache, lens)."""
-        if fam not in ("dense", "moe", "vlm"):
-            raise ValueError(
-                f"paged prefill needs an attention family, got {fam}")
-        tokens = batch["tokens"]
-        B, S = tokens.shape
-        x = _embed_tokens(params, tokens)
-        x = constrain(x, "btd")
-        x, cache = T.stack_prefill_paged(params["blocks"], x, cfg, cache,
-                                         page_ids, impl=impl)
-        tl = batch.get("true_lens")
-        lens = jnp.full((B,), S, jnp.int32) if tl is None \
-            else jnp.asarray(tl, jnp.int32)
-        x = apply_norm(params["final_norm"], x, cfg)
-        x_last = x[:, -1:] if tl is None else \
-            jnp.take_along_axis(x, (lens - 1)[:, None, None], axis=1)
-        logits = unembed(params["tok"], x_last, cfg)
-        return logits.astype(jnp.float32), cache, lens
-
     def prefill_chunks(params, batch, cache, page_tables, *, impl=None,
                        tp_mesh=None):
         """Prefill a RAGGED BATCH of mid-prompt chunks: K chunks of K
@@ -273,7 +239,7 @@ def build_model(cfg: ModelConfig) -> Model:
         chunks of one sequence are packed together with ordered offsets),
         and the row's own chunk - through the offset-causal batched
         block-table kernel (kernels/paged_prefill.py), so composing
-        chunks left to right reproduces the monolithic prefill exactly.
+        chunks left to right reproduces one whole-prompt chunk exactly.
         Dead padding rows carry true_lens == 0 and an all-null table row;
         their logits are garbage the caller drops.
         Returns (chunk_last_logits (K, 1, V), cache, cursors (K,)): each
@@ -340,25 +306,6 @@ def build_model(cfg: ModelConfig) -> Model:
         x = apply_norm(params["final_norm"], x, cfg)
         logits = unembed(params["tok"], x, cfg)
         return logits.astype(jnp.float32), cache
-
-    def prefill_chunk(params, batch, cache, page_row, *, impl=None):
-        """Prefill one MID-PROMPT chunk of one sequence's prompt: the K=1
-        special case of prefill_chunks.
-
-        batch: {"tokens": (1, S_pad), "offset": (1,), "true_lens": (1,)}
-        - exactly the batched layout with one row; page_row: (n_max,) the
-        sequence's block-table row.  Returns (chunk_last_logits, cache,
-        cursor).
-
-        The prefix-cache suffix path is the final-chunk special case:
-        cursor == full prompt length (Model.prefill_suffix aliases this)."""
-        return prefill_chunks(params, batch, cache,
-                              jnp.asarray(page_row, jnp.int32)[None],
-                              impl=impl)
-
-    # prefix-cached suffix prefill IS a chunk prefill whose cursor is the
-    # full prompt length - kept under its established name
-    prefill_suffix = prefill_chunk
 
     def _fill_cross_cache(params, cache, enc_out):
         from .layers import dense
@@ -442,8 +389,5 @@ def build_model(cfg: ModelConfig) -> Model:
     return Model(cfg=cfg, init=init, forward=forward, loss=loss,
                  init_cache=init_cache, prefill=prefill,
                  decode_step=decode_step,
-                 prefill_paged=prefill_paged if is_attn else None,
-                 prefill_suffix=prefill_suffix if is_attn else None,
-                 prefill_chunk=prefill_chunk if is_attn else None,
                  prefill_chunks=prefill_chunks if is_attn else None,
                  verify_chunks=verify_chunks if is_attn else None)

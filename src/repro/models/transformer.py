@@ -26,7 +26,7 @@ from ..kernels import ops
 from ..sharding import constrain
 from .attention import (attn_decode, attn_decode_paged, attn_forward,
                         attn_init, attn_prefill, attn_prefill_chunks_paged,
-                        attn_prefill_paged, layer_window)
+                        layer_window)
 from .layers import apply_norm, grad_cast, mlp, mlp_init, norm_init, pdtype
 from .mamba2 import (mamba2_decode, mamba2_forward, mamba2_init,
                      mamba2_init_state, mamba2_prefill)
@@ -216,24 +216,6 @@ def _scan_paged(cfg: ModelConfig, params, x, kv_pages, attn):
     return x, kv_pages
 
 
-def stack_prefill_paged(params, x, cfg: ModelConfig, cache, page_ids, *,
-                        impl=None):
-    """Paged prefill of ONE sequence (B=1), x: (1, S, D) with S a multiple
-    of the page size.  cache: {"kv_pages": (L, Hkv, P, page, W),
-    "block_table": (B, n_max)}; page_ids: (S // page,) pages owned by the
-    sequence.  The block table itself is host-managed (serve/paged_cache.py)
-    and passes through untouched."""
-    S, ps = x.shape[1], cache["kv_pages"].shape[3]
-    zero = jnp.zeros((1,), jnp.int32)
-    plan = ops.kv_write_plan(jnp.asarray(page_ids, jnp.int32)[None], zero,
-                             zero + S, S, ps, impl=impl)
-    x, kv = _scan_paged(
-        cfg, params, x, cache["kv_pages"],
-        lambda p, h, kv, l, flag: attn_prefill_paged(
-            p, h, cfg, kv, l, flag, plan, impl=impl))
-    return x, {"kv_pages": kv, "block_table": cache["block_table"]}
-
-
 def stack_prefill_chunks_paged(params, x, cfg: ModelConfig, cache,
                                page_tables, offsets, true_lens, *,
                                q_lens=None, impl=None, tp_mesh=None):
@@ -257,22 +239,6 @@ def stack_prefill_chunks_paged(params, x, cfg: ModelConfig, cache,
             p, h, cfg, kv, l, flag, page_tables, offsets, true_lens, plan,
             q_lens=q_lens, impl=impl, tp_mesh=tp_mesh))
     return x, {"kv_pages": kv, "block_table": cache["block_table"]}
-
-
-def stack_prefill_chunk_paged(params, x, cfg: ModelConfig, cache, page_row,
-                              offset, *, impl=None):
-    """Paged prefill of ONE mid-prompt chunk of ONE sequence: the K=1
-    special case of stack_prefill_chunks_paged (every position of x
-    treated as real - the historical single-row contract).  x: (1, S, D)
-    at absolute positions offset + arange(S); page_row: (n_max,)."""
-    off = jnp.asarray(offset, jnp.int32).reshape(1)
-    return stack_prefill_chunks_paged(
-        params, x, cfg, cache, jnp.asarray(page_row, jnp.int32)[None], off,
-        off + x.shape[1], impl=impl)
-
-
-# the prefix-cache suffix is the final-chunk special case
-stack_prefill_suffix_paged = stack_prefill_chunk_paged
 
 
 def stack_decode_paged(params, x, cfg: ModelConfig, cache, lens, *,

@@ -9,11 +9,9 @@ from .sampling import (apply_top_k, apply_top_p, sample, sample_chain,
 from .scheduler import (ChunkBatch, ChunkTask, DraftTask, Request,
                         RequestState, SpecBatch, TokenBudgetScheduler,
                         bucket_rows)
-from .serve_step import (make_chunk_batch_step, make_chunk_prefill_step,
-                         make_fused_decode_step, make_paged_prefill_step,
+from .serve_step import (make_chunk_batch_step, make_fused_decode_step,
                          make_prefill_step, make_serve_step,
-                         make_spec_verify_step, make_suffix_prefill_step,
-                         sample_token)
+                         make_spec_verify_step, sample_token)
 from .telemetry import (Counter, Gauge, Histogram, LaunchRecord,
                         MetricError, MetricsRegistry, Span, SpanTracer,
                         Telemetry, TickRecord, TraceEvent,
@@ -28,9 +26,7 @@ __all__ = ["ChunkBatch", "ChunkTask", "Counter", "DraftTask", "FleetConfig",
            "Telemetry", "TickRecord", "TokenBudgetScheduler", "TraceEvent",
            "apply_top_k", "apply_top_p", "bucket_rows", "dense_kv_bytes",
            "export_chrome_trace", "make_chunk_batch_step",
-           "make_chunk_prefill_step", "make_fused_decode_step",
-           "make_paged_prefill_step", "make_prefill_step", "make_serve_step",
-           "make_spec_verify_step", "make_suffix_prefill_step",
-           "movement_breakdown", "ngram_draft", "paged_kv_bytes",
-           "pages_needed", "sample", "sample_chain", "sample_token",
-           "speculative_accept"]
+           "make_fused_decode_step", "make_prefill_step", "make_serve_step",
+           "make_spec_verify_step", "movement_breakdown", "ngram_draft",
+           "paged_kv_bytes", "pages_needed", "sample", "sample_chain",
+           "sample_token", "speculative_accept"]

@@ -8,10 +8,8 @@ chunk planning, and latency accounting live in the token-budget scheduler
 (serve/scheduler.py); this module owns all device state and page
 bookkeeping.
 
-Two cache modes (ServeConfig.paged):
+Two cache modes (ServeConfig.paged), each with its one tick:
 
-  dense  one (L, max_batch, max_seq, Hkv, D) strip per K and V - every slot
-         reserves max_seq worth of KV whether it needs it or not.
   paged  a global page pool + block table (serve/paged_cache.py): a request
          holds ceil((prompt + max_new) / page_size) pages from admission to
          completion and returns them the tick it finishes, so mixed-length
@@ -19,33 +17,30 @@ Two cache modes (ServeConfig.paged):
          Admission reserves the worst case up front; when the free list
          cannot cover it the request simply stays queued (backpressure) -
          nothing mid-flight can run out of pages.
-
-Two prefill schedules (ServeConfig.chunked):
-
-  monolithic  (default) the whole prompt prefills in ONE batched pass at
-         admission - simple, but a 4k-token admission stalls every active
-         decode slot for the full prefill (a request-level pipeline
-         bubble, the serving analogue of the tier stalls the paper's
-         3D-FlashAttention chunking removes).
-  chunked  each tick gets ServeConfig.tick_token_budget tokens of work:
+         Each tick gets ServeConfig.tick_token_budget tokens of work:
          decoding slots consume 1 each, and the remainder is filled with
          prompt chunks (multiples of ServeConfig.prefill_chunk) for
          PREFILLING slots through the offset-causal block-table kernel
          (kernels/paged_prefill.py) - decode latency stays flat while
-         long prompts stream in.  Paged mode only.  A slot that is still
-         prefilling keeps lens == 0 and a zeroed row in the DEVICE block
-         table, so the batched decode step's write lane for it lands in
-         the reserved null page, never in its half-filled pages.
-         With ServeConfig.batched (default) a chunked tick costs ONE
+         long prompts stream in.  A slot that is still prefilling keeps
+         lens == 0 and a zeroed row in the DEVICE block table, so the
+         batched decode step's write lane for it lands in the reserved
+         null page, never in its half-filled pages.  A tick costs ONE
          ragged batched prefill launch + ONE fused decode launch + ONE
          device->host transfer regardless of how many requests are in
          flight: the scheduler packs every planned chunk into a K-row
          batch (serve/scheduler.py pack_chunks, power-of-two bucketed),
          final-chunk tokens are sampled device-side, and per-slot
          bookkeeping collapses into vectorized masked updates.
-         batched=False keeps one launch per chunk (the parity oracle).
+  dense  one (L, max_batch, max_seq, Hkv, D) strip per K and V - every slot
+         reserves max_seq worth of KV whether it needs it or not.  The
+         whole prompt prefills in ONE pass at admission (token by token
+         for the recurrent and audio families), then one fused decode
+         launch per tick.  It is the independent reference the paged
+         engine's outputs are tested against, and the only engine for
+         the non-attention families.
 
-Decode-priority shaping + preemption (chunked mode):
+Decode-priority shaping + preemption (paged mode):
 
   ServeConfig.decode_priority caps the prefill share of every tick at
   max_prefill_fraction * tick_token_budget after decode slots take their
@@ -66,13 +61,13 @@ Prefix caching (ServeConfig.prefix_cache, paged mode only): finished
 requests publish their prompt pages into a radix tree
 (serve/prefix_cache.py); admission matches the longest cached prefix,
 attaches those pages refcounted, and prefills ONLY the uncached remainder
-- monolithically as a suffix, or as budgeted chunks when chunked (the
-request's prefill cursor simply starts at the cached-prefix boundary).
+as budgeted chunks (the request's prefill cursor simply starts at the
+cached-prefix boundary).
 
-Self-speculative decoding (ServeConfig.speculative, chunked+batched
-only): each tick, every DECODING slot may propose a draft chain by
-n-gram lookup over its own token history (serve/drafting.py - no second
-model), capped by spec_k, the remaining generation budget, and the
+Self-speculative decoding (ServeConfig.speculative, paged mode only):
+each tick, every DECODING slot may propose a draft chain by n-gram
+lookup over its own token history (serve/drafting.py - no second model),
+capped by spec_k, the remaining generation budget, and the
 tick's token budget (drafted tokens consume budget exactly like prefill
 chunks).  All chains verify in ONE extra ragged launch through the same
 batched chunk kernel decode already uses (serve/serve_step.py
@@ -114,16 +109,15 @@ from .prefix_cache import RadixPrefixCache
 from .scheduler import (ChunkTask, DraftTask, Request, RequestState,
                         SpecBatch, TokenBudgetScheduler)
 from .serve_step import (copy_page, make_chunk_batch_step,
-                         make_chunk_prefill_step,
-                         make_fused_decode_step, make_paged_prefill_step,
-                         make_prefill_step, make_serve_step,
-                         make_spec_verify_step, sample_token)
+                         make_fused_decode_step, make_prefill_step,
+                         make_serve_step, make_spec_verify_step,
+                         sample_token)
 from .telemetry import (TRACK_ENGINE, TRACK_QUEUE, LaunchRecord,
                         MetricsRegistry, SpanTracer, Telemetry, TickRecord,
                         export_chrome_trace, movement_breakdown)
 
-# attention-family prompts are padded to a multiple of this before the
-# batched prefill, bounding jit recompiles to one per bucket
+# the dense engine pads attention-family prompts to a multiple of this
+# before the whole-prompt prefill, bounding jit recompiles to one per bucket
 PREFILL_BUCKET = 16
 
 # Jitted serve steps are SHARED across every engine built on the same model
@@ -134,7 +128,7 @@ PREFILL_BUCKET = 16
 # keeps greedy outputs bit-identical ACROSS engine instances: near-tie
 # argmaxes are sensitive to last-ulp rounding differences between separate
 # compilations of the same program, so parity comparisons between two
-# engines (monolithic vs chunked, preempted vs uninterrupted oracle) are
+# engines (paged vs dense, preempted vs uninterrupted oracle) are
 # only exact when both run the same executables.
 #
 # The cache lives for the PROCESS: the step closures capture the model, so
@@ -181,13 +175,7 @@ def _shared_steps(model: Model, temperature: float, top_k: int = 0,
                                        tp_mesh=tp_mesh), 1),
             "prefill": _jit_donating_cache(make_prefill_step(model), 2),
         }
-        if model.prefill_paged is not None:
-            steps["prefill_paged"] = _jit_donating_cache(
-                make_paged_prefill_step(model), 2)
-            # one jitted step serves the prefix-suffix AND chunked paths:
-            # a suffix is a final chunk (same batch contract, same HLO)
-            steps["prefill_chunk"] = _jit_donating_cache(
-                make_chunk_prefill_step(model), 2)
+        if model.prefill_chunks is not None:
             # the one-launch tick: every chunk planned this tick runs as
             # one ragged batch, final-chunk tokens sampled device-side
             steps["prefill_chunks"] = _jit_donating_cache(
@@ -238,7 +226,6 @@ class ServeEngine:
         cfg = model.cfg
         B = scfg.max_batch
         self.paged = scfg.paged
-        self.chunked = scfg.chunked
         self.speculative = scfg.speculative
         self._attention_family = cfg.family in ("dense", "moe", "vlm")
         if self.speculative and model.verify_chunks is None:
@@ -318,12 +305,11 @@ class ServeEngine:
         if scfg.prefix_cache and not scfg.paged:
             raise ValueError("prefix_cache requires paged=True")
         if self.paged:
-            if model.prefill_paged is None:
+            if model.prefill_chunks is None:
                 raise ValueError(f"paged serving needs an attention family, "
                                  f"got {cfg.family}")
             if scfg.max_seq % scfg.page_size:
-                # the page-multiple invariant (attn_prefill_paged writes
-                # prompts as whole pages) must hold at the max_seq cap too
+                # the block table covers max_seq in whole pages
                 raise ValueError(
                     f"max_seq ({scfg.max_seq}) must be a multiple of "
                     f"page_size ({scfg.page_size})")
@@ -372,7 +358,7 @@ class ServeEngine:
         self.lens = jnp.zeros((B,), jnp.int32)
         self.slots: List[Optional[Request]] = [None] * B
         self.tokens = jnp.zeros((B, 1), jnp.int32)
-        self.sched = TokenBudgetScheduler(scfg, metrics=m)
+        self.sched = TokenBudgetScheduler(self.scfg, metrics=m)
         self._uid = 0
         self._admit_seq = 0          # monotone admission stamp (victim order)
         self._key = jax.random.PRNGKey(scfg.seed)
@@ -405,8 +391,6 @@ class ServeEngine:
         self._decode_fused = steps["decode_fused"]
         self._prefill = steps["prefill"]
         if self.paged:
-            self._prefill_paged = steps["prefill_paged"]
-            self._prefill_chunk = steps["prefill_chunk"]
             self._prefill_chunks = steps["prefill_chunks"]
             self._copy_page_step = steps["copy_page"]
         if self.speculative:
@@ -466,7 +450,7 @@ class ServeEngine:
         the token-emitting launches read, the block-table bytes paid to
         replication, and the per-shard page-byte unit - everything the
         conformance cross-check (shard_bytes * tp == pages_read *
-        page_bytes) and the serve_bench --tp inequality consume."""
+        page_bytes) consumes."""
         g = self.tm.registry.get
         return {
             "tp_degree": int(self.scfg.tp_degree),
@@ -695,8 +679,6 @@ class ServeEngine:
         out: Dict[str, float] = dict(self.sched.stats())
         out.update(self.prefix_stats())
         out["tick_token_budget"] = self.scfg.tick_token_budget
-        out["chunked"] = self.chunked
-        out["batched"] = self.scfg.batched
         out["jit_calls"] = self.jit_calls
         out["host_syncs"] = self.host_syncs
         out["compile_count"] = self.compile_cache_size()
@@ -776,8 +758,6 @@ class ServeEngine:
         absolute count spans every sibling engine in the process; deltas
         within one engine's run still measure that run's recompiles."""
         fns = [self._decode, self._decode_fused, self._prefill,
-               getattr(self, "_prefill_paged", None),
-               getattr(self, "_prefill_chunk", None),
                getattr(self, "_prefill_chunks", None),
                getattr(self, "_spec_verify", None)]
         return sum(fn._cache_size() for fn in fns
@@ -826,8 +806,8 @@ class ServeEngine:
               work: Optional[int] = None) -> bool:
         """Record one generated token; True when the request is finished
         (stop token or length budget).  `work` back-stamps the token's
-        work clock (one-launch tick: emission is deferred until after the
-        decode launch, but the stamp must match the sequential path)."""
+        work clock (paged tick: a final chunk's token is emitted after the
+        decode launch, but stamped at its place in the chunk plan)."""
         req.out_tokens.append(tok)
         self.gen_tokens += 1
         self.sched.note_token(req, time.perf_counter(), work=work)
@@ -955,13 +935,12 @@ class ServeEngine:
             self._table_dirty = False
 
     # ------------------------------------------------------------------
-    # admission (monolithic prefill)
+    # dense admission (whole-prompt prefill)
     # ------------------------------------------------------------------
     def _admit(self):
         """Prefill queued requests into free slots, whole prompts at once.
-        Admission order follows ServeConfig.admission_policy; stops at the
-        first candidate that cannot be placed (no slot, or - paged - not
-        enough free pages: backpressure, it stays queued)."""
+        Admission order follows ServeConfig.admission_policy; stops when
+        no slot is free."""
         while True:
             req = self.sched.peek()
             if req is None:
@@ -969,48 +948,26 @@ class ServeEngine:
             slot = self._free_slot()
             if slot is None:
                 return
-            if self.paged:
-                if not self._admit_paged(slot, req):
-                    return
-            elif self._attention_family:
+            if self._attention_family:
                 self._admit_prefill(slot, req)
             else:
                 self._admit_stepwise(slot, req)
 
-    def _padded_prompt(self, prompt: List[int], bucket: int):
-        s_real = len(prompt)
-        s_pad = min(-(-s_real // bucket) * bucket, self.scfg.max_seq)
-        toks = np.zeros((1, s_pad), np.int32)
-        toks[0, :s_real] = prompt
-        return jnp.asarray(toks), s_real
-
-    def _place(self, slot: int, req: Request, logits, s_real: int):
-        """Common tail of every monolithic admission path: record the slot
-        state and sample the first generated token from the prompt's last
-        logits (a stop token here finishes the request immediately)."""
-        self.lens = self.lens.at[slot].set(s_real)
-        self._lens_np[slot] = s_real
-        self.host_syncs += 1
-        nxt = int(self._sample(logits)[0, 0])
-        self.tokens = self.tokens.at[slot, 0].set(nxt)
-        self.slots[slot] = req
-        req.slot = slot
-        req.prefill_pos = len(req.prompt)
-        req.state = RequestState.DECODING
-        self._stamp_admit(req)
-        self._phase(req, "DECODING", slot)
-        if self._emit(req, nxt):
-            self._finish(req)
-
     def _admit_prefill(self, slot: int, req: Request):
         """Dense cache, attention family: one batched prefill into a
-        sub-cache sized to the padded prompt, scattered into the slot row."""
+        sub-cache sized to the padded prompt, scattered into the slot row;
+        the first generated token is sampled from the prompt's last logits
+        (a stop token here finishes the request immediately)."""
         self._pop_admitted(req)
         self._phase(req, "PREFILLING", slot)
-        toks, s_real = self._padded_prompt(req.prompt, PREFILL_BUCKET)
-        s_pad = toks.shape[1]
+        s_real = len(req.prompt)
+        s_pad = min(-(-s_real // PREFILL_BUCKET) * PREFILL_BUCKET,
+                    self.scfg.max_seq)
+        toks = np.zeros((1, s_pad), np.int32)
+        toks[0, :s_real] = req.prompt
         sub = self.model.init_cache(1, s_pad)
-        batch = {"tokens": toks, "true_lens": jnp.asarray([s_real])}
+        batch = {"tokens": jnp.asarray(toks),
+                 "true_lens": jnp.asarray([s_real])}
         self.jit_calls += 1
         with self.tm.phase("engine.prefill_launch", true_tokens=s_real):
             logits, sub, _ = self._prefill(self.params, batch, sub)
@@ -1024,7 +981,19 @@ class ServeEngine:
             sub["v"][:, 0])
         self.prefill_tokens += s_real
         self.sched.note_work(s_real)
-        self._place(slot, req, logits, s_real)
+        self.lens = self.lens.at[slot].set(s_real)
+        self._lens_np[slot] = s_real
+        self.host_syncs += 1
+        nxt = int(self._sample(logits)[0, 0])
+        self.tokens = self.tokens.at[slot, 0].set(nxt)
+        self.slots[slot] = req
+        req.slot = slot
+        req.prefill_pos = s_real
+        req.state = RequestState.DECODING
+        self._stamp_admit(req)
+        self._phase(req, "DECODING", slot)
+        if self._emit(req, nxt):
+            self._finish(req)
 
     def _note_alloc(self):
         self.peak_pages = max(self.peak_pages, self.allocator.used_pages)
@@ -1048,42 +1017,6 @@ class ServeEngine:
         place in the donated pool (serve_step.copy_page)."""
         self.cache["kv_pages"] = self._copy_page_step(self.cache["kv_pages"],
                                                       src, dst)
-
-    def _admit_paged(self, slot: int, req: Request) -> bool:
-        """Paged cache: reserve the request's worst case up front; prefill
-        the prompt straight into its pages.  False = out of pages.
-        (Reservations that can never fit were rejected at submit time.)"""
-        if self.prefix is not None:
-            return self._admit_prefix(slot, req)
-        scfg = self.scfg
-        need = pages_needed(len(req.prompt) + req.max_new_tokens,
-                            scfg.page_size)
-        if not self.allocator.can_alloc(need):
-            return False
-        self._pop_admitted(req)
-        self._phase(req, "PREFILLING", slot)
-        pages = self.allocator.alloc(slot, need)
-        self._note_alloc()
-        toks, s_real = self._padded_prompt(req.prompt, scfg.page_size)
-        page_ids = jnp.asarray(pages[:toks.shape[1] // scfg.page_size],
-                               jnp.int32)
-        self.cache["block_table"] = self.allocator.table_device()
-        self._note_table_upload(self.allocator.table.nbytes)
-        batch = {"tokens": toks, "true_lens": jnp.asarray([s_real])}
-        self.jit_calls += 1
-        with self.tm.phase("engine.prefill_launch", true_tokens=s_real):
-            logits, self.cache, _ = self._prefill_paged(
-                self.params, batch, self.cache, page_ids)
-        self._note_launch("prefill_paged", rows=1, live_rows=1,
-                          true_tokens=s_real,
-                          padded_tokens=toks.shape[1],
-                          kv_pages_read=self._row_pages(slot, s_real),
-                          kv_pages_written=self._span_pages(0, s_real),
-                          new_kv_tokens=s_real)
-        self.prefill_tokens += s_real
-        self.sched.note_work(s_real)
-        self._place(slot, req, logits, s_real)
-        return True
 
     def _reserve_prefix(self, slot: int, req: Request) -> Optional[int]:
         """Shared prefix-cached reservation: attach the longest cached
@@ -1137,29 +1070,6 @@ class ServeEngine:
             self.tm.registry.get("serve_queue_wait_seconds").observe(
                 req.t_admit - req.t_submit)
 
-    def _admit_prefix(self, slot: int, req: Request) -> bool:
-        """Prefix-cached monolithic admission: the whole uncached suffix
-        prefills in one pass - literally the request's FINAL chunk, so
-        this delegates to _run_chunk (which samples the first token and
-        flips the request to DECODING).  False = out of pages even after
-        eviction."""
-        start = self._reserve_prefix(slot, req)
-        if start is None:
-            return False
-        self._pop_admitted(req)
-        self.slots[slot] = req
-        req.slot = slot
-        req.prefill_pos = start
-        req.state = RequestState.PREFILLING
-        self._stamp_admit(req)
-        self._phase(req, "PREFILLING", slot, cached_tokens=start)
-        # the decode step later this tick walks the slot's row on device
-        self.cache["block_table"] = self.allocator.table_device()
-        self._note_table_upload(self.allocator.table.nbytes)
-        self._run_chunk(ChunkTask(req, slot, start,
-                                  len(req.prompt) - start))
-        return True
-
     def _admit_stepwise(self, slot: int, req: Request):
         """Token-by-token prefill through decode_step (exact for every
         architecture family, including recurrent state caches)."""
@@ -1200,10 +1110,10 @@ class ServeEngine:
             self._finish(req)
 
     # ------------------------------------------------------------------
-    # chunked prefill (token-budget schedule)
+    # paged admission and chunked prefill (token-budget schedule)
     # ------------------------------------------------------------------
     def _reserve_chunked(self, slot: int, req: Request) -> bool:
-        """Chunked admission: reserve pages (through the prefix cache when
+        """Paged admission: reserve pages (through the prefix cache when
         enabled) and mark the request PREFILLING with its cursor at the
         cached-prefix boundary - no prompt computation happens here; the
         scheduler streams chunks in over the coming ticks."""
@@ -1227,55 +1137,6 @@ class ServeEngine:
         self._phase(req, "PREFILLING", slot, cached_tokens=start)
         return True
 
-    def _run_chunk(self, task: ChunkTask):
-        """Execute one planned prefill chunk through the offset-causal
-        block-table kernel; the chunk's K/V lands in the slot's pages and
-        its queries attend over everything already written (cached prefix
-        + earlier chunks).  The final chunk samples the request's first
-        token from the prompt's last logits and flips it to DECODING.
-        (The sequential oracle path - ServeConfig.batched=False - and the
-        monolithic prefix-suffix admission; the batched tick replaces the
-        per-chunk launches and per-token syncs with _run_chunk_batch.)"""
-        req, slot = task.req, task.slot
-        ps = self.scfg.page_size
-        start, n = task.start, task.length
-        with self.tm.phase("engine.chunk_launch", rows=1, live_rows=1,
-                           true_tokens=n):
-            s_pad = -(-n // ps) * ps
-            toks = np.zeros((1, s_pad), np.int32)
-            toks[0, :n] = req.target[start:start + n]
-            # copy: the row is a view into the live allocator table (see
-            # _sync_table for the zero-copy aliasing hazard)
-            page_row = jnp.asarray(self.allocator.table[slot].copy(),
-                                   jnp.int32)
-            batch = {"tokens": jnp.asarray(toks),
-                     "offset": jnp.asarray([start], jnp.int32),
-                     "true_lens": jnp.asarray([start + n], jnp.int32)}
-            self.jit_calls += 1
-            logits, self.cache, _ = self._prefill_chunk(
-                self.params, batch, self.cache, page_row)
-        req.prefill_pos = start + n
-        self.prefill_tokens += n
-        self.sched.note_work(n)
-        self.sched.chunks_run += 1
-        self._note_launch("chunk", rows=1, live_rows=1, true_tokens=n,
-                          padded_tokens=s_pad,
-                          kv_pages_read=self._row_pages(slot, start + n),
-                          kv_pages_written=self._span_pages(start,
-                                                            start + n),
-                          new_kv_tokens=n)
-        if req.prefill_pos >= len(req.target):
-            self.lens = self.lens.at[slot].set(len(req.target))
-            self._lens_np[slot] = len(req.target)
-            self.host_syncs += 1
-            nxt = int(self._sample(logits)[0, 0])
-            self.tokens = self.tokens.at[slot, 0].set(nxt)
-            req.state = RequestState.DECODING
-            self._phase(req, "DECODING", slot)
-            self._table_dirty = True     # unmask the slot's device row
-            if self._emit(req, nxt):
-                self._finish(req)
-
     def _run_chunk_batch(self, tasks: List[ChunkTask]):
         """Execute EVERY prefill chunk planned this tick in ONE jitted
         launch: the scheduler packs the tasks into a ragged K-row batch
@@ -1287,8 +1148,9 @@ class ServeEngine:
         emissions [(req, slot, work-clock stamp)] - their token VALUES
         surface in the tick's single device_get after the decode launch.
 
-        Host accounting walks the tasks in plan order so work-clock
-        TTFT/TBT match the sequential per-chunk path bit for bit."""
+        Host accounting walks the tasks in plan order: each final row's
+        first token is stamped with the work clock as it stands after its
+        own chunk, not after the whole pack."""
         with self.tm.phase("engine.chunk_launch") as args:
             pack = self.sched.pack_chunks(tasks)
             finals = []
@@ -1492,20 +1354,18 @@ class ServeEngine:
         self._preempt(victim)
         return True
 
-    def _tick_chunked(self) -> List[Request]:
+    def _tick_paged(self) -> List[Request]:
         """One budgeted iteration: admit, fill the budget with prefill
         chunks, run one batched decode step for the slots that were
         already decoding.  Total work never exceeds tick_token_budget.
 
-        With ServeConfig.batched (default) the tick is ONE batched ragged
-        prefill launch + ONE fused decode launch + ONE device->host
-        transfer, whatever the traffic: all sampling happens device-side
-        and token values surface in a single fetch at the end, so the
-        host loop carries no per-chunk or per-slot round-trips (the
-        serving analogue of the paper's bubble-free vertical dataflow -
-        fine-grained chunking only wins once per-step dispatch overhead
-        is gone).  batched=False keeps one launch per chunk and per-slot
-        emission: the sequential parity oracle."""
+        The tick is ONE batched ragged prefill launch + ONE fused decode
+        launch + ONE device->host transfer, whatever the traffic: all
+        sampling happens device-side and token values surface in a
+        single fetch at the end, so the host loop carries no per-chunk
+        or per-slot round-trips (the serving analogue of the paper's
+        bubble-free vertical dataflow - fine-grained chunking only wins
+        once per-step dispatch overhead is gone)."""
         w0 = self.sched.work_clock
         # admission FIRST (it can preempt: a decoding victim shed here
         # must not join this tick's decode batch): reserve slots + pages
@@ -1565,13 +1425,7 @@ class ServeEngine:
             plan.update(n_chunks=len(chunks), n_decode=len(decode_slots),
                         n_drafts=len(spec_tasks))
         self._tick_profile = (len(chunks), len(decode_slots))
-        finals = []
-        if chunks:
-            if self.scfg.batched:
-                finals = self._run_chunk_batch(chunks)
-            else:
-                for task in chunks:
-                    self._run_chunk(task)
+        finals = self._run_chunk_batch(chunks) if chunks else []
         # drafted slots verify their whole chain in the spec launch; the
         # rest take their one token through the fused decode as before
         spec_slots = {t.slot for t in spec_tasks}
@@ -1655,7 +1509,7 @@ class ServeEngine:
         return self._finished_this_tick
 
     # ------------------------------------------------------------------
-    def _cow_guard(self, spec_spans: Optional[Dict[int, int]] = None):
+    def _cow_guard(self, spec_spans: Dict[int, int]):
         """Give any decoding slot about to WRITE into a shared page a
         private copy first.  By construction generation pages are private
         (the one structural COW happens at admission), so this is a cheap
@@ -1668,13 +1522,12 @@ class ServeEngine:
         page that range touches gets the same guard."""
         ps = self.scfg.page_size
         lens = self._lens_np          # host mirror: no device->host sync
-        spans = spec_spans or {}
         dirty = False
         for i, req in enumerate(self.slots):
             if req is None or req.state is not RequestState.DECODING:
                 continue
             lo = int(lens[i]) // ps
-            hi = (int(lens[i]) + spans.get(i, 0)) // ps
+            hi = (int(lens[i]) + spec_spans.get(i, 0)) // ps
             for idx in range(lo, hi + 1):
                 page = int(self.allocator.table[i, idx])
                 if self.allocator.refcount(page) > 1:
@@ -1694,9 +1547,9 @@ class ServeEngine:
                 self.prefix.evict(short)
 
     def tick(self) -> List[Request]:
-        """One engine iteration.  Monolithic: admit (full prefills) + one
-        batched decode step.  Chunked: one token-budgeted round of decode
-        + prefill chunks.  Returns requests that finished this tick.
+        """One engine iteration.  Paged: one token-budgeted round of decode
+        + prefill chunks.  Dense: admit (whole-prompt prefills) + one
+        batched decode step.  Returns requests that finished this tick.
         Every tick appends its dispatch accounting to self.tm.ticks and
         runs inside the engine.tick phase (Telemetry.phase)."""
         with self.tm.phase("engine.tick") as args:
@@ -1705,8 +1558,7 @@ class ServeEngine:
             self._expire_deadlines()
             j0, s0 = self.jit_calls, self.host_syncs
             t0 = time.perf_counter()
-            out = self._tick_chunked() if self.chunked \
-                else self._tick_monolithic()
+            out = self._tick_paged() if self.paged else self._tick_dense()
             rec = TickRecord(self.jit_calls - j0, self.host_syncs - s0,
                              time.perf_counter() - t0, *self._tick_profile)
             self.tm.ticks.append(rec)
@@ -1714,30 +1566,19 @@ class ServeEngine:
                         n_chunks=rec.n_chunk_tasks, n_decode=rec.n_decode)
         return out
 
-    def _tick_monolithic(self) -> List[Request]:
+    def _tick_dense(self) -> List[Request]:
         w0 = self.sched.work_clock
         with self.tm.phase("engine.admit"):
             self._admit()
-        if self._finished_this_tick and self.paged:
-            # a request can finish AT admission (stop token / length 1 on
-            # its first sampled token); its pages just went back to the
-            # pool or into the prefix cache, but the device table still
-            # maps its lane to them - re-upload BEFORE the decode step or
-            # the lane's masked write (lens == 0) corrupts position 0 of a
-            # freed or published page
-            self._sync_table()
         active = [i for i, r in enumerate(self.slots) if r is not None]
         if not active:
             if self.sched.work_clock > w0:      # admissions that finished
                 self.sched.note_tick(0, self.sched.work_clock - w0)
             return self._finished_this_tick
         self._tick_profile = (0, len(active))
-        if self.prefix is not None:
-            self._cow_guard()
         # one fused launch: decode + device-side sampling + vectorized
         # masked token/length updates, then ONE device->host transfer for
-        # every lane's sampled token (was: one int() sync + two .at[i]
-        # dispatches PER SLOT)
+        # every lane's sampled token
         with self.tm.phase("engine.decode_launch",
                            live_rows=len(active)) as args:
             live = np.zeros((len(self.slots),), bool)
@@ -1746,23 +1587,13 @@ class ServeEngine:
             self.decode_launches += 1
             args["ctx_tokens"] = self._note_decode_ctx(
                 int(self._lens_np[i]) + 1 for i in active)
-            pages_read = 0
-            if self.paged:
-                self._note_kv_pages_read(sum(
-                    -(-(int(self._lens_np[i]) + 1) // self.scfg.page_size)
-                    for i in active))
-                pages_read = sum(self._row_pages(i,
-                                                 int(self._lens_np[i]) + 1)
-                                 for i in active)
             self.cache, self.tokens, self.lens = self._decode_fused(
                 self.params, self.cache, self.tokens, self.lens,
                 jnp.asarray(live), self._next_key())
             self._note_launch("decode", rows=len(self.slots),
                               live_rows=len(active), true_tokens=len(active),
                               padded_tokens=len(self.slots),
-                              kv_pages_read=pages_read,
-                              kv_pages_written=len(active)
-                              if self.paged else 0,
+                              kv_pages_read=0, kv_pages_written=0,
                               new_kv_tokens=len(active),
                               ctx_tokens=args["ctx_tokens"])
         self.sched.note_work(len(active))
@@ -1776,9 +1607,6 @@ class ServeEngine:
                     self._finish(req)
             self.sched.note_tick(len(active),
                                  self.sched.work_clock - w0 - len(active))
-            if self._finished_this_tick and self.paged:
-                self._maybe_evict_watermark()
-                self._sync_table()
         return self._finished_this_tick
 
     def run_until_done(self, max_ticks: int = 10_000,

@@ -414,9 +414,9 @@ class FleetRouter:
                 m.get("fleet_retries_exhausted_total").inc()
                 self._terminated.append(req)
                 continue
-            if req.out_tokens and not self.scfg.chunked:
+            if req.out_tokens and not self.scfg.paged:
                 raise RuntimeError(
-                    "in-flight failure recovery requires chunked=True: "
+                    "in-flight failure recovery requires paged=True: "
                     "a mid-decode request resumes through the chunk path")
             self._redispatch(fuid, req)
             moved.append(fuid)

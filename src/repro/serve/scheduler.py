@@ -600,8 +600,8 @@ class TokenBudgetScheduler:
         recorded for it: the one-launch tick runs every chunk before any
         token value reaches the host, so it snapshots each final chunk's
         work clock at planning time and stamps the deferred emission with
-        it - keeping work-clock TTFT/TBT identical to the sequential
-        per-chunk path."""
+        it - the clock as it stood right after that chunk, as one launch
+        per chunk would have stamped it."""
         req.token_wall.append(wall)
         req.token_work.append(self.work_clock if work is None else work)
         req.token_tick.append(self.ticks)

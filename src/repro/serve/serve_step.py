@@ -1,4 +1,6 @@
-"""Serving steps: batched single-token decode + (dense or paged) prefill.
+"""Serving steps: batched single-token decode, the dense whole-prompt
+prefill, and the paged ragged chunk batch (every prefill of the paged
+engine) and speculative verify.
 
 The decode step is cache-layout agnostic: pass the dense {"k","v"} cache or
 the paged {"kv_pages","block_table"} cache and decode_step routes
@@ -41,48 +43,6 @@ def make_prefill_step(model: Model):
         return model.prefill(params, batch, cache)
 
     return prefill_step
-
-
-def make_paged_prefill_step(model: Model):
-    """paged_prefill_step(params, batch, cache, page_ids) ->
-    (last_logits, cache, lens).  batch["tokens"]: (1, S_pad) prompt padded
-    to a page multiple, real length in batch["true_lens"]; page_ids:
-    (S_pad // page_size,) pages owned by the sequence (PageAllocator)."""
-
-    def paged_prefill_step(params, batch, cache, page_ids):
-        return model.prefill_paged(params, batch, cache, page_ids)
-
-    return paged_prefill_step
-
-
-def make_suffix_prefill_step(model: Model):
-    """suffix_prefill_step(params, batch, cache, page_row) ->
-    (last_logits, cache, lens).  Prefix-cached prefill: batch["tokens"]:
-    (1, S_pad) holds only the UNCACHED prompt suffix (zero-padded), its
-    absolute start position in batch["offset"], the FULL prompt length in
-    batch["true_lens"]; page_row: (n_max,) the sequence's block-table row
-    with cached prefix pages first (serve/prefix_cache.py)."""
-
-    def suffix_prefill_step(params, batch, cache, page_row):
-        return model.prefill_suffix(params, batch, cache, page_row)
-
-    return suffix_prefill_step
-
-
-def make_chunk_prefill_step(model: Model):
-    """chunk_prefill_step(params, batch, cache, page_row) ->
-    (chunk_last_logits, cache, cursor).  One MID-PROMPT chunk of a
-    token-budget scheduled prefill (serve/scheduler.py): batch["tokens"]:
-    (1, S_pad) the chunk (zero-padded to a page multiple), its absolute
-    start in batch["offset"], and the cursor AFTER the chunk's last real
-    token in batch["true_lens"] (= offset + real chunk length; equals the
-    full prompt length only for the final chunk, whose logits seed
-    decoding); page_row: (n_max,) the sequence's block-table row."""
-
-    def chunk_prefill_step(params, batch, cache, page_row):
-        return model.prefill_chunk(params, batch, cache, page_row)
-
-    return chunk_prefill_step
 
 
 def copy_page(kv_pages, src, dst):

@@ -406,8 +406,8 @@ class LaunchRecord:
     exactly against ceil(true_len / page_size) math - one source of
     truth, not a parallel convention."""
     tick: int
-    kind: str                # prefill | prefill_paged | chunk | chunk_batch
-    #                          | decode | spec_verify | stepwise
+    kind: str                # prefill | stepwise (dense) | chunk_batch
+    #                          | decode | spec_verify
     rows: int                # kernel rows launched (after pow2 bucketing)
     live_rows: int           # rows carrying real work
     true_tokens: int         # real query tokens computed
@@ -540,7 +540,7 @@ def movement_breakdown(launches: Iterable[LaunchRecord], cfg, scfg,
     by tp_degree (each shard streams only its Hkv/tp head slice of every
     page), while weights, activations, and the block table are replicated
     - every device streams them in full, which is exactly the replication
-    overhead the serve_bench --tp inequality charges against the split.
+    overhead tensor parallelism pays for the split.
 
     The byte model is a first-order serving roofline, not a device
     counter (benchmarks/roofline.py makes the same tradeoff):

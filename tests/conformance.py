@@ -40,10 +40,10 @@ from traffic import (TrafficItem, assert_greedy_equivalent, mixed_prompts,
                      wave_arrivals)
 
 # smoke-scale engine shape every conformance trace shares (speculation
-# needs paged + chunked + batched; overrides per trace below)
+# needs the paged engine; overrides per trace below)
 BASE_SCFG = dict(max_batch=4, max_seq=512, page_size=16, prefill_chunk=32,
                  tick_token_budget=64, max_new_tokens=24, paged=True,
-                 chunked=True, batched=True, spec_k=6, spec_ngram=3)
+                 spec_k=6, spec_ngram=3)
 
 
 @dataclass(frozen=True)

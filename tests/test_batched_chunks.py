@@ -4,9 +4,10 @@ host-overhead-free serve loop.
 Covers: batched-vs-single-row kernel parity (ref + pallas interpret,
 ragged rows, shuffled tables, dead rows, sliding windows), the
 scheduler's pack step (power-of-two bucketing, sentinel slots), engine
-parity batched-vs-sequential (mixed traffic, prefix cache on/off,
-windowed gemma3 models, the K=1 degenerate case), a hypothesis property
-over random chunk packings / bucket sizes, the one-launch dispatch
+parity against the dense engine (mixed traffic, prefix cache on/off,
+windowed gemma3 models, the K=1 degenerate case), plan-order work-clock
+stamps, a hypothesis property over random chunk packings / bucket
+sizes, the one-launch dispatch
 accounting (one batched prefill launch + one decode launch + one
 device->host transfer per busy tick), and a recompile guard: a
 steady-state tick triggers ZERO new XLA compilations (jax.log_compiles)."""
@@ -40,7 +41,7 @@ def model_f32():
 
 def _base(**over):
     base = dict(max_batch=3, max_seq=256, max_new_tokens=6, paged=True,
-                page_size=8, num_pages=3 * 29 + 1, chunked=True,
+                page_size=8, num_pages=3 * 29 + 1,
                 prefill_chunk=16, tick_token_budget=32)
     base.update(over)
     return ServeConfig(**base)
@@ -126,7 +127,7 @@ def test_bucket_rows_powers_of_two():
 
 def test_pack_chunks_layout():
     scfg = ServeConfig(max_batch=4, prefill_chunk=8, tick_token_budget=64,
-                      paged=True, chunked=True, page_size=8)
+                      paged=True, page_size=8)
     sched = TokenBudgetScheduler(scfg)
     a = Request(1, list(range(100, 120)), 4)   # 20 tokens
     b = Request(2, list(range(200, 209)), 4)   # 9 tokens
@@ -145,19 +146,16 @@ def test_pack_chunks_layout():
 
 
 # ===========================================================================
-# engine parity: batched one-launch tick == sequential per-chunk oracle
+# engine parity: the batched one-launch tick == the dense engine
 # ===========================================================================
 
 @pytest.mark.parametrize("prefix_cache", [False, True])
 def test_batched_matches_sequential_mixed_traffic(prefix_cache, model_f32):
     m, params = model_f32
     prompts = _mixed_prompts(m.cfg.vocab_size)
-    seq, _ = _serve(m, params,
-                    _base(prefix_cache=prefix_cache, batched=False), prompts)
-    bat, eng = _serve(m, params,
-                      _base(prefix_cache=prefix_cache, batched=True),
-                      prompts)
-    assert bat == seq
+    dense, _ = _serve(m, params, _base(paged=False), prompts)
+    bat, eng = _serve(m, params, _base(prefix_cache=prefix_cache), prompts)
+    assert bat == dense
     st = eng.stats()
     assert st["packs_run"] > 0
     assert st["chunks_run"] > st["packs_run"]   # batching actually batched
@@ -173,22 +171,21 @@ def test_batched_matches_sequential_windowed_model(rng):
     m = build_model(cfg)
     params = m.init(rng)
     prompts = _mixed_prompts(cfg.vocab_size, lens=(40, 9, 100))
-    seq, _ = _serve(m, params, _base(max_batch=2, batched=False), prompts)
-    bat, _ = _serve(m, params, _base(max_batch=2, batched=True), prompts)
-    assert bat == seq
+    dense, _ = _serve(m, params, _base(max_batch=2, paged=False), prompts)
+    bat, _ = _serve(m, params, _base(max_batch=2), prompts)
+    assert bat == dense
 
 
 def test_k1_degenerate_case(model_f32):
     """One slot, one request: the batched path runs K=1 packs and must
-    still match the sequential oracle and the monolithic engine."""
+    still match the dense engine."""
     m, params = model_f32
     prompts = _mixed_prompts(m.cfg.vocab_size, lens=(70,))
+    dense, _ = _serve(m, params, _base(max_batch=1, paged=False), prompts)
     # budget 17 = max_batch + prefill_chunk: exactly one chunk per tick
-    kw = dict(max_batch=1, tick_token_budget=17)
-    mono, _ = _serve(m, params, _base(max_batch=1, chunked=False), prompts)
-    seq, _ = _serve(m, params, _base(batched=False, **kw), prompts)
-    bat, eng = _serve(m, params, _base(batched=True, **kw), prompts)
-    assert bat == seq == mono
+    bat, eng = _serve(m, params, _base(max_batch=1, tick_token_budget=17),
+                      prompts)
+    assert bat == dense
     assert eng.stats()["packs_run"] == eng.stats()["chunks_run"]  # all K=1
 
 
@@ -217,17 +214,39 @@ def test_batched_stop_tokens_and_temperature(model_f32):
 
 
 def test_work_clock_stats_match_sequential(model_f32):
-    """Deferred emission must not shift the work-clock accounting: TTFT
-    and TBT stamps are identical to the per-chunk oracle's."""
+    """Deferred emission must not shift the work-clock accounting: a
+    request's first token is stamped with the work clock as it stands
+    right after its OWN final chunk in plan order (what one launch per
+    chunk would stamp), not after the whole pack; every later token with
+    the clock at the end of the tick that emitted it."""
     m, params = model_f32
-    prompts = _mixed_prompts(m.cfg.vocab_size)
+    eng = ServeEngine(m, params, _base())
+    first, early_finals = {}, 0
+    run_pack = eng._run_chunk_batch
 
-    def stamps(batched):
-        _, eng = _serve(m, params, _base(batched=batched), prompts)
-        return sorted((r.uid, r.token_work, r.token_tick)
-                      for r in eng.sched.finished)
+    def spy(tasks):
+        nonlocal early_finals
+        w = eng.sched.work_clock
+        for k, t in enumerate(tasks):
+            w += t.length
+            if t.start + t.length >= len(t.req.target):
+                first[t.req.uid] = w
+                early_finals += k < len(tasks) - 1
+        return run_pack(tasks)
 
-    assert stamps(True) == stamps(False)
+    eng._run_chunk_batch = spy
+    for p in _mixed_prompts(m.cfg.vocab_size):
+        eng.submit(p)
+    tick_end = []
+    while eng.queue or any(s is not None for s in eng.slots):
+        eng.tick()
+        tick_end.append(eng.sched.work_clock)
+    done = eng.sched.finished
+    assert len(done) == len(MIXED_LENS)
+    assert {r.uid: r.token_work[0] for r in done} == first
+    assert early_finals >= 1     # a final chunk with work after it
+    for r in done:
+        assert r.token_work[1:] == [tick_end[t] for t in r.token_tick[1:]]
 
 
 # ===========================================================================
@@ -264,10 +283,10 @@ def test_one_launch_per_busy_tick(model_f32):
 
 
 def test_monolithic_tick_single_sync(model_f32):
-    """Satellite: the NON-chunked tick's decode phase is one fused launch
+    """The dense engine's tick: its decode phase is one fused launch
     + one device->host transfer, not per-slot int() syncs."""
     m, params = model_f32
-    eng = ServeEngine(m, params, _base(chunked=False, max_new_tokens=12))
+    eng = ServeEngine(m, params, _base(paged=False, max_new_tokens=12))
     for p in _mixed_prompts(m.cfg.vocab_size, lens=(12, 20, 9)):
         eng.submit(p)
     eng.tick()                                  # admissions + first decode
@@ -330,7 +349,7 @@ def test_steady_state_tick_zero_recompiles(model_f32):
 
 
 # ===========================================================================
-# hypothesis: batched == sequential over random packings / bucket sizes
+# hypothesis: batched == dense over random packings / bucket sizes
 # ===========================================================================
 
 def test_property_random_packings(model_f32):
@@ -339,7 +358,7 @@ def test_property_random_packings(model_f32):
 
     m, params = model_f32
     prompts = _mixed_prompts(m.cfg.vocab_size, lens=(28, 9, 60))
-    mono, _ = _serve(m, params, _base(max_batch=2, chunked=False), prompts)
+    dense, _ = _serve(m, params, _base(max_batch=2, paged=False), prompts)
 
     @settings(max_examples=8, deadline=None)
     @given(chunk_mult=st.integers(1, 4), extra=st.integers(0, 40),
@@ -352,7 +371,7 @@ def test_property_random_packings(model_f32):
             _base(max_batch=2, prefill_chunk=chunk,
                   tick_token_budget=budget, admission_policy=policy),
             prompts)
-        assert out == mono
+        assert out == dense
         st_ = eng.stats()
         assert st_["max_tick_tokens"] <= budget
         assert st_["jit_calls_per_tick_max"] <= 2

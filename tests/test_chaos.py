@@ -10,6 +10,7 @@ a fault-free run of the same trace - replica death is invisible in the
 tokens, visible only in telemetry and latency.
 """
 import jax
+import numpy as np
 import pytest
 
 from chaos import (Fault, FaultPlan, assert_chaos_conformance,
@@ -70,31 +71,59 @@ def test_kill_replica_mid_trace_is_invisible_in_outputs(trace, model_f32):
     assert s["replica_states"] == ["healthy", "dead"]
 
 
+def _tick_to_empty(router, before_tick=lambda tick: None):
+    """Tick the fleet until it is idle, calling before_tick(t) ahead of
+    tick t; returns {fleet uid: the fleet tick that brought its first
+    token} - the one clock that spans a redispatch."""
+    first, tick = {}, 0
+    while not router.idle:
+        before_tick(tick)
+        router.tick()
+        tick += 1
+        for fuid, r in router.requests.items():
+            if r.out_tokens:
+                first.setdefault(fuid, tick)
+    return first
+
+
 def test_kill_redispatches_queued_and_in_flight(model_f32):
     """fail() moves EVERYTHING the dead replica owed: requests still
     queued and requests mid-prefill/mid-decode - each keeps its fleet
-    uid, lands on a survivor, and carries its redispatch count."""
+    uid, lands on a survivor, and carries its redispatch count.  The
+    recovery's latency cost is bounded: the p95 first-token latency in
+    fleet ticks at most triples against the fault-free run."""
     m, params = model_f32
     spec = TRACES["mixed"]
     scfg = make_scfg(spec, False, max_new_tokens=12)
+    prompts = [it.prompt for it in spec.build(m.cfg.vocab_size)]
+    fault_free = _fleet(m, params, scfg, 2)
     router = _fleet(m, params, scfg, 2)
-    for p in [it.prompt for it in spec.build(m.cfg.vocab_size)]:
+    for p in prompts:
+        fault_free.submit(p)
         router.submit(p)
-    for _ in range(2):
-        router.tick()
-    victims = sorted(f for f, r in router.placement.items()
-                     if r == 1 and not router.requests[f].done)
-    assert victims, "trace never placed work on replica 1"
-    moved = router.fail(1)
-    assert moved == victims
-    for fuid in moved:
-        assert router.placement[fuid] == 0
-        assert router.requests[fuid].n_redispatches == 1
-    # idempotent: a second fail of the corpse is a no-op
-    assert router.fail(1) == []
-    router.run_until_done()
+
+    def kill_after_two_ticks(tick):
+        if tick != 2:
+            return
+        victims = sorted(f for f, r in router.placement.items()
+                         if r == 1 and not router.requests[f].done)
+        assert victims, "trace never placed work on replica 1"
+        moved = router.fail(1)
+        assert moved == victims
+        for fuid in moved:
+            assert router.placement[fuid] == 0
+            assert router.requests[fuid].n_redispatches == 1
+        # idempotent: a second fail of the corpse is a no-op
+        assert router.fail(1) == []
+
+    ttft_base = _tick_to_empty(fault_free)
+    ttft = _tick_to_empty(router, kill_after_two_ticks)
+    assert router.fleet_stats()["failures"] == 1
     assert set(router.statuses().values()) == {"done"}
     router.check_invariants()
+    assert ttft.keys() == ttft_base.keys()
+    p95_base = max(float(np.percentile(list(ttft_base.values()), 95)), 1.0)
+    assert float(np.percentile(list(ttft.values()), 95)) <= 3.0 * p95_base
 
 
 def test_no_healthy_replica_raises(model_f32):

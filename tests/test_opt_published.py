@@ -176,7 +176,7 @@ def test_request_past_the_position_table_is_refused(setup):
     mcfg = harness.model_config(cfg).replace(dtype="float32")
     scfg = ServeConfig(max_batch=2, max_seq=4096, page_size=PAGE,
                        prefill_chunk=32, tick_token_budget=64, paged=True,
-                       chunked=True, batched=True, num_pages=300)
+                       num_pages=300)
     eng = ServeEngine(build_model(mcfg), params, scfg)
     with pytest.raises(ValueError, match="max_seq 2048"):
         eng.submit([1] * 2000, max_new_tokens=49)

@@ -154,11 +154,28 @@ def test_engine_full_cover_prompt_cows_and_matches(rng):
 # engine parity: greedy tokens identical with the cache on vs off
 # ===========================================================================
 
-@pytest.mark.parametrize("arch", ["granite-3-2b", "gemma3-4b"])
-def test_engine_prefix_parity(arch, rng):
+def _shared_prefix_waves(vocab):
+    """Two shared 32-token prefixes, one after the other: a warm request
+    runs to completion (publishing its pages), then three followers that
+    share its prefix run concurrently."""
+    rng = np.random.default_rng(0)
+    waves = []
+    for _ in range(2):
+        shared = rng.integers(1, vocab, size=32).tolist()
+        waves += [[shared + rng.integers(1, vocab, size=8).tolist()
+                   for _ in range(n)] for n in (1, 3)]
+    return waves
+
+
+@pytest.mark.parametrize("arch,trace", [("granite-3-2b", "mixed"),
+                                        ("gemma3-4b", "mixed"),
+                                        ("granite-3-2b", "shared")])
+def test_engine_prefix_parity(arch, trace, rng):
     """Shared prefixes, divergence inside and across pages, full-cover
     repeats, sub-page prompts: greedy outputs must be identical with
-    prefix caching on and off (gemma3 adds sliding windows + QK norm)."""
+    prefix caching on and off (gemma3 adds sliding windows + QK norm).
+    On the shared-prefix trace the cache must also save at least 40% of
+    the prompt tokens' prefill and shrink the peak working set."""
     cfg = get_smoke_config(arch).replace(dtype="float32")
     m = build_model(cfg)
     params = m.init(rng)
@@ -166,6 +183,8 @@ def test_engine_prefix_parity(arch, rng):
     waves = [[base + [30, 31, 32, 33, 34]],
              [base + [40, 41], base, [9, 9, 9, 9],
               base + [30, 31, 32, 33, 34]]]
+    if trace == "shared":
+        waves = _shared_prefix_waves(cfg.vocab_size)
 
     def run(prefix):
         eng = ServeEngine(m, params,
@@ -189,6 +208,10 @@ def test_engine_prefix_parity(arch, rng):
     # with the cache on, only tree pages remain in use at the end
     assert eng_on.allocator.used_pages == eng_on.prefix.cached_pages
     eng_on.prefix.check_invariants()
+    if trace == "shared":
+        saved = 1 - eng_on.prefill_tokens / eng_off.prefill_tokens
+        assert saved >= 0.40, saved
+        assert eng_on.peak_live_pages < eng_off.peak_live_pages
 
 
 # ===========================================================================

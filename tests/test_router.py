@@ -47,7 +47,7 @@ def _fleet(model, params, n, scfg, **fcfg_kw):
 def _affinity_scfg(**over):
     base = dict(max_batch=4, max_seq=512, page_size=16, prefill_chunk=32,
                 tick_token_budget=64, max_new_tokens=8, paged=True,
-                chunked=True, batched=True, prefix_cache=True)
+                prefix_cache=True)
     base.update(over)
     return ServeConfig(**base)
 
@@ -141,6 +141,21 @@ def test_affinity_routes_followers_to_the_warm_replica(model_f32):
     assert router.engines[home].prefix_hit_tokens >= 128 * len(follower_uids)
     cold = router.engines[1 - home]
     assert cold.prefix_hit_tokens == 0
+    # the control: the same warm-then-followers trace round-robin routes
+    # followers off the cached prefix, so it recomputes prefill that
+    # affinity saves - with the same greedy outputs
+    rr = _fleet(m, params, 2, _affinity_scfg(), policy="round_robin")
+    rr.submit(prompts[0])
+    rr.run_until_done()
+    for p in prompts[1:]:
+        rr.submit(p)
+    rr.run_until_done()
+    assert rr.outputs() == router.outputs()
+
+    def prefill(fleet):
+        return sum(e.prefill_tokens for e in fleet.engines)
+
+    assert prefill(router) < prefill(rr)
 
 
 def test_round_robin_ignores_the_cache(model_f32):

@@ -1,7 +1,8 @@
-"""Token-budget scheduler: chunked-vs-monolithic parity on mixed traffic,
-budget accounting invariants, decode starvation, lifecycle, admission
-policy, temperature plumbing, stop tokens, mid-prompt chunk kernel parity,
-and a hypothesis property test over random budgets / chunk sizes."""
+"""Token-budget scheduler: paged chunked prefill against the dense engine
+on mixed traffic, budget accounting invariants, decode starvation,
+lifecycle, admission policy, temperature plumbing, stop tokens, mid-prompt
+chunk kernel parity, and a hypothesis property test over random budgets /
+chunk sizes."""
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -38,17 +39,18 @@ def _base(**over):
 
 # ===========================================================================
 # parity: chunked scheduling must produce byte-identical greedy outputs
+# to the dense engine (the independent reference)
 # ===========================================================================
 
 @pytest.mark.parametrize("prefix_cache", [False, True])
 def test_chunked_matches_monolithic_mixed_traffic(prefix_cache, model_f32):
     m, params = model_f32
     prompts = _mixed_prompts(m.cfg.vocab_size)
-    mono, _ = _serve(m, params, _base(prefix_cache=prefix_cache), prompts)
+    dense, _ = _serve(m, params, _base(paged=False), prompts)
     chunked, eng = _serve(
-        m, params, _base(prefix_cache=prefix_cache, chunked=True,
+        m, params, _base(prefix_cache=prefix_cache,
                          prefill_chunk=16, tick_token_budget=32), prompts)
-    assert mono == chunked
+    assert dense == chunked
     assert eng.allocator.used_pages == 0 if not prefix_cache \
         else eng.allocator.live_pages() == 0
     st = eng.stats()
@@ -64,11 +66,11 @@ def test_chunked_matches_monolithic_windowed_model(rng):
     m = build_model(cfg)
     params = m.init(rng)
     prompts = _mixed_prompts(cfg.vocab_size, lens=(40, 9, 100))
-    mono, _ = _serve(m, params, _base(max_batch=2), prompts)
+    dense, _ = _serve(m, params, _base(max_batch=2, paged=False), prompts)
     chunked, _ = _serve(m, params,
-                        _base(max_batch=2, chunked=True, prefill_chunk=16,
+                        _base(max_batch=2, prefill_chunk=16,
                               tick_token_budget=32), prompts)
-    assert mono == chunked
+    assert dense == chunked
 
 
 def test_prefix_cache_composes_with_chunking(model_f32):
@@ -80,8 +82,8 @@ def test_prefix_cache_composes_with_chunking(model_f32):
     tails = [rng.integers(1, m.cfg.vocab_size, size=24).tolist()
              for _ in range(3)]
     prompts = [shared + t for t in tails] + [shared]   # last: full cover
-    scfg_off = _base(chunked=True, prefill_chunk=16, tick_token_budget=32)
-    scfg_on = _base(prefix_cache=True, chunked=True, prefill_chunk=16,
+    scfg_off = _base(prefill_chunk=16, tick_token_budget=32)
+    scfg_on = _base(prefix_cache=True, prefill_chunk=16,
                     tick_token_budget=32)
 
     def run(scfg):
@@ -111,7 +113,7 @@ def test_budget_accounting_invariants(model_f32):
     their token, and prefill chunks are governed by prefill_chunk."""
     m, params = model_f32
     budget, chunk = 24, 8
-    eng = ServeEngine(m, params, _base(chunked=True, prefill_chunk=chunk,
+    eng = ServeEngine(m, params, _base(prefill_chunk=chunk,
                                        tick_token_budget=budget))
     for p in _mixed_prompts(m.cfg.vocab_size):
         eng.submit(p)
@@ -134,7 +136,7 @@ def test_decode_never_starves_behind_long_prefill(model_f32):
     per tick (no request-level pipeline bubble)."""
     m, params = model_f32
     eng = ServeEngine(m, params,
-                      _base(max_batch=2, chunked=True, prefill_chunk=8,
+                      _base(max_batch=2, prefill_chunk=8,
                             tick_token_budget=16, max_new_tokens=40))
     short = eng.submit([5, 7, 11, 13])
     # let the short request reach DECODING
@@ -169,7 +171,7 @@ def test_long_prefill_never_starved_by_short_stream(model_f32):
     oldest prefilling request is guaranteed one chunk every tick."""
     m, params = model_f32
     eng = ServeEngine(m, params,
-                      _base(max_batch=4, chunked=True, prefill_chunk=8,
+                      _base(max_batch=4, prefill_chunk=8,
                             tick_token_budget=20, max_new_tokens=2))
     long_uid = eng.submit(list(range(1, 129)))     # 16 chunks of 8
     eng.tick()
@@ -187,7 +189,7 @@ def test_long_prefill_never_starved_by_short_stream(model_f32):
 def test_lifecycle_states(model_f32):
     m, params = model_f32
     eng = ServeEngine(m, params,
-                      _base(max_batch=1, chunked=True, prefill_chunk=8,
+                      _base(max_batch=1, prefill_chunk=8,
                             tick_token_budget=9, max_new_tokens=2))
     uid = eng.submit(list(range(1, 33)))           # 4 chunks
     req = eng.queue[0]
@@ -210,9 +212,10 @@ def test_lifecycle_states(model_f32):
 def test_chunked_lowers_stalls_and_short_ttft(model_f32):
     """The acceptance criterion at test scale: on a wave trace (a long
     prompt arriving at the head of each wave with shorts behind it while
-    earlier requests decode), chunked scheduling lowers the p95 per-token
-    tick-work stall (the deterministic TBT bubble) and the p95 TTFT of
-    short prompts - with byte-identical greedy outputs."""
+    earlier requests decode), 16-token chunks at budget 40 lower the p95
+    per-token tick-work stall (the deterministic TBT bubble) and the p95
+    TTFT of short prompts against whole-prompt chunks - with
+    byte-identical greedy outputs."""
     m, params = model_f32
     rng = np.random.default_rng(1)
     lens = (224, 32, 16)                 # each wave: long first, shorts behind
@@ -239,16 +242,18 @@ def test_chunked_lowers_stalls_and_short_ttft(model_f32):
 
     base = dict(max_batch=6, max_seq=256, max_new_tokens=8, paged=True,
                 page_size=8, num_pages=6 * 29 + 1)
+    # whole-prompt chunks: the longest prompt is one chunk, and the budget
+    # resolves to max_batch + prefill_chunk
+    whole_out, whole_stall, whole_ttft = run(
+        ServeConfig(**base, prefill_chunk=max(lens)))
     # budget fits the oldest request's guaranteed chunk plus a
     # shortest-remaining-first chunk, so shorts drain past the long
-    mono_out, mono_stall, mono_ttft = run(ServeConfig(**base))
     chunk_out, chunk_stall, chunk_ttft = run(
-        ServeConfig(**base, chunked=True, prefill_chunk=16,
-                    tick_token_budget=40))
-    assert chunk_out == mono_out
-    assert chunk_stall <= 40 < mono_stall
-    assert chunk_stall < mono_stall
-    assert chunk_ttft < mono_ttft
+        ServeConfig(**base, prefill_chunk=16, tick_token_budget=40))
+    assert chunk_out == whole_out
+    assert chunk_stall <= 40 < whole_stall
+    assert chunk_stall < whole_stall
+    assert chunk_ttft < whole_ttft
 
 
 # ===========================================================================
@@ -274,7 +279,7 @@ def test_scheduler_plan_chunks_unit():
     """Pure planning: shortest-remaining-first chunk fill under the
     budget, round-robin passes until the budget is spent."""
     scfg = ServeConfig(max_batch=2, prefill_chunk=8, tick_token_budget=64,
-                       paged=True, chunked=True, page_size=8)
+                       paged=True, page_size=8)
     sched = TokenBudgetScheduler(scfg)
     a = Request(1, list(range(20)), 4)   # 20 tokens: chunks 8, 8, 4
     b = Request(2, list(range(9)), 4)    # 9 tokens: chunks 8, 1
@@ -289,18 +294,27 @@ def test_scheduler_plan_chunks_unit():
 
 
 def test_config_validation():
-    bad = [dict(chunked=True),                                 # not paged
-           dict(chunked=True, paged=True, tick_token_budget=512,
+    bad = [dict(paged=True, tick_token_budget=512,
                 prefill_chunk=13, page_size=8),                # misaligned
-           dict(chunked=True, paged=True, prefill_chunk=8, page_size=8,
+           dict(paged=True, prefill_chunk=8, page_size=8,
                 max_batch=8, tick_token_budget=8),             # starves
            dict(admission_policy="lifo"),
            dict(temperature=-1.0)]
     for kw in bad:
         with pytest.raises(ValueError):
             ServeConfig(**kw).validate()
-    ServeConfig(chunked=True, paged=True, page_size=8, prefill_chunk=16,
+    # the monolithic and sequential-chunk paths are gone
+    for flag in ("chunked", "batched"):
+        for paged in (True, False):
+            with pytest.raises(ValueError, match=f"{flag}=False .*removed"):
+                ServeConfig(paged=paged, **{flag: False}).validate()
+    ServeConfig(paged=True, page_size=8, prefill_chunk=16,
                 max_batch=4, tick_token_budget=20).validate()
+    # a paged budget of 0 resolves to the least budget that validates
+    got = ServeConfig(paged=True, page_size=8, prefill_chunk=16,
+                      max_batch=4).validate()
+    assert got.tick_token_budget == 4 + 16
+    assert ServeConfig().validate().tick_token_budget == 0     # dense
 
 
 # ===========================================================================
@@ -360,7 +374,7 @@ def test_priority_aging_bounds_starvation(model_f32):
 
     def run(priority_aging):
         eng = ServeEngine(m, params, _base(
-            max_batch=1, chunked=True, prefill_chunk=16,
+            max_batch=1, prefill_chunk=16,
             tick_token_budget=17, max_new_tokens=2,
             priority_aging=priority_aging, priority_age_tokens=32))
         low = eng.submit(rng.integers(1, V, size=16).tolist(), priority=0)
@@ -422,7 +436,7 @@ def test_temperature_seeded_is_reproducible(model_f32):
 def test_temperature_chunked_reproducible(model_f32):
     m, params = model_f32
     prompts = _mixed_prompts(m.cfg.vocab_size, lens=(40, 9))
-    kw = dict(max_batch=2, temperature=0.7, seed=11, chunked=True,
+    kw = dict(max_batch=2, temperature=0.7, seed=11,
               prefill_chunk=8, tick_token_budget=16)
     out1, _ = _serve(m, params, _base(**kw), prompts)
     out2, _ = _serve(m, params, _base(**kw), prompts)
@@ -560,39 +574,38 @@ def test_chunk_attention_matches_monolithic(impl, off, s_chunk, rng):
 
 
 def test_model_chunked_prefill_composes_exactly(model_f32):
-    """Composing Model.prefill_chunk left to right must reproduce the
-    monolithic paged prefill: identical final logits, identical decode
-    continuation."""
+    """Composing K=1 Model.prefill_chunks calls left to right must
+    reproduce one whole-prompt row: identical final logits, identical
+    decode continuation."""
     m, params = model_f32
     toks = np.random.default_rng(5).integers(
         1, m.cfg.vocab_size, size=40).tolist()
     ps, n_pages = 8, 8
-    page_ids = jnp.arange(1, 6, dtype=jnp.int32)     # 40 tokens = 5 pages
-    row = np.zeros(8, np.int32)
+    row = np.zeros(8, np.int32)                      # 40 tokens = 5 pages
     row[:5] = np.arange(1, 6)
+    page_rows = jnp.asarray([row])
 
     def fresh_cache():
         c = m.init_cache(1, 64, page_size=ps, num_pages=n_pages)
-        return dict(c, block_table=jnp.asarray([row]))
+        return dict(c, block_table=page_rows)
 
-    batch = {"tokens": jnp.asarray([toks], jnp.int32),
-             "true_lens": jnp.asarray([40])}
-    logits_mono, cache_mono, _ = m.prefill_paged(params, batch,
-                                                 fresh_cache(), page_ids)
+    def chunk(start, n):
+        return {"tokens": jnp.asarray([toks[start:start + n]], jnp.int32),
+                "offset": jnp.asarray([start], jnp.int32),
+                "true_lens": jnp.asarray([start + n], jnp.int32)}
+
+    logits_whole, cache_whole, _ = m.prefill_chunks(
+        params, chunk(0, 40), fresh_cache(), page_rows)
     cache = fresh_cache()
-    page_row = jnp.asarray(row)
     for start, n in ((0, 16), (16, 16), (32, 8)):
-        chunk = {"tokens": jnp.asarray([toks[start:start + n]], jnp.int32),
-                 "offset": jnp.asarray([start], jnp.int32),
-                 "true_lens": jnp.asarray([start + n], jnp.int32)}
-        logits, cache, cursor = m.prefill_chunk(params, chunk, cache,
-                                                page_row)
+        logits, cache, cursor = m.prefill_chunks(params, chunk(start, n),
+                                                 cache, page_rows)
         assert int(cursor[0]) == start + n
-    assert float(jnp.abs(logits - logits_mono).max()) <= 1e-4
+    assert float(jnp.abs(logits - logits_whole).max()) <= 1e-4
     assert float(jnp.abs(cache["kv_pages"]
-                         - cache_mono["kv_pages"]).max()) <= 1e-4
+                         - cache_whole["kv_pages"]).max()) <= 1e-4
     d1, _ = m.decode_step(params, jnp.asarray([[7]]), jnp.asarray([40]),
-                          cache_mono)
+                          cache_whole)
     d2, _ = m.decode_step(params, jnp.asarray([[7]]), jnp.asarray([40]),
                           cache)
     assert float(jnp.abs(d1 - d2).max()) <= 1e-4
@@ -612,7 +625,7 @@ def test_property_random_budget_and_chunk(model_f32):
 
     m, params = model_f32
     prompts = _mixed_prompts(m.cfg.vocab_size, lens=(28, 9, 60))
-    mono, _ = _serve(m, params, _base(max_batch=2), prompts)
+    dense, _ = _serve(m, params, _base(max_batch=2, paged=False), prompts)
 
     @settings(max_examples=8, deadline=None)
     @given(chunk_mult=st.integers(1, 4), extra=st.integers(0, 40),
@@ -622,10 +635,10 @@ def test_property_random_budget_and_chunk(model_f32):
         budget = 2 + chunk + extra
         out, eng = _serve(
             m, params,
-            _base(max_batch=2, chunked=True, prefill_chunk=chunk,
+            _base(max_batch=2, prefill_chunk=chunk,
                   tick_token_budget=budget, admission_policy=policy),
             prompts)
-        assert out == mono
+        assert out == dense
         assert eng.stats()["max_tick_tokens"] <= budget
         assert eng.prefill_tokens == sum(len(p) for p in prompts)
         assert eng.allocator.used_pages == 0
@@ -681,7 +694,7 @@ def test_deadline_expiry_frees_pages_same_tick(model_f32):
     immediately (conservation checked per tick), unrelated traffic
     unharmed, and the engine never hangs."""
     m, params = model_f32
-    scfg = _base(chunked=True, prefill_chunk=16, tick_token_budget=32,
+    scfg = _base(prefill_chunk=16, tick_token_budget=32,
                  max_new_tokens=8)
     eng = ServeEngine(m, params, scfg)
     # 100-token prompt, deadline 101: barely above the submit-time floor,
@@ -705,7 +718,7 @@ def test_deadline_expiry_in_queue_never_admits(model_f32):
     """A request that expires while still QUEUED times out from the
     queue - it must never be admitted, never touch a slot or a page."""
     m, params = model_f32
-    scfg = _base(max_batch=1, chunked=True, prefill_chunk=16,
+    scfg = _base(max_batch=1, prefill_chunk=16,
                  tick_token_budget=32, max_new_tokens=4)
     eng = ServeEngine(m, params, scfg)
     hog = eng.submit(list(range(1, 80)))          # owns the only slot

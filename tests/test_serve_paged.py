@@ -301,10 +301,11 @@ def test_decode_softcap_matches_prefill(paged, rng):
     if paged:
         cache = m.init_cache(1, 16, page_size=4, num_pages=9)
         cache["block_table"] = jnp.asarray([[1, 2, 3, 4]], jnp.int32)
-        page_ids = jnp.asarray([1, 2], jnp.int32)   # 7 tokens pad to 8
         batch = {"tokens": jnp.pad(toks[:, :7], ((0, 0), (0, 1))),
-                 "true_lens": jnp.asarray([7])}
-        _, cache, lens = m.prefill_paged(params, batch, cache, page_ids)
+                 "offset": jnp.asarray([0], jnp.int32),   # 7 tokens pad
+                 "true_lens": jnp.asarray([7], jnp.int32)}  # to 8
+        _, cache, lens = m.prefill_chunks(params, batch, cache,
+                                          cache["block_table"])
     else:
         cache = m.init_cache(1, 16)
         _, cache, lens = m.prefill(params, {"tokens": toks[:, :7]}, cache)

@@ -63,15 +63,17 @@ def test_draft_degenerate_inputs():
 # ===========================================================================
 
 _SPEC_KW = dict(max_batch=2, max_seq=128, page_size=16, paged=True,
-                chunked=True, batched=True, prefill_chunk=16,
-                tick_token_budget=32, max_new_tokens=8, speculative=True)
+                prefill_chunk=16, tick_token_budget=32, max_new_tokens=8,
+                speculative=True)
 
 
 def test_speculative_requires_chunked_batched():
-    with pytest.raises(ValueError, match="chunked"):
-        ServeConfig(**{**_SPEC_KW, "chunked": False}).validate()
-    with pytest.raises(ValueError, match="batched"):
-        ServeConfig(**{**_SPEC_KW, "batched": False}).validate()
+    with pytest.raises(ValueError, match="speculative decoding requires "
+                                         "paged=True"):
+        ServeConfig(**{**_SPEC_KW, "paged": False}).validate()
+    for flag in ("chunked", "batched"):
+        with pytest.raises(ValueError, match=f"{flag}=False is no longer"):
+            ServeConfig(**{**_SPEC_KW, flag: False}).validate()
     with pytest.raises(ValueError, match="spec_k"):
         ServeConfig(**{**_SPEC_KW, "spec_k": 0}).validate()
     with pytest.raises(ValueError, match="spec_ngram"):
@@ -167,8 +169,8 @@ def test_engine_spec_counters_and_budget(model_f32):
     and the emitted stream matches the non-speculative engine's."""
     m, params = model_f32
     scfg = dict(max_batch=2, max_seq=256, page_size=16, paged=True,
-                chunked=True, batched=True, prefill_chunk=16,
-                tick_token_budget=32, max_new_tokens=48, spec_k=4)
+                prefill_chunk=16, tick_token_budget=32, max_new_tokens=48,
+                spec_k=4)
     rng = np.random.default_rng(11)
     base = rng.integers(1, m.cfg.vocab_size, size=4).tolist()
     prompt = base * 6                              # repetitive by design
@@ -188,4 +190,11 @@ def test_engine_spec_counters_and_budget(model_f32):
     budget = eng_on.scfg.tick_token_budget
     for d, p in eng_on.sched.tick_log:
         assert d + p <= budget
-    assert s["ticks"] <= eng_off.stats()["ticks"]
+    s_off = eng_off.stats()
+    assert s["ticks"] <= s_off["ticks"]
+    # the work clock counts accepted tokens only, and accepted drafts
+    # raise the tokens each token-emitting launch yields
+    assert s["work_tokens"] == s_off["work_tokens"]
+    assert s["spec_accepted"] > 0
+    assert s["tokens_per_launch"] > 1.5 * s_off["tokens_per_launch"], \
+        (s["tokens_per_launch"], s_off["tokens_per_launch"])

@@ -51,7 +51,7 @@ def model_f32():
 
 def _base(**over):
     base = dict(max_batch=3, max_seq=256, max_new_tokens=6, paged=True,
-                page_size=PAGE, num_pages=3 * 29 + 1, chunked=True,
+                page_size=PAGE, num_pages=3 * 29 + 1,
                 prefill_chunk=16, tick_token_budget=32,
                 prefix_cache=True)
     base.update(over)
@@ -307,7 +307,7 @@ def test_preempt_resume_events(model_f32):
                            burst_lens=(64,), burst_tick=2)
     scfg = ServeConfig(max_batch=3, max_seq=256, max_new_tokens=8,
                        paged=True, page_size=PAGE, num_pages=200,
-                       chunked=True, prefill_chunk=16,
+                       prefill_chunk=16,
                        tick_token_budget=24, preemption=True,
                        max_chunks_per_tick=1, usable_pages=28,
                        telemetry=True)
@@ -385,8 +385,8 @@ def test_export_trace_work_clock(traced_run, tmp_path):
 # per-launch movement attribution
 # ===========================================================================
 
-KNOWN_KINDS = {"prefill", "prefill_paged", "chunk", "chunk_batch",
-               "decode", "spec_verify", "stepwise"}
+KNOWN_KINDS = {"prefill", "chunk_batch", "decode", "spec_verify",
+               "stepwise"}
 
 
 def test_launch_records_match_page_allocator_accounting(traced_run):
@@ -478,7 +478,7 @@ def test_spec_counters_reach_registry(model_f32):
     base = rng.integers(1, model.cfg.vocab_size, size=4).tolist()
     prompts = [base * 6, base * 5]                 # repetitive by design
     scfg = ServeConfig(max_batch=2, max_seq=256, max_new_tokens=48,
-                       paged=True, page_size=16, chunked=True,
+                       paged=True, page_size=16,
                        prefill_chunk=16, tick_token_budget=32,
                        speculative=True, spec_k=4, telemetry=True)
     outs, eng = serve_all(model, params, scfg, prompts)
@@ -538,24 +538,24 @@ def test_launch_log_and_stats_compat(traced_run):
 # engine phases on the profiler's clock, admission stamps
 # ===========================================================================
 
-CHUNKED_PHASES = {"engine.tick", "engine.admit", "engine.table_upload",
+PAGED_PHASES = {"engine.tick", "engine.admit", "engine.table_upload",
                   "engine.plan", "engine.chunk_launch",
                   "engine.decode_launch", "engine.device_get",
                   "engine.emit"}
-MONOLITHIC_PHASES = {"engine.tick", "engine.admit", "engine.prefill_launch",
-                     "engine.decode_launch", "engine.device_get",
-                     "engine.emit"}
+DENSE_PHASES = {"engine.tick", "engine.admit", "engine.prefill_launch",
+                "engine.decode_launch", "engine.device_get",
+                "engine.emit"}
 
 
-@pytest.mark.parametrize("chunked", [True, False])
-def test_engine_phases_in_profiler_trace(model_f32, tmp_path, chunked):
+@pytest.mark.parametrize("paged", [True, False])
+def test_engine_phases_in_profiler_trace(model_f32, tmp_path, paged):
     """One tick that admits a prompt while another request decodes,
     recorded by jax.profiler: every phase of the tick is in the
     .xplane.pb, nested in engine.tick, and the launches carry their
     args.  The tracer is off: the annotations do not depend on it."""
     from jax.profiler import ProfileData
     model, params = model_f32
-    eng = ServeEngine(model, params, _base(chunked=chunked,
+    eng = ServeEngine(model, params, _base(paged=paged,
                                            prefix_cache=False,
                                            max_new_tokens=4))
     eng.submit(list(range(1, 12)))
@@ -573,20 +573,20 @@ def test_engine_phases_in_profiler_trace(model_f32, tmp_path, chunked):
               for line in plane.lines for e in line.events
               if e.name.startswith("engine.")]
     names = {e.name for e in events}
-    assert names == (CHUNKED_PHASES if chunked else MONOLITHIC_PHASES)
+    assert names == (PAGED_PHASES if paged else DENSE_PHASES)
     (tick,) = [e for e in events if e.name == "engine.tick"]
     t0, t1 = tick.start_ns, tick.start_ns + tick.duration_ns
     for e in events:
         assert t0 <= e.start_ns and e.start_ns + e.duration_ns <= t1, e.name
     stats = {e.name: dict(e.stats) for e in events}
-    # a monolithic admission prefills whole, so the newcomer decodes too.
-    # Context attended: chunked, 11 prompt positions + the pending token;
-    # monolithic, the first request's 12 + 1 and the newcomer's 14 + 1
-    n_decode, ctx = (1, 12) if chunked else (2, 28)
+    # a dense admission prefills whole, so the newcomer decodes too.
+    # Context attended: paged, 11 prompt positions + the pending token;
+    # dense, the first request's 12 + 1 and the newcomer's 14 + 1
+    n_decode, ctx = (1, 12) if paged else (2, 28)
     assert stats["engine.decode_launch"] == {"live_rows": n_decode,
                                              "ctx_tokens": ctx}
     assert stats["engine.tick"]["n_decode"] == n_decode
-    if chunked:
+    if paged:
         assert stats["engine.chunk_launch"] == {"rows": 1, "live_rows": 1,
                                                 "true_tokens": 14}
     else:
@@ -603,7 +603,7 @@ def preempt_run(model_f32):
                            burst_lens=(64,), burst_tick=2)
     scfg = ServeConfig(max_batch=3, max_seq=256, max_new_tokens=8,
                        paged=True, page_size=PAGE, num_pages=200,
-                       chunked=True, prefill_chunk=16,
+                       prefill_chunk=16,
                        tick_token_budget=24, preemption=True,
                        max_chunks_per_tick=1, usable_pages=28,
                        telemetry=True)

@@ -43,8 +43,7 @@ multi_device = pytest.mark.skipif(
            "(XLA_FLAGS=--xla_force_host_platform_device_count=4)")
 
 TP_SCFG = dict(max_batch=4, max_seq=512, page_size=16, prefill_chunk=32,
-               tick_token_budget=64, max_new_tokens=12, paged=True,
-               chunked=True, batched=True)
+               tick_token_budget=64, max_new_tokens=12, paged=True)
 
 
 @pytest.fixture(scope="module")
@@ -64,13 +63,17 @@ def test_tp_degree_below_one_rejected():
         ServeConfig(**{**TP_SCFG, "tp_degree": 0}).validate()
 
 
-# knocking out `paged` also knocks out `chunked` (chunked requires paged
-# and its own validate() check fires first)
-@pytest.mark.parametrize("off", [("paged", "chunked"), ("chunked",),
-                                 ("batched",)])
+# tp needs the paged engine; chunked/batched False name paths that no
+# longer exist, whatever tp_degree is
+_OFF_ERRORS = {"paged": "tp_degree=2 requires paged=True",
+               "chunked": "chunked=False is no longer supported",
+               "batched": "batched=False is no longer supported"}
+
+
+@pytest.mark.parametrize("off", sorted(_OFF_ERRORS))
 def test_tp_requires_paged_chunked_batched(off):
-    kw = {**TP_SCFG, "tp_degree": 2, **{k: False for k in off}}
-    with pytest.raises(ValueError, match="tp_degree"):
+    kw = {**TP_SCFG, "tp_degree": 2, off: False}
+    with pytest.raises(ValueError, match=_OFF_ERRORS[off]):
         ServeConfig(**kw).validate()
 
 
