@@ -36,15 +36,22 @@ def attn_init(key, cfg: ModelConfig):
 
 def _qkv(params, x, cfg: ModelConfig, kv_x=None):
     """q, k, v of x (k and v of kv_x for cross-attention), biases added
-    where the config has them: before RoPE and before any cache write."""
+    where the config has them: before RoPE and before any cache write.
+
+    The barrier keeps the split into heads out of the three dots.  Folded
+    in, it turns each into a convolution that wants its weight as
+    (heads, head_dim, d_model), so inside a layer scan the TPU compiler
+    slices and transposes a copy of the layer's wq/wk/wv every step;
+    kept out, each stays a plain dot that reads its layer of the stacked
+    weight in place (tests/test_chip_compile.py guards this)."""
     B = x.shape[0]
     kv_src = x if kv_x is None else kv_x
-    q = linear(params, "q", x, cfg).reshape(B, x.shape[1], cfg.n_heads,
-                                            cfg.head_dim)
-    k = linear(params, "k", kv_src, cfg).reshape(
-        B, kv_src.shape[1], cfg.n_kv_heads, cfg.head_dim)
-    v = linear(params, "v", kv_src, cfg).reshape(
-        B, kv_src.shape[1], cfg.n_kv_heads, cfg.head_dim)
+    q, k, v = jax.lax.optimization_barrier(
+        (linear(params, "q", x, cfg), linear(params, "k", kv_src, cfg),
+         linear(params, "v", kv_src, cfg)))
+    q = q.reshape(B, x.shape[1], cfg.n_heads, cfg.head_dim)
+    k = k.reshape(B, kv_src.shape[1], cfg.n_kv_heads, cfg.head_dim)
+    v = v.reshape(B, kv_src.shape[1], cfg.n_kv_heads, cfg.head_dim)
     if cfg.qk_norm:
         q = rms_head_norm(q, params["q_norm"])
         k = rms_head_norm(k, params["k_norm"])

@@ -11,6 +11,8 @@ it would refuse on the chip.
 The step guard compiles the program's own fused decode and chunk-batch
 steps the same way and reads the optimized HLO: no XLA op may copy,
 slice or restack the KV page pool, which only the Pallas calls touch.
+The weight guard reads the same steps' layer scan: no op may slice out
+or relayout a copy of one layer's wq, wk or wv.
 
 The topology is described inside a fixture, never at import time: only
 one process at a time may load the TPU library, and the test workers all
@@ -256,7 +258,7 @@ STEP_CFGS = {"": CFG, "-hd128": get_config("granite-8b"),
              "-opt-6.7b": get_config("opt-6.7b")}
 
 
-def _compile_step(one_chip, kind, arch):
+def _compile_step(one_chip, kind, arch, rows=STEP_ROWS):
     cfg = STEP_CFGS[arch].replace(n_layers=STEP_LAYERS)
     model = build_model(cfg)
     seq = min(STEP_SEQ, cfg.max_seq)
@@ -277,9 +279,9 @@ def _compile_step(one_chip, kind, arch):
         donate = 1
     else:
         fn = serve_step.make_chunk_batch_step(model, temperature=0.0)
-        batch = {"tokens": vec(STEP_ROWS, CHUNK), "offset": vec(STEP_ROWS),
-                 "true_lens": vec(STEP_ROWS), "final_slot": vec(STEP_ROWS)}
-        args = (params, batch, cache, vec(STEP_ROWS, seq // 128),
+        batch = {"tokens": vec(rows, CHUNK), "offset": vec(rows),
+                 "true_lens": vec(rows), "final_slot": vec(rows)}
+        args = (params, batch, cache, vec(rows, seq // 128),
                 vec(STEP_BATCH, 1), vec(STEP_BATCH), key)
         donate = 2
     pool = max(jax.tree.leaves(cache), key=lambda x: x.size)
@@ -287,21 +289,26 @@ def _compile_step(one_chip, kind, arch):
     return compiled, pool
 
 
-def _pool_sized_ops(hlo: str, min_elems: int):
-    """(name, opcode) of every instruction outside a fusion's body whose
-    array result holds at least `min_elems` elements."""
-    found, comp = [], ""
+def _instructions(hlo: str):
+    """(computation, name, element count, opcode) of every instruction
+    whose result is an array."""
+    comp = ""
     for line in hlo.splitlines():
         head = re.match(r"(?:ENTRY )?%(\S+) .*\{$", line)
         if head and not line.startswith(" "):
             comp = head.group(1)
         m = re.match(r"\s*(?:ROOT )?%(\S+) = \w+\[([\d,]*)\]\S* "
                      r"([\w-]+)\(", line)
-        if m and "fused" not in comp:
+        if m:
             n = int(np.prod([int(d) for d in m.group(2).split(",") if d]))
-            if n >= min_elems:
-                found.append((m.group(1), m.group(3)))
-    return found
+            yield comp, m.group(1), n, m.group(3)
+
+
+def _pool_sized_ops(hlo: str, min_elems: int):
+    """(name, opcode) of every instruction outside a fusion's body whose
+    array result holds at least `min_elems` elements."""
+    return [(name, op) for comp, name, n, op in _instructions(hlo)
+            if "fused" not in comp and n >= min_elems]
 
 
 @pytest.mark.parametrize(("kind", "arch"), [
@@ -328,6 +335,52 @@ def test_step_never_copies_the_page_pool(topo, one_chip, for_chip, kind,
                  "chunk": "batched_paged_prefill_attention"}[kind]
     assert _kernel_named(compiled, "kv_page_write")
     assert _kernel_named(compiled, attention)
+
+
+# the weight guard's chunk rows: 3 x 512 tokens, so that no activation of
+# the step holds as many elements as one layer's wq/wk/wv (at 4 x 512,
+# granite-3-2b's q has the element count of its wq)
+WEIGHT_GUARD_ROWS = 3
+
+
+def _while_body_ops(hlo: str, attention: str):
+    """(name, element count, opcode) of every instruction of the while
+    body that calls the `attention` kernel: the layer scan's body."""
+    bodies = set(re.findall(r"\bwhile\(.*?\bbody=%([\w.-]+)", hlo))
+    ops_of = {}
+    for comp, name, n, op in _instructions(hlo):
+        if comp in bodies:
+            ops_of.setdefault(comp, []).append((name, n, op))
+    scan = [ops for ops in ops_of.values()
+            if any(name.startswith(attention) for name, _, _ in ops)]
+    assert len(scan) == 1, sorted(ops_of)
+    return scan[0]
+
+
+@pytest.mark.parametrize(("kind", "arch"), [
+    pytest.param(kind, arch, id=kind + arch)
+    for arch in STEP_CFGS for kind in ("decode", "chunk")])
+def test_step_reads_qkv_weights_in_place(topo, one_chip, for_chip, kind,
+                                         arch):
+    """The steps of the page-pool guard (2 layers; chunk steps of 3 rows):
+    in the layer scan's body, neither a `copy` nor a fusion results in an
+    array of one layer's wq, wk or wv.  Split into heads inside the dot,
+    a projection became a convolution that wanted its weight as (heads,
+    head_dim, d_model): each step sliced out a copy of each layer's
+    weight and transposed it.  A plain dot reads the weight in place.
+    The compiler's asynchronous prefetches (copy-start/copy-done,
+    slice-start/slice-done) are not relayouts and are not counted."""
+    cfg = STEP_CFGS[arch]
+    attention = {"decode": "paged_flash_decode",
+                 "chunk": "batched_paged_prefill_attention"}[kind]
+    compiled, _ = _compile_step(one_chip, kind, arch,
+                                rows=WEIGHT_GUARD_ROWS)
+    layer = {cfg.d_model * cfg.n_heads * cfg.head_dim,
+             cfg.d_model * cfg.n_kv_heads * cfg.head_dim}
+    bad = [(name, op)
+           for name, n, op in _while_body_ops(compiled.as_text(), attention)
+           if op in ("copy", "fusion") and n in layer]
+    assert not bad, bad
 
 
 @pytest.mark.parametrize("head_dim", sorted(PAGED_CFGS))
